@@ -84,7 +84,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.core import load as loads
-from repro.core.engine_backend import available_backends
+from repro.core.engine_backend import available_backends, use_compile_cache
 from repro.core.fleet_engine import SensorBank, fleet_audit
 from repro.core.ledger import EnergyLedger
 from repro.core.meter import WorkloadSet
@@ -188,6 +188,15 @@ def _run_shard_worker(n_devices, n_shards, shard_chunk, repeat=1,
     import subprocess
     import sys as _sys
 
+    import jax
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            "the shard sweep runs each shard count in a child process on "
+            "forced host-CPU devices, a CPU rehearsal only; on this "
+            f"{jax.default_backend()} host the chips belong to this "
+            "process, so run the sharded audit in-process instead: "
+            "fleet_audit(..., mesh=data_mesh(k)) or chip_smoke.py "
+            "--four-chips")
     here = os.path.dirname(os.path.abspath(__file__))
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
@@ -620,6 +629,7 @@ def run(argv=None) -> None:
     # programmatic callers (benchmarks/run.py) get the defaults; the CLI
     # passes sys.argv[1:] explicitly
     args = _parse_args(argv if argv is not None else [])
+    use_compile_cache()
     n = args.n_devices
     backends = _selected_backends(args.backend)
 

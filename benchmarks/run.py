@@ -21,6 +21,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.core.engine_backend import use_compile_cache
+    use_compile_cache()
     from benchmarks import (boxcar, energy_cases, fleet, load_linearity,
                             module_scope, profile_sweep, roofline_report,
                             steady_state, transient, update_period,

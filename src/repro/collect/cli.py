@@ -34,6 +34,7 @@ from repro.collect.registry import DeviceRegistry
 from repro.core import profiles
 from repro.core.calibrate import CalibrationRecord, nominal_record
 from repro.core.calibrate_store import ArtifactStore, StoreError
+from repro.core.engine_backend import use_compile_cache
 
 
 def _default_record(profile_name: Optional[str],
@@ -257,6 +258,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     try:
         return args.func(args)
     except (StoreError, ValueError, FileNotFoundError, KeyError) as e:

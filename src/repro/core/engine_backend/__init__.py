@@ -13,9 +13,9 @@ per array backend:
   under x64 so results stay within one reporting quantum of NumPy.
 * :mod:`~repro.core.engine_backend.pallas_backend` — fused Pallas
   kernels for the streaming hot loops (``stream_ingest``,
-  ``stream_ingest_grid``, ``step_integrate``, ``log_filter``), with
-  ``interpret=True`` fallback on CPU-only hosts; gather-bound kernels
-  delegate to the jax tier.
+  ``stream_ingest_grid``, ``step_integrate``, ``log_filter``) in 32
+  bits, run by the Pallas interpreter where the platform is the CPU;
+  gather-bound kernels delegate to the jax tier.
 
 Backends are plain modules sharing one function signature set over the
 pytree containers in :mod:`~repro.core.engine_backend.pytrees`
@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import importlib
 import importlib.util
+import os
 from typing import Optional, Tuple
 
 from repro.core.engine_backend import numpy_backend
@@ -40,10 +41,12 @@ from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
                                                TimelineArrays)
 
 __all__ = ["available_backends", "get_backend", "has_jax",
-           "resolve_backend", "PollGrid", "ReadingSchedule",
-           "TimelineArrays", "numpy_backend"]
+           "resolve_backend", "use_compile_cache", "PollGrid",
+           "ReadingSchedule", "TimelineArrays", "numpy_backend"]
 
 _BACKENDS = {"numpy": numpy_backend}
+_CHECKOUT = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                         *[os.pardir] * 4))
 _KNOWN = ("numpy", "jax", "pallas")
 
 
@@ -51,33 +54,45 @@ _HAS_JAX: Optional[bool] = None
 
 
 def has_jax() -> bool:
-    """Whether the jax backend can actually be loaded.
-
-    A present-but-broken install (jax without a matching jaxlib) must
-    read as unavailable so ``backend="auto"`` degrades to numpy instead
-    of crashing; that means probing with a real import, not just
-    ``find_spec``.  The result is cached — the probe runs once."""
+    """Whether jax is installed.  An installed jax is imported here, and
+    a broken install (jax without a matching jaxlib, say) raises instead
+    of reading as absent, so ``backend="auto"`` never falls back to numpy
+    on a host that was meant to use the accelerator.  The result is
+    cached."""
     global _HAS_JAX
     if _HAS_JAX is None:
-        if "jax" in _BACKENDS:
-            _HAS_JAX = True
-        elif importlib.util.find_spec("jax") is None:
-            _HAS_JAX = False
-        else:
-            try:
-                importlib.import_module("jax")
-                _HAS_JAX = True
-            except Exception:
-                _HAS_JAX = False
+        _HAS_JAX = importlib.util.find_spec("jax") is not None
+        if _HAS_JAX:
+            importlib.import_module("jax")
     return _HAS_JAX
+
+
+def use_compile_cache() -> Optional[str]:
+    """Keep jax's persistent compilation cache in one fixed directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already uses it and
+    nothing else is set.  Otherwise the cache goes to ``.jax_cache/`` at
+    the root of the checkout: a fixed path, since the path is part of the
+    cache key.  Entry points call this (``chip_smoke.py``, the
+    benchmarks, ``python -m repro.collect``); the tests do not.  Returns
+    the directory, or None without jax."""
+    if not has_jax():
+        return None
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def available_backends() -> Tuple[str, ...]:
     """Names accepted by :func:`get_backend`, in preference order.
 
-    The pallas tier rides on the same jax install (its kernels fall back
-    to ``interpret=True`` without an accelerator), so both accelerated
-    tiers appear whenever jax imports."""
+    The pallas tier rides on the same jax install (on a CPU platform its
+    kernels run in the Pallas interpreter), so both accelerated tiers
+    appear whenever jax is installed."""
     return ("numpy", "jax", "pallas") if has_jax() else ("numpy",)
 
 

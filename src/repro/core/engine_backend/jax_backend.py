@@ -10,11 +10,12 @@ arrays out — with the array math dispatched through XLA:
   O(log S) depth instead of the NumPy backend's sequential Python loop;
 * the poll-counting closed form is one fused jitted kernel.
 
-Everything is traced under ``jax.experimental.enable_x64`` so float64
-semantics match NumPy bit-for-bit on elementwise arithmetic; only
-reduction/scan association order differs, which is why the parity
-contract is "within one reporting quantum", not bitwise
-(``tests/test_engine_backend.py`` pins it).  Compiled kernels are cached
+Everything is traced under the 64-bit policy of
+:mod:`~repro.core.engine_backend.precision`, so float64 semantics match
+NumPy bit-for-bit on elementwise arithmetic; only reduction/scan
+association order differs, which is why the parity contract is "within
+one reporting quantum", not bitwise (``tests/test_engine_backend.py``
+pins it).  Compiled kernels are cached
 by shape, so repeated trials of a fixed fleet re-use one compilation.
 """
 from __future__ import annotations
@@ -27,14 +28,21 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
+from repro.core.engine_backend import precision as _p
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
                                                TimelineArrays)
 
 name = "jax"
 
 _FAR = np.iinfo(np.int64).max // 2
+
+
+def pad_bucket(n: int, floor: int) -> int:
+    """Padded length for an axis of ``n``: a power of two no smaller
+    than ``floor``, so inputs of varying size share a few compilations
+    (each costs seconds of TPU compile under emulated float64)."""
+    return max(floor, 1 << max(int(n) - 1, 0).bit_length())
 
 
 def _searchsorted_rows(a, v, side: str):
@@ -63,7 +71,7 @@ def _integral_impl(tl: TimelineArrays, t0, t1):
     g = t0.shape[0]
     seg = tl.powers * jnp.diff(tl.edges, axis=1)
     cum = jnp.concatenate(
-        [jnp.zeros((tl.edges.shape[0], 1)), jnp.cumsum(seg, axis=1)],
+        [jnp.zeros((tl.edges.shape[0], 1)), _p.cumsum(seg, axis=1)],
         axis=1)
     tl = _broadcast_rows(tl, g)
     cum = jnp.broadcast_to(cum, (g, cum.shape[1]))
@@ -137,7 +145,7 @@ def _query_slots_impl(sched: ReadingSchedule, tq):
     T = sched.update_period_s[:, None]
     phase = sched.phase[:, None]
     m = sched.ticks.shape[1]
-    j = jnp.floor((tq - phase) / T).astype(jnp.int64) - sched.k0[:, None]
+    j = jnp.floor((tq - phase) / T).astype(_p.INT) - sched.k0[:, None]
     j = jnp.clip(j, 0, m - 1)
     for _ in range(2):
         tj = jnp.take_along_axis(sched.ticks, j, axis=1)
@@ -153,7 +161,7 @@ def _query_slots_impl(sched: ReadingSchedule, tq):
 def _poll_counts_impl(sched: ReadingSchedule, t0, t1, period_s,
                       grid_offset, a, b):
     n = a.shape[0]
-    m_i = jnp.floor((t1 - t0) / period_s).astype(jnp.int64)
+    m_i = jnp.floor((t1 - t0) / period_s).astype(_p.INT)
 
     def q(idx):
         return t0 + period_s * idx
@@ -161,8 +169,8 @@ def _poll_counts_impl(sched: ReadingSchedule, t0, t1, period_s,
     def r(idx):
         return (t0 + period_s * idx) + grid_offset
 
-    j0 = jnp.ceil((a - grid_offset - t0) / period_s).astype(jnp.int64)
-    j1 = jnp.floor((b - grid_offset - t0) / period_s).astype(jnp.int64)
+    j0 = jnp.ceil((a - grid_offset - t0) / period_s).astype(_p.INT)
+    j1 = jnp.floor((b - grid_offset - t0) / period_s).astype(_p.INT)
     for _ in range(2):
         j0 = jnp.where(r(j0 - 1) >= a, j0 - 1, j0)
         j0 = jnp.where(r(j0) < a, j0 + 1, j0)
@@ -174,12 +182,12 @@ def _poll_counts_impl(sched: ReadingSchedule, t0, t1, period_s,
     ticks = sched.ticks
     m = ticks.shape[1]
     slot = jnp.arange(m)[None, :]
-    lo = jnp.ceil((ticks - t0) / period_s).astype(jnp.int64)
+    lo = jnp.ceil((ticks - t0) / period_s).astype(_p.INT)
     for _ in range(2):
         lo = jnp.where(q(lo - 1) >= ticks, lo - 1, lo)
         lo = jnp.where(q(lo) < ticks, lo + 1, lo)
     hi = jnp.concatenate([lo[:, 1:] - 1, jnp.full((n, 1), _FAR)], axis=1)
-    lo = jnp.where(slot == sched.first[:, None], jnp.int64(0), lo)
+    lo = jnp.where(slot == sched.first[:, None], _p.INT(0), lo)
     hi = jnp.where(slot == sched.last[:, None], _FAR, hi)
     counts = (jnp.minimum(hi, (j1 - 1)[:, None])
               - jnp.maximum(lo, j0[:, None]) + 1)
@@ -187,8 +195,8 @@ def _poll_counts_impl(sched: ReadingSchedule, t0, t1, period_s,
              & (slot <= sched.last[:, None]))
     counts = jnp.where(valid, jnp.maximum(counts, 0), 0)
 
-    slot_b = _query_slots_impl(sched, q(j1.astype(jnp.float64))[:, None])
-    tail_dt = b - r(j1.astype(jnp.float64))
+    slot_b = _query_slots_impl(sched, q(j1.astype(_p.FLOAT))[:, None])
+    tail_dt = b - r(j1.astype(_p.FLOAT))
     return counts, slot_b[:, 0], tail_dt, j1 >= j0
 
 
@@ -196,24 +204,24 @@ def _poll_counts_impl(sched: ReadingSchedule, t0, t1, period_s,
 
 def boxcar_means(tl: TimelineArrays, t0: np.ndarray,
                  t1: np.ndarray) -> np.ndarray:
-    with enable_x64():
-        return np.asarray(_boxcar_impl(tl, jnp.asarray(t0, jnp.float64),
-                                       jnp.asarray(t1, jnp.float64)))
+    with _p.x64():
+        return np.asarray(_boxcar_impl(tl, jnp.asarray(t0, _p.FLOAT),
+                                       jnp.asarray(t1, _p.FLOAT)))
 
 
 def estimation_means(tl: TimelineArrays, t0: np.ndarray, t1: np.ndarray,
                      model_gain: np.ndarray) -> np.ndarray:
-    with enable_x64():
+    with _p.x64():
         return np.asarray(_estimation_impl(
-            tl, jnp.asarray(t0, jnp.float64), jnp.asarray(t1, jnp.float64),
-            jnp.asarray(model_gain, jnp.float64)))
+            tl, jnp.asarray(t0, _p.FLOAT), jnp.asarray(t1, _p.FLOAT),
+            jnp.asarray(model_gain, _p.FLOAT)))
 
 
 def timeline_integral(tl: TimelineArrays, t0: np.ndarray,
                       t1: np.ndarray) -> np.ndarray:
-    with enable_x64():
-        return np.asarray(_integral_impl(tl, jnp.asarray(t0, jnp.float64),
-                                         jnp.asarray(t1, jnp.float64)))
+    with _p.x64():
+        return np.asarray(_integral_impl(tl, jnp.asarray(t0, _p.FLOAT),
+                                         jnp.asarray(t1, _p.FLOAT)))
 
 
 def log_filter(tl: TimelineArrays, ticks: np.ndarray,
@@ -224,30 +232,30 @@ def log_filter(tl: TimelineArrays, ticks: np.ndarray,
     t_lo = (min(float(np.min(ticks)), float(np.min(tl.t_start)))
             - 5.0 * float(np.max(tau)))
     t_hi = max(float(np.max(ticks)), float(np.max(tl.t_end))) + 1e-9
-    with enable_x64():
+    with _p.x64():
         return np.asarray(_log_filter_impl(
-            tl, jnp.asarray(ticks, jnp.float64), jnp.asarray(tau),
-            jnp.float64(t_lo), jnp.float64(t_hi)))
+            tl, jnp.asarray(ticks, _p.FLOAT), jnp.asarray(tau),
+            _p.FLOAT(t_lo), _p.FLOAT(t_hi)))
 
 
 def poll_counts(sched: ReadingSchedule, grid: PollGrid, a: np.ndarray,
                 b: np.ndarray) -> Tuple[np.ndarray, np.ndarray,
                                         np.ndarray, np.ndarray]:
-    with enable_x64():
+    with _p.x64():
         counts, slot_b, tail_dt, nonempty = _poll_counts_impl(
-            sched, jnp.float64(grid.t0),
-            jnp.asarray(grid.t1, jnp.float64),
-            jnp.float64(grid.period_s),
-            jnp.asarray(grid.grid_offset, jnp.float64),
-            jnp.asarray(a, jnp.float64), jnp.asarray(b, jnp.float64))
+            sched, _p.FLOAT(grid.t0),
+            jnp.asarray(grid.t1, _p.FLOAT),
+            _p.FLOAT(grid.period_s),
+            jnp.asarray(grid.grid_offset, _p.FLOAT),
+            jnp.asarray(a, _p.FLOAT), jnp.asarray(b, _p.FLOAT))
     return (np.asarray(counts), np.asarray(slot_b),
             np.asarray(tail_dt), np.asarray(nonempty))
 
 
 def query_slots(sched: ReadingSchedule, tq: np.ndarray) -> np.ndarray:
-    with enable_x64():
+    with _p.x64():
         return np.asarray(_query_slots_impl(
-            sched, jnp.asarray(tq, jnp.float64)))
+            sched, jnp.asarray(tq, _p.FLOAT)))
 
 
 @functools.partial(jax.jit, static_argnums=(4,))
@@ -264,7 +272,7 @@ def _step_integrate_impl(ts, vals, t0, t1, trapezoid: bool):
     else:
         dens = vals[:, :-1]
     cum = jnp.concatenate(
-        [jnp.zeros((n, 1)), jnp.cumsum(dens * dt, axis=1)], axis=1)
+        [jnp.zeros((n, 1)), _p.cumsum(dens * dt, axis=1)], axis=1)
 
     j0c = jnp.clip(j0, 0, m - 1)[:, None]
     j1c = jnp.clip(j1, 0, m - 1)[:, None]
@@ -283,11 +291,22 @@ def step_integrate(ts: np.ndarray, vals: np.ndarray, t0: np.ndarray,
     ts = np.asarray(ts, dtype=np.float64)
     if ts.shape[1] == 0:    # no samples at all: every window is 0
         return np.zeros(ts.shape[0])
-    with enable_x64():
+    with _p.x64():
         return np.asarray(_step_integrate_impl(
-            jnp.asarray(ts, jnp.float64), jnp.asarray(vals, jnp.float64),
-            jnp.asarray(t0, jnp.float64), jnp.asarray(t1, jnp.float64),
+            jnp.asarray(ts, _p.FLOAT), jnp.asarray(vals, _p.FLOAT),
+            jnp.asarray(t0, _p.FLOAT), jnp.asarray(t1, _p.FLOAT),
             bool(trapezoid)))
+
+
+def ingest_prev(t, v, seg, first, prev_t, prev_v, has_prev):
+    """Each sample's predecessor ``(pt, pv, has)``: the previous sample
+    within the slab, or the stored state at group starts."""
+    shift_t = jnp.concatenate([jnp.zeros(1, t.dtype), t[:-1]])
+    shift_v = jnp.concatenate([jnp.zeros(1, v.dtype), v[:-1]])
+    pt = jnp.where(first, prev_t[seg], shift_t)
+    pv = jnp.where(first, prev_v[seg], shift_v)
+    has = jnp.where(first, has_prev[seg], True)
+    return pt, pv, has
 
 
 @functools.partial(jax.jit, static_argnums=(19,))
@@ -295,16 +314,7 @@ def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
                         prev_v, has_prev, run_t, n_changes, gain, offset,
                         tshift, win_a, win_b, max_hold, env_lo, env_hi,
                         trapezoid: bool):
-    k = t.shape[0]
-    u = prev_t.shape[0]
-    idx = jnp.arange(k)
-
-    shift_t = jnp.concatenate([jnp.zeros(1), t[:-1]])
-    shift_v = jnp.concatenate([jnp.zeros(1), v[:-1]])
-    pt = jnp.where(first, prev_t[seg], shift_t)
-    pv = jnp.where(first, prev_v[seg], shift_v)
-    has = jnp.where(first, has_prev[seg], True)
-
+    pt, pv, has = ingest_prev(t, v, seg, first, prev_t, prev_v, has_prev)
     g = gain[seg]
     off = offset[seg]
     vc = (v - off) / g
@@ -316,13 +326,6 @@ def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
     inc = jnp.where(has, dens_r * hold, 0.0)
     inc_c = jnp.where(has, dens_c * hold, 0.0)
 
-    cs = jnp.cumsum(inc)
-    cum_e = cs - (cs[start_idx] - inc[start_idx])[seg]
-    csc = jnp.cumsum(inc_c)
-    cum_ec = csc - (csc[start_idx] - inc_c[start_idx])[seg]
-    d_energy = cum_e[end_idx]
-    d_energy_corr = cum_ec[end_idx]
-
     a = win_a[seg]
     b = win_b[seg]
     w_inc = jnp.where(
@@ -332,6 +335,27 @@ def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
     w_inc_c = jnp.where(
         has & (pts >= a),
         dens_c * jnp.maximum(jnp.minimum(pts + hold, b) - pts, 0.0), 0.0)
+    change = has & (v != pv)
+    out = (vc < env_lo[seg]) | (vc > env_hi[seg])
+    return ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes,
+                       inc, inc_c, w_inc, w_inc_c, vc, change, out)
+
+
+def ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes, inc,
+                inc_c, w_inc, w_inc_c, vc, change, out) -> Tuple:
+    """Fold one slab's per-sample increments into per-group results (the
+    second half of :func:`_stream_ingest_impl`, shared with the pallas
+    tier, whose kernel computes the per-sample part)."""
+    k = t.shape[0]
+    u = start_idx.shape[0]
+    idx = jnp.arange(k)
+
+    cs = _p.cumsum(inc)
+    cum_e = cs - (cs[start_idx] - inc[start_idx])[seg]
+    csc = _p.cumsum(inc_c)
+    cum_ec = csc - (csc[start_idx] - inc_c[start_idx])[seg]
+    d_energy = cum_e[end_idx]
+    d_energy_corr = cum_ec[end_idx]
     d_win = jax.ops.segment_sum(w_inc, seg, num_segments=u)
     d_win_corr = jax.ops.segment_sum(w_inc_c, seg, num_segments=u)
 
@@ -342,11 +366,10 @@ def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
     # (slot 0 reads the -1/unused sentinel when there is none).  The
     # pre-slab maximum is carried in the monitor state (``run_t``), so
     # per-slab work stays O(slab) with O(1) scatter/gather passes.
-    change = has & (v != pv)
-    chg_i = change.astype(jnp.int64)
-    cchg = jnp.cumsum(chg_i)
+    chg_i = change.astype(_p.INT)
+    cchg = _p.cumsum(chg_i)
     slot = jnp.where(change, cchg, k + 1)
-    pch = jnp.full(k + 2, -1, dtype=jnp.int64).at[slot].set(
+    pch = jnp.full(k + 2, -1, dtype=_p.INT).at[slot].set(
         jnp.where(change, idx, -1))
     tch = jnp.zeros(k + 2).at[slot].set(jnp.where(change, t, 0.0))
     prev_ord = cchg - chg_i
@@ -361,13 +384,12 @@ def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
     new_run_t = jnp.where(pch[ord_last] >= start_idx,
                           tch[ord_last], run_t)
     new_n_changes = n_changes + jax.ops.segment_sum(
-        change.astype(jnp.int64), seg, num_segments=u)
+        change.astype(_p.INT), seg, num_segments=u)
 
-    counts = jax.ops.segment_sum(jnp.ones(k, dtype=jnp.int64), seg,
+    counts = jax.ops.segment_sum(jnp.ones(k, dtype=_p.INT), seg,
                                  num_segments=u)
     sum_vc = jax.ops.segment_sum(vc, seg, num_segments=u)
-    out = ((vc < env_lo[seg]) | (vc > env_hi[seg])).astype(jnp.int64)
-    n_out = jax.ops.segment_sum(out, seg, num_segments=u)
+    n_out = jax.ops.segment_sum(out.astype(_p.INT), seg, num_segments=u)
 
     return (t[end_idx], v[end_idx], new_run_t, new_n_changes, counts,
             d_energy, d_energy_corr, d_win, d_win_corr, sum_vc, n_out,
@@ -379,29 +401,55 @@ def stream_ingest(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
                   win_a, win_b, max_hold, env_lo, env_hi,
                   trapezoid: bool = False) -> Tuple:
     """Streaming-monitor ingest slab (see the numpy backend's reference
-    docstring), fused into one jitted kernel; compiled once per
-    (K, U) slab shape, so a fixed-tick replay reuses one compilation."""
-    with enable_x64():
-        outs = _stream_ingest_impl(
-            jnp.asarray(t, jnp.float64), jnp.asarray(v, jnp.float64),
-            jnp.asarray(seg, jnp.int64), jnp.asarray(first, jnp.bool_),
-            jnp.asarray(start_idx, jnp.int64),
-            jnp.asarray(end_idx, jnp.int64),
-            jnp.asarray(prev_t, jnp.float64),
-            jnp.asarray(prev_v, jnp.float64),
-            jnp.asarray(has_prev, jnp.bool_),
-            jnp.asarray(run_t, jnp.float64),
-            jnp.asarray(n_changes, jnp.int64),
-            jnp.asarray(gain, jnp.float64),
-            jnp.asarray(offset, jnp.float64),
-            jnp.asarray(tshift, jnp.float64),
-            jnp.asarray(win_a, jnp.float64),
-            jnp.asarray(win_b, jnp.float64),
-            jnp.asarray(max_hold, jnp.float64),
-            jnp.asarray(env_lo, jnp.float64),
-            jnp.asarray(env_hi, jnp.float64),
-            bool(trapezoid))
-    return tuple(np.asarray(o) for o in outs)
+    docstring), fused into one jitted kernel."""
+    return ingest_padded(_stream_ingest_impl, t, v, seg, first, start_idx,
+                         end_idx, prev_t, prev_v, has_prev, run_t,
+                         n_changes, gain, offset, tshift, win_a, win_b,
+                         max_hold, env_lo, env_hi, bool(trapezoid))
+
+
+def ingest_padded(impl, t, v, seg, first, start_idx, end_idx, prev_t,
+                  prev_v, has_prev, run_t, n_changes, gain, offset, tshift,
+                  win_a, win_b, max_hold, env_lo, env_hi, *static) -> Tuple:
+    """Run the jitted slab kernel ``impl`` under 64-bit mode on the slab
+    padded to power-of-two ``(K, U)`` buckets (the tail samples form one
+    extra, inert group), so slabs of varying size share a few
+    compilations; ``static`` follows the 19 array arguments."""
+    k, u = len(t), len(start_idx)
+    kp, up = pad_bucket(k, 1024), pad_bucket(u + 1, 8)
+
+    def pad(x, n, fill):
+        x = np.asarray(x)
+        return np.concatenate([x, np.full(n - len(x), fill, x.dtype)])
+
+    t = np.asarray(t, dtype=np.float64)
+    first = pad(first, kp, False)
+    first[k:k + 1] = True
+    with _p.x64():
+        outs = impl(
+            jnp.asarray(pad(t, kp, t[-1] if k else 0.0), _p.FLOAT),
+            jnp.asarray(pad(np.asarray(v, np.float64), kp, 0.0), _p.FLOAT),
+            jnp.asarray(pad(seg, kp, u), _p.INT),
+            jnp.asarray(first, jnp.bool_),
+            jnp.asarray(np.concatenate(
+                [start_idx, [k], np.full(up - u - 1, kp - 1)]), _p.INT),
+            jnp.asarray(pad(end_idx, up, kp - 1), _p.INT),
+            jnp.asarray(pad(prev_t, up, 0.0), _p.FLOAT),
+            jnp.asarray(pad(prev_v, up, 0.0), _p.FLOAT),
+            jnp.asarray(pad(has_prev, up, False), jnp.bool_),
+            jnp.asarray(pad(run_t, up, 0.0), _p.FLOAT),
+            jnp.asarray(pad(n_changes, up, 0), _p.INT),
+            jnp.asarray(pad(gain, up, 1.0), _p.FLOAT),
+            jnp.asarray(pad(offset, up, 0.0), _p.FLOAT),
+            jnp.asarray(pad(tshift, up, 0.0), _p.FLOAT),
+            jnp.asarray(pad(win_a, up, np.inf), _p.FLOAT),
+            jnp.asarray(pad(win_b, up, -np.inf), _p.FLOAT),
+            jnp.asarray(pad(max_hold, up, 0.0), _p.FLOAT),
+            jnp.asarray(pad(env_lo, up, -np.inf), _p.FLOAT),
+            jnp.asarray(pad(env_hi, up, np.inf), _p.FLOAT),
+            *static)
+    return (tuple(np.asarray(o)[:u] for o in outs[:11])
+            + tuple(np.asarray(o)[:k] for o in outs[11:]))
 
 
 @functools.partial(jax.jit, static_argnums=(15,))
@@ -427,8 +475,8 @@ def _stream_ingest_grid_impl(ts, v, prev_t, prev_v, has_prev, run_t,
     dens_c = 0.5 * (pvc + vc) if trapezoid else pvc
     inc = jnp.where(has, dens_r * hold, 0.0)
     inc_c = jnp.where(has, dens_c * hold, 0.0)
-    cum_e = jnp.cumsum(inc, axis=1)
-    cum_ec = jnp.cumsum(inc_c, axis=1)
+    cum_e = _p.cumsum(inc, axis=1)
+    cum_ec = _p.cumsum(inc_c, axis=1)
 
     a = win_a[:, None]
     b = win_b[:, None]
@@ -446,14 +494,14 @@ def _stream_ingest_grid_impl(ts, v, prev_t, prev_v, has_prev, run_t,
     # gathers from the 1-D ``ts``, no scatters (XLA CPU scatters are
     # serial and dominated this kernel's profile)
     change = has & (v != pv)
-    chg_i = change.astype(jnp.int64)
-    cchg = jnp.cumsum(chg_i, axis=1)
+    chg_i = change.astype(_p.INT)
+    cchg = _p.cumsum(chg_i, axis=1)
     tsb = jnp.broadcast_to(ts[None, :], (d, m))
-    cols = lax.broadcasted_iota(jnp.int64, (d, m), 1)
-    ci = jnp.where(change, cols, jnp.int64(-1))
-    acc = lax.cummax(ci, axis=1)                  # last change ≤ col j
+    cols = lax.broadcasted_iota(_p.INT, (d, m), 1)
+    ci = jnp.where(change, cols, _p.INT(-1))
+    acc = _p.cummax(ci, axis=1)                  # last change ≤ col j
     acc_excl = jnp.concatenate(
-        [jnp.full((d, 1), -1, dtype=jnp.int64), acc[:, :-1]], axis=1)
+        [jnp.full((d, 1), -1, dtype=_p.INT), acc[:, :-1]], axis=1)
     run_start = jnp.where(acc_excl >= 0, ts[jnp.maximum(acc_excl, 0)],
                           run_t[:, None])
     run_dur = jnp.where(change, tsb - run_start, 0.0)
@@ -470,7 +518,7 @@ def _stream_ingest_grid_impl(ts, v, prev_t, prev_v, has_prev, run_t,
             jnp.sum(w_inc, axis=1), jnp.sum(w_inc_c, axis=1),
             jnp.sum(vc, axis=1), jnp.sum(vc * vc, axis=1),
             jnp.sum(av, axis=1), jnp.max(av, axis=1),
-            jnp.sum(out, axis=1).astype(jnp.int64),
+            jnp.sum(out, axis=1).astype(_p.INT),
             cum_e, cum_ec, run_dur, run_rec)
 
 
@@ -492,22 +540,22 @@ def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
                 np.zeros(d), np.zeros(d), np.zeros(d), np.zeros(d),
                 np.zeros(d, dtype=np.int64), z, z, z,
                 np.zeros((d, 0), dtype=bool))
-    with enable_x64():
+    with _p.x64():
         outs = _stream_ingest_grid_impl(
-            jnp.asarray(ts, jnp.float64), jnp.asarray(v, jnp.float64),
-            jnp.asarray(prev_t, jnp.float64),
-            jnp.asarray(prev_v, jnp.float64),
+            jnp.asarray(ts, _p.FLOAT), jnp.asarray(v, _p.FLOAT),
+            jnp.asarray(prev_t, _p.FLOAT),
+            jnp.asarray(prev_v, _p.FLOAT),
             jnp.asarray(has_prev, jnp.bool_),
-            jnp.asarray(run_t, jnp.float64),
-            jnp.asarray(n_changes, jnp.int64),
-            jnp.asarray(gain, jnp.float64),
-            jnp.asarray(offset, jnp.float64),
-            jnp.asarray(tshift, jnp.float64),
-            jnp.asarray(win_a, jnp.float64),
-            jnp.asarray(win_b, jnp.float64),
-            jnp.asarray(max_hold, jnp.float64),
-            jnp.asarray(env_lo, jnp.float64),
-            jnp.asarray(env_hi, jnp.float64),
+            jnp.asarray(run_t, _p.FLOAT),
+            jnp.asarray(n_changes, _p.INT),
+            jnp.asarray(gain, _p.FLOAT),
+            jnp.asarray(offset, _p.FLOAT),
+            jnp.asarray(tshift, _p.FLOAT),
+            jnp.asarray(win_a, _p.FLOAT),
+            jnp.asarray(win_b, _p.FLOAT),
+            jnp.asarray(max_hold, _p.FLOAT),
+            jnp.asarray(env_lo, _p.FLOAT),
+            jnp.asarray(env_hi, _p.FLOAT),
             bool(trapezoid))
     return tuple(np.asarray(o) for o in outs)
 
@@ -525,9 +573,9 @@ def err_moments(e: np.ndarray):
     e = np.asarray(e, dtype=np.float64)
     if e.size == 0:
         return 0, 0.0, 0.0, 0.0, 0.0
-    with enable_x64():
+    with _p.x64():
         mean, m2, mean_abs, max_abs = _err_moments_impl(
-            jnp.asarray(e, jnp.float64))
+            jnp.asarray(e, _p.FLOAT))
     return (int(e.size), float(mean), float(m2), float(mean_abs),
             float(max_abs))
 
@@ -571,13 +619,17 @@ def snapshot_energy_at(tq: np.ndarray, last_t: np.ndarray,
     if not with_ring:
         r = np.zeros((last_t.shape[0], 0))
         ring_t = ring_dens = ring_base = r
-    with enable_x64():
+    tq = np.asarray(tq, dtype=np.float64)
+    q = tq.shape[0]
+    tq = np.concatenate([tq, np.full(pad_bucket(q, 8) - q,
+                                     tq[-1] if q else 0.0)])
+    with _p.x64():
         e, covered = _snapshot_energy_at_impl(
-            jnp.asarray(tq, jnp.float64), jnp.asarray(last_t, jnp.float64),
-            jnp.asarray(dens, jnp.float64), jnp.asarray(has, jnp.bool_),
-            jnp.asarray(first_t, jnp.float64), jnp.asarray(base, jnp.float64),
-            jnp.asarray(max_hold, jnp.float64),
-            jnp.asarray(ring_t, jnp.float64),
-            jnp.asarray(ring_dens, jnp.float64),
-            jnp.asarray(ring_base, jnp.float64), with_ring)
-    return np.asarray(e), np.asarray(covered)
+            jnp.asarray(tq, _p.FLOAT), jnp.asarray(last_t, _p.FLOAT),
+            jnp.asarray(dens, _p.FLOAT), jnp.asarray(has, jnp.bool_),
+            jnp.asarray(first_t, _p.FLOAT), jnp.asarray(base, _p.FLOAT),
+            jnp.asarray(max_hold, _p.FLOAT),
+            jnp.asarray(ring_t, _p.FLOAT),
+            jnp.asarray(ring_dens, _p.FLOAT),
+            jnp.asarray(ring_base, _p.FLOAT), with_ring)
+    return np.asarray(e)[:q], np.asarray(covered)[:q]

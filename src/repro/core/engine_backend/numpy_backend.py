@@ -161,8 +161,25 @@ def log_filter(tl: TimelineArrays, ticks: np.ndarray,
     ``idle_w`` (the ``t_lo`` padding only ever covers idle), so readings
     are bitwise identical to the scalar filter for any padding choice.
     """
-    g, _ = ticks.shape
+    ext_e, ext_p, dts = log_filter_segments(tl, ticks, tau)
+    g, n_seg = ticks.shape[0], ext_p.shape[1]
     tau = np.asarray(tau, dtype=np.float64)
+    y = np.empty((g, n_seg + 1))
+    y[:, 0] = np.broadcast_to(tl.idle_w, (g,))
+    for i in range(n_seg):
+        dt = dts[:, i]
+        sp = ext_p[:, i]
+        step = sp + (y[:, i] - sp) * np.exp(-dt / tau)
+        y[:, i + 1] = np.where(dt > 0, step, y[:, i])
+    return log_filter_readout(y, ext_e, ext_p, ticks, tau)
+
+
+def log_filter_segments(tl: TimelineArrays, ticks: np.ndarray,
+                        tau: np.ndarray):
+    """The filter's segment sequence: edges ``ext_e`` [R, S+3] padded by
+    an idle segment on each side (wide enough that the state has settled
+    at ``idle_w`` before the first real edge), their powers ``ext_p``
+    [R, S+2] and widths ``dts`` [R, S+2]."""
     t_lo = (min(float(np.min(ticks)), float(np.min(tl.t_start)))
             - 5.0 * float(np.max(tau)))
     t_hi = max(float(np.max(ticks)), float(np.max(tl.t_end))) + 1e-9
@@ -171,17 +188,15 @@ def log_filter(tl: TimelineArrays, ticks: np.ndarray,
                             np.full((r, 1), t_hi)], axis=1)
     ext_p = np.concatenate([tl.idle_w[:, None], tl.powers,
                             tl.idle_w[:, None]], axis=1)
+    return ext_e, ext_p, np.diff(ext_e, axis=1)
+
+
+def log_filter_readout(y: np.ndarray, ext_e: np.ndarray, ext_p: np.ndarray,
+                       ticks: np.ndarray, tau: np.ndarray) -> np.ndarray:
+    """Readings at ``ticks`` from the filter state ``y`` [G, S+3] at each
+    segment entry: decay from the entry state of the tick's segment."""
+    g = ticks.shape[0]
     n_seg = ext_p.shape[1]
-    dts = np.diff(ext_e, axis=1)
-
-    y = np.empty((g, n_seg + 1))
-    y[:, 0] = np.broadcast_to(tl.idle_w, (g,))
-    for i in range(n_seg):
-        dt = dts[:, i]
-        sp = ext_p[:, i]
-        step = sp + (y[:, i] - sp) * np.exp(-dt / tau)
-        y[:, i + 1] = np.where(dt > 0, step, y[:, i])
-
     idx = np.clip(searchsorted_rows(ext_e, ticks, side="right") - 1,
                   0, n_seg - 1)
     y_at = np.take_along_axis(y, idx, axis=1)

@@ -2,43 +2,42 @@
 
 Same signatures, same semantics as
 :mod:`repro.core.engine_backend.numpy_backend` — NumPy arrays in, NumPy
-arrays out — with the three streaming hot loops fused into
-``pl.pallas_call`` kernels:
+arrays out — with the per-sample arithmetic of four kernels in
+``pl.pallas_call``\\ s that compile for the TPU:
 
-* ``stream_ingest`` — jax prologue (per-sample parameter gathers, shift
-  of previous sample/time across group firsts) feeding a 1-D blocked
-  kernel that fuses the hold/window/envelope elementwise math with the
-  running energy cumsums, carried across blocks in VMEM scratch; a jax
-  epilogue re-bases the cumsums at group starts and does the segment
-  reductions and run tracking;
-* ``stream_ingest_grid`` — the rectangular fast path: one fused
-  row-block kernel per device block computing everything (cumulative
-  energies, window overlaps, run tracking via an in-kernel ``cummax``
-  over change columns, and the per-device moment reductions) in a
-  single pass over the ``[block_d, M]`` slab;
-* ``step_integrate`` — row-blocked kernel; the window edges are located
-  by counting (``sum(ts < t0)``), which equals binary search on the
-  sorted, inf-padded rows but vectorises cleanly inside the kernel;
+* ``stream_ingest_grid`` — the rectangular fast path.  Devices lie on
+  the vector lanes (``[M, D/128, 128]`` tiles) and the kernel walks the
+  shared tick axis in a loop, so the running energies, the run tracking
+  (a carried run-start time and last-change column) and the per-device
+  moments are loop carries: no in-kernel scan, gather or cross-lane
+  reduction.  Long slabs are split along the tick axis, with the carries
+  kept in VMEM scratch and in the resident per-device output blocks.
+* ``stream_ingest`` — the general (sorted, segmented) path: one fused
+  elementwise kernel over ``[K/128, 128]`` tiles (correction, hold,
+  window clipping, change and envelope flags) inside a float64 jit that
+  forms each sample's predecessor, gap and window openings before it
+  and runs the jax tier's fold (group-re-based prefix sums, segment
+  sums, run tracking) after it, all on the device.
+* ``step_integrate`` — row-blocked masked sums over the sample axis; the
+  window edges come from an exact float64 search on the host.
 * ``log_filter`` — the affine recurrence ``y_{i+1} = a_i·y_i + b_i`` as
-  a blocked sequential scan over segment chunks (grid iterates the
-  segment axis innermost; VMEM scratch carries the filter state), the
-  same idiom as :mod:`repro.kernels.rglru_scan`.
+  a blocked sequential scan over segment chunks (the grid walks the
+  segment axis innermost; VMEM scratch carries the filter state).
 
-Gather-bound kernels with no streaming inner loop (``boxcar_means``,
-``poll_counts``, ``query_slots``, …) delegate to the jax tier — they are
-binary-search + take_along_axis compositions XLA already fuses well, and
-a Pallas rewrite would only re-derive the same gathers.
-
-All kernels run under ``interpret=True`` when no accelerator is present
-(or when ``REPRO_PALLAS_INTERPRET`` is set), so the tier is exercised on
-CPU-only CI with identical float64 semantics.  Kernel construction
-happens inside ``jax.jit`` so each (shape, flags) combination compiles
-once and replays from the jit cache.
+Kernels run in 32 bits (:mod:`~repro.core.engine_backend.precision`):
+times enter relative to a float64 per-slab anchor, gaps are formed in
+float64 before the cast, kernels return per-slab increments, and these
+are re-based into float64 totals and counts widened to int64 outside
+the ``pallas_call`` (on the host, or for ``stream_ingest`` in the
+float64 jit around it).
+Where the platform is the CPU the same kernels run in the Pallas
+interpreter.  Gather-bound kernels with no streaming inner loop
+(``boxcar_means``, ``poll_counts``, ``snapshot_energy_at``, …) are the
+jax tier's.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import numpy as np
@@ -46,22 +45,25 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.engine_backend import jax_backend as _jb
 from repro.core.engine_backend import numpy_backend as _nb
+from repro.core.engine_backend import precision as _p
 
 name = "pallas"
 
-# block sizes: 1-D ingest blocks and the log-filter (chunk, group) tile
-# are padded to these; the grid ingest kernel blocks only the device axis
-_INGEST_BLOCK = 32768
-_GRID_BLOCK_D = 4096
+_LANES = 128
+# grid ingest: devices per block are _GRID_SUBLANES·128, ticks per block
+# at most _GRID_TICKS (longer slabs loop over tick blocks)
+_GRID_SUBLANES = 8
+_GRID_TICKS = 64
+# flat ingest: rows of 128 samples per block
+_FLAT_ROWS = 256
+_STEP_ROWS = 256
 _SCAN_CHUNK = 64
-_SCAN_BLOCK_G = 512
-_STEP_BLOCK_N = 1024
+_SCAN_LANES = 512
 
 # gather-bound kernels: same jitted jax implementations, re-exported
 boxcar_means = _jb.boxcar_means
@@ -74,165 +76,313 @@ snapshot_energy_at = _jb.snapshot_energy_at
 
 
 def _interpret() -> bool:
-    """True when kernels should run via the Pallas interpreter.
-
-    ``REPRO_PALLAS_INTERPRET`` overrides (any value but ``0``/``false``
-    forces interpret mode, ``0`` forces compiled mode); otherwise
-    interpret exactly when the default jax backend is the CPU.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("", "0", "false", "no")
+    """Kernels run in the Pallas interpreter exactly when jax's platform
+    is the CPU."""
     return jax.default_backend() == "cpu"
 
 
-def _pad_to(x, n, value):
-    k = x.shape[0]
-    if k == n:
-        return x
-    return jnp.concatenate(
-        [x, jnp.full((n - k,), value, dtype=x.dtype)])
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-# -- stream_ingest: 1-D blocked elementwise + carried cumsums ---------------
+def _k32(x, n: int, fill, dtype=_p.KFLOAT) -> np.ndarray:
+    """``x`` cast to a kernel dtype and padded with ``fill`` to ``n``
+    along the first axis."""
+    x = np.asarray(x)
+    out = np.full((n,) + x.shape[1:], fill, dtype=dtype)
+    out[:x.shape[0]] = x
+    return out
 
-def _ingest1d_kernel(t_ref, v_ref, pt_ref, pv_ref, has_ref, g_ref,
-                     off_ref, tsh_ref, wa_ref, wb_ref, mh_ref, el_ref,
-                     eh_ref, inc_ref, incc_ref, cs_ref, csc_ref,
-                     cchg_ref, wi_ref, wic_ref, vc_ref, chg_ref,
-                     out_ref, carry, *, trapezoid: bool):
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+def _first_at_least(ts: np.ndarray, a: np.ndarray, shift) -> np.ndarray:
+    """Per row, the first index ``i`` with ``ts[i] - shift >= a`` (``len(ts)``
+    if none), decided in float64 by a vectorised binary search on that
+    exact predicate — a window edge that coincides with a poll instant
+    must fall on the same side as in the float64 reference."""
+    m = ts.shape[0]
+    shift = np.broadcast_to(shift, a.shape)
+    lo = np.zeros(a.shape, dtype=np.int64)
+    hi = np.full(a.shape, m, dtype=np.int64)
+    for _ in range(max(m, 1).bit_length()):
+        mid = np.minimum((lo + hi) // 2, m - 1)
+        ok = ts[mid] - shift >= a
+        live = lo < hi
+        hi = np.where(live & ok, mid, hi)
+        lo = np.where(live & ~ok, mid + 1, lo)
+    return lo
+
+
+# -- stream_ingest_grid: devices on lanes, loop over ticks ------------------
+
+def _grid_kernel(ts_ref, dts_ref, v_ref, pt0_ref, dt0_ref, pv0_ref,
+                 has0_ref, rt_ref, nchp_ref, g_ref, off_ref, tsh_ref,
+                 ja_ref, jac_ref, wb_ref, mh_ref, el_ref, eh_ref,
+                 ce_ref, cec_ref, rd_ref, rr_ref,
+                 de_ref, dec_ref, dw_ref, dwc_ref, sv_ref, sv2_ref, sa_ref,
+                 mx_ref, no_ref, last_ref, nchg_ref,
+                 pv_s, rs_s, *, trapezoid: bool, m_real: int, tm: int):
+    mi = pl.program_id(1)
+    f32, i32 = _p.KFLOAT, _p.KINT
+    sums = (de_ref, dec_ref, dw_ref, dwc_ref, sv_ref, sv2_ref, sa_ref,
+            mx_ref)
+
+    @pl.when(mi == 0)
     def _init():
-        carry[...] = jnp.zeros_like(carry)
+        pv_s[...] = pv0_ref[...]
+        rs_s[...] = rt_ref[...]
+        for r in sums:
+            r[...] = jnp.zeros(r.shape, f32)
+        no_ref[...] = jnp.zeros(no_ref.shape, i32)
+        nchg_ref[...] = jnp.zeros(nchg_ref.shape, i32)
+        last_ref[...] = jnp.full(last_ref.shape, -1, i32)
 
-    t = t_ref[...]
-    v = v_ref[...]
-    pt = pt_ref[...]
-    pv = pv_ref[...]
-    has = has_ref[...]
     g = g_ref[...]
     off = off_ref[...]
+    tsh = tsh_ref[...]
+    ja = ja_ref[...]
+    jac = jac_ref[...]
+    b = wb_ref[...]
+    mh = mh_ref[...]
+    el = el_ref[...]
+    eh = eh_ref[...]
+    nch_pos = nchp_ref[...] != 0
+    pt0 = pt0_ref[...]
+    dt0 = dt0_ref[...]
+    has0 = has0_ref[...] != 0
+
+    def body(jj, c):
+        pv, rs, ce, cec, dw, dwc, sv, sv2, sa, mx, no, last, nchg = c
+        j = mi * tm + jj
+        first = j == 0
+        valid = j < m_real
+        v = v_ref[jj]
+        t_j = ts_ref[j]
+        pt = jnp.where(first, pt0, ts_ref[jnp.maximum(j - 1, 0)])
+        dt = jnp.where(first, dt0, dts_ref[j])
+        has = (has0 | (j != 0)) & valid
+
+        vc = (v - off) / g
+        pvc = (pv - off) / g
+        hold = jnp.minimum(dt, mh)
+        dens_r = 0.5 * (pv + v) if trapezoid else pv
+        dens_c = 0.5 * (pvc + vc) if trapezoid else pvc
+        ce = ce + jnp.where(has, dens_r * hold, 0.0)
+        cec = cec + jnp.where(has, dens_c * hold, 0.0)
+        ce_ref[jj] = ce
+        cec_ref[jj] = cec
+        dw = dw + jnp.where(
+            has & (j >= ja),
+            dens_r * jnp.maximum(jnp.minimum(hold, b - pt), 0.0), 0.0)
+        pts = pt - tsh
+        dwc = dwc + jnp.where(
+            has & (j >= jac),
+            dens_c * jnp.maximum(jnp.minimum(hold, b - pts), 0.0), 0.0)
+
+        change = has & (v != pv)
+        rd_ref[jj] = jnp.where(change, t_j - rs, 0.0)
+        rr_ref[jj] = (change & (nch_pos | (nchg >= 1))).astype(i32)
+        nchg = nchg + change.astype(i32)
+        last = jnp.where(change, j, last)
+        rs = jnp.where(change, t_j, rs)
+
+        av = jnp.abs(vc)
+        sv = sv + jnp.where(valid, vc, 0.0)
+        sv2 = sv2 + jnp.where(valid, vc * vc, 0.0)
+        sa = sa + jnp.where(valid, av, 0.0)
+        mx = jnp.where(valid, jnp.maximum(mx, av), mx)
+        no = no + (valid & ((vc < el) | (vc > eh))).astype(i32)
+        pv = jnp.where(valid, v, pv)
+        return pv, rs, ce, cec, dw, dwc, sv, sv2, sa, mx, no, last, nchg
+
+    carry = (pv_s[...], rs_s[...], de_ref[...], dec_ref[...], dw_ref[...],
+             dwc_ref[...], sv_ref[...], sv2_ref[...], sa_ref[...],
+             mx_ref[...], no_ref[...], last_ref[...], nchg_ref[...])
+    (pv, rs, ce, cec, dw, dwc, sv, sv2, sa, mx, no, last,
+     nchg) = lax.fori_loop(0, tm, body, carry)
+    pv_s[...] = pv
+    rs_s[...] = rs
+    for r, x in zip(sums, (ce, cec, dw, dwc, sv, sv2, sa, mx)):
+        r[...] = x
+    no_ref[...] = no
+    last_ref[...] = last
+    nchg_ref[...] = nchg
+
+
+@functools.partial(jax.jit, static_argnums=(18, 19, 20))
+def _grid_call(ts, dts, v, pt0, dt0, pv0, has0, rt, nchp, g, off, tsh, ja,
+               jac, wb, mh, el, eh, trapezoid: bool, m_real: int,
+               interpret: bool):
+    """``v`` [Dp, Mp] → per-tick outputs [Dp, Mp], per-device [Dp]."""
+    dp, mp = v.shape
+    rows = dp // _LANES
+    tm = min(mp, _GRID_TICKS)
+    bs = min(_GRID_SUBLANES, rows)
+    tile = lambda x: x.reshape(rows, _LANES)
+    vt = v.T.reshape(mp, rows, _LANES)
+    per_dev = [tile(x) for x in (pt0, dt0, pv0, has0, rt, nchp, g, off,
+                                 tsh, ja, jac, wb, mh, el, eh)]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tick = pl.BlockSpec((tm, bs, _LANES), lambda i, m: (m, i, 0))
+    row = pl.BlockSpec((bs, _LANES), lambda i, m: (i, 0))
+    f32, i32 = _p.KFLOAT, _p.KINT
+    slab = lambda dt: jax.ShapeDtypeStruct((mp, rows, _LANES), dt)
+    vec = lambda dt: jax.ShapeDtypeStruct((rows, _LANES), dt)
+    outs = pl.pallas_call(
+        functools.partial(_grid_kernel, trapezoid=trapezoid,
+                          m_real=m_real, tm=tm),
+        grid=(rows // bs, mp // tm),
+        in_specs=[smem, smem, tick] + [row] * 15,
+        out_specs=[tick] * 4 + [row] * 11,
+        out_shape=[slab(f32)] * 3 + [slab(i32)] + [vec(f32)] * 8
+        + [vec(i32)] * 3,
+        scratch_shapes=[pltpu.VMEM((bs, _LANES), f32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(ts, dts, vt, *per_dev)
+    per_tick = [o.reshape(mp, dp).T for o in outs[:4]]
+    return tuple(per_tick) + tuple(o.reshape(dp) for o in outs[4:])
+
+
+def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
+                       gain, offset, tshift, win_a, win_b, max_hold,
+                       env_lo, env_hi, trapezoid: bool = False) -> Tuple:
+    """Rectangular-slab streaming ingest (see the numpy backend's
+    reference docstring) as one Pallas kernel over device-lane tiles."""
+    ts = np.asarray(ts, dtype=np.float64)
+    v = np.asarray(v, dtype=np.float64)
+    d, m = v.shape
+    if m == 0 or d == 0:
+        return _nb.stream_ingest_grid(
+            ts, v, prev_t, prev_v, has_prev, run_t, n_changes, gain,
+            offset, tshift, win_a, win_b, max_hold, env_lo, env_hi,
+            trapezoid)
+    anchor = ts[0]
+    rows = -(-d // _LANES)
+    dp = _ceil_to(d, _LANES * min(_GRID_SUBLANES, rows))
+    mp = _ceil_to(m, min(m, _GRID_TICKS))
+    prev_t = np.asarray(prev_t, dtype=np.float64)
+    win_a = np.asarray(win_a, dtype=np.float64)
+    tshift = np.asarray(tshift, dtype=np.float64)
+    dts = np.zeros(mp)
+    dts[1:m] = np.diff(ts)
+    # window openings as tick columns, decided in float64: column j >= 1
+    # starts its hold at ts[j-1], column 0 at the stored prev_t
+    ja = 1 + _first_at_least(ts, win_a, 0.0)
+    jac = 1 + _first_at_least(ts, win_a, tshift)
+    ja = np.where(has_prev & (prev_t >= win_a), 0, ja)
+    jac = np.where(has_prev & (prev_t - tshift >= win_a), 0, jac)
+    # neutral device padding: has=0 zeroes the increments, gain=1 keeps
+    # the division defined, the open envelope keeps the padding out of
+    # n_out (all of it is sliced off below)
+    with _p.x32():
+        outs = _grid_call(
+            _k32(ts - anchor, mp, 0.0), _k32(dts, mp, 0.0),
+            _k32(np.pad(v, ((0, 0), (0, mp - m)), mode="edge"), dp, 0.0),
+            _k32(prev_t - anchor, dp, 0.0),
+            _k32(np.where(has_prev, ts[0] - prev_t, 0.0), dp, 0.0),
+            _k32(prev_v, dp, 0.0), _k32(has_prev, dp, 0, _p.KINT),
+            _k32(np.asarray(run_t) - anchor, dp, 0.0),
+            _k32(np.asarray(n_changes) >= 1, dp, 0, _p.KINT),
+            _k32(gain, dp, 1.0), _k32(offset, dp, 0.0),
+            _k32(tshift, dp, 0.0), _k32(ja, dp, mp, _p.KINT),
+            _k32(jac, dp, mp, _p.KINT),
+            _k32(np.asarray(win_b) - anchor, dp, -np.inf),
+            _k32(max_hold, dp, 0.0), _k32(env_lo, dp, -np.inf),
+            _k32(env_hi, dp, np.inf), bool(trapezoid), m, _interpret())
+    (ce, cec, rd, rr, de, dec, dw, dwc, sv, sv2, sa, mx, no, last,
+     nchg) = (np.asarray(o)[:d] for o in outs)
+    f64 = lambda x: x.astype(np.float64)
+    new_run_t = np.where(last >= 0, ts[np.maximum(last, 0)], run_t)
+    new_n_changes = np.asarray(n_changes, dtype=np.int64) + nchg
+    return (v[:, -1].copy(), new_run_t, new_n_changes, f64(de), f64(dec),
+            f64(dw), f64(dwc), f64(sv), f64(sv2), f64(sa), f64(mx),
+            no.astype(np.int64), f64(ce[:, :m]), f64(cec[:, :m]),
+            f64(rd[:, :m]), rr[:, :m] != 0)
+
+
+# -- stream_ingest: fused elementwise kernel, float64 fold on the device ---
+
+def _flat_kernel(v_ref, pv_ref, dt_ref, has_ref, pt_ref, win_ref, winc_ref,
+                 g_ref, off_ref, tsh_ref, wb_ref, mh_ref, el_ref, eh_ref,
+                 inc_ref, incc_ref, wi_ref, wic_ref, vc_ref, chg_ref,
+                 out_ref, *, trapezoid: bool):
+    v = v_ref[...]
+    pv = pv_ref[...]
+    pt = pt_ref[...]
+    has = has_ref[...] != 0
+    g = g_ref[...]
+    off = off_ref[...]
+    b = wb_ref[...]
 
     vc = (v - off) / g
     pvc = (pv - off) / g
-    hold = jnp.minimum(t - pt, mh_ref[...])
+    hold = jnp.minimum(dt_ref[...], mh_ref[...])
     dens_r = 0.5 * (pv + v) if trapezoid else pv
     dens_c = 0.5 * (pvc + vc) if trapezoid else pvc
-    inc = jnp.where(has, dens_r * hold, 0.0)
-    inc_c = jnp.where(has, dens_c * hold, 0.0)
-
-    a = wa_ref[...]
-    b = wb_ref[...]
+    inc_ref[...] = jnp.where(has, dens_r * hold, 0.0)
+    incc_ref[...] = jnp.where(has, dens_c * hold, 0.0)
     wi_ref[...] = jnp.where(
-        has & (pt >= a),
-        dens_r * jnp.maximum(jnp.minimum(pt + hold, b) - pt, 0.0), 0.0)
+        win_ref[...] != 0,
+        dens_r * jnp.maximum(jnp.minimum(hold, b - pt), 0.0), 0.0)
     pts = pt - tsh_ref[...]
     wic_ref[...] = jnp.where(
-        has & (pts >= a),
-        dens_c * jnp.maximum(jnp.minimum(pts + hold, b) - pts, 0.0), 0.0)
-
-    change = has & (v != pv)
-    cs_l = jnp.cumsum(inc)
-    csc_l = jnp.cumsum(inc_c)
-    cchg_l = jnp.cumsum(change.astype(jnp.float64))
-    inc_ref[...] = inc
-    incc_ref[...] = inc_c
-    cs_ref[...] = cs_l + carry[0]
-    csc_ref[...] = csc_l + carry[1]
-    cchg_ref[...] = cchg_l + carry[2]
-    carry[0] = carry[0] + cs_l[-1]
-    carry[1] = carry[1] + csc_l[-1]
-    carry[2] = carry[2] + cchg_l[-1]
+        winc_ref[...] != 0,
+        dens_c * jnp.maximum(jnp.minimum(hold, b - pts), 0.0), 0.0)
     vc_ref[...] = vc
-    chg_ref[...] = change
-    out_ref[...] = (vc < el_ref[...]) | (vc > eh_ref[...])
+    chg_ref[...] = (has & (v != pv)).astype(_p.KINT)
+    out_ref[...] = ((vc < el_ref[...]) | (vc > eh_ref[...])).astype(_p.KINT)
+
+
+@functools.partial(jax.jit, static_argnums=(14, 15))
+def _flat_call(v, pv, dt, has, pt, win, winc, g, off, tsh, wb, mh, el, eh,
+               trapezoid: bool, interpret: bool):
+    """Fourteen per-sample [Kp] 32-bit inputs → seven per-sample [Kp]
+    outputs."""
+    kp = v.shape[0]
+    rows = kp // _LANES
+    br = min(_FLAT_ROWS, rows)
+    spec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
+    shape = lambda dt: jax.ShapeDtypeStruct((rows, _LANES), dt)
+    outs = pl.pallas_call(
+        functools.partial(_flat_kernel, trapezoid=trapezoid),
+        grid=(rows // br,),
+        in_specs=[spec] * 14,
+        out_specs=[spec] * 7,
+        out_shape=[shape(_p.KFLOAT)] * 5 + [shape(_p.KINT)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(*(x.reshape(rows, _LANES) for x in (v, pv, dt, has, pt, win, winc,
+                                          g, off, tsh, wb, mh, el, eh)))
+    return tuple(o.reshape(kp) for o in outs)
 
 
 @functools.partial(jax.jit, static_argnums=(19, 20))
-def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
-                        prev_v, has_prev, run_t, n_changes, gain, offset,
-                        tshift, win_a, win_b, max_hold, env_lo, env_hi,
-                        trapezoid: bool, interpret: bool):
-    k = t.shape[0]
-    u = prev_t.shape[0]
-    idx = jnp.arange(k)
-
-    # prologue: per-sample parameter gathers + previous-sample shifts
-    shift_t = jnp.concatenate([jnp.zeros(1), t[:-1]])
-    shift_v = jnp.concatenate([jnp.zeros(1), v[:-1]])
-    pt = jnp.where(first, prev_t[seg], shift_t)
-    pv = jnp.where(first, prev_v[seg], shift_v)
-    has = jnp.where(first, has_prev[seg], True)
-
-    block = min(_INGEST_BLOCK, max(k, 1))
-    kp = -(-k // block) * block
-    # neutral padding: has=False zeroes the increments, gain=1 keeps the
-    # division defined, the open envelope keeps the tail out of n_out
-    args = (
-        _pad_to(t, kp, 0.0), _pad_to(v, kp, 0.0), _pad_to(pt, kp, 0.0),
-        _pad_to(pv, kp, 0.0), _pad_to(has, kp, False),
-        _pad_to(gain[seg], kp, 1.0), _pad_to(offset[seg], kp, 0.0),
-        _pad_to(tshift[seg], kp, 0.0),
-        _pad_to(win_a[seg], kp, jnp.inf),
-        _pad_to(win_b[seg], kp, -jnp.inf),
-        _pad_to(max_hold[seg], kp, 0.0),
-        _pad_to(env_lo[seg], kp, -jnp.inf),
-        _pad_to(env_hi[seg], kp, jnp.inf))
-    spec = pl.BlockSpec((block,), lambda i: (i,))
-    f64 = functools.partial(jax.ShapeDtypeStruct, (kp,))
-    outs = pl.pallas_call(
-        functools.partial(_ingest1d_kernel, trapezoid=trapezoid),
-        grid=(kp // block,),
-        in_specs=[spec] * 13,
-        out_specs=[spec] * 10,
-        out_shape=[f64(jnp.float64)] * 7
-        + [f64(jnp.float64), f64(jnp.bool_), f64(jnp.bool_)],
-        scratch_shapes=[pltpu.VMEM((3,), jnp.float64)],
-        interpret=interpret,
-    )(*args)
-    (inc, inc_c, cs, csc, cchg_f, w_inc, w_inc_c, vc, change,
-     out) = (o[:k] for o in outs)
-    cchg = cchg_f.astype(jnp.int64)
-    chg_i = change.astype(jnp.int64)
-
-    # epilogue: re-base the carried cumsums at group starts, segment
-    # reductions, and the same ordinal-scatter run tracking as the jax
-    # tier (see jax_backend._stream_ingest_impl)
-    cum_e = cs - (cs[start_idx] - inc[start_idx])[seg]
-    cum_ec = csc - (csc[start_idx] - inc_c[start_idx])[seg]
-    d_energy = cum_e[end_idx]
-    d_energy_corr = cum_ec[end_idx]
-    d_win = jax.ops.segment_sum(w_inc, seg, num_segments=u)
-    d_win_corr = jax.ops.segment_sum(w_inc_c, seg, num_segments=u)
-
-    slot = jnp.where(change, cchg, k + 1)
-    pch = jnp.full(k + 2, -1, dtype=jnp.int64).at[slot].set(
-        jnp.where(change, idx, -1))
-    tch = jnp.zeros(k + 2).at[slot].set(jnp.where(change, t, 0.0))
-    prev_ord = cchg - chg_i
-    gstart = start_idx[seg]
-    run_start = jnp.where(pch[prev_ord] >= gstart, tch[prev_ord],
-                          run_t[seg])
-    run_dur = jnp.where(change, t - run_start, 0.0)
-    chg_before_slab = prev_ord - (cchg - chg_i)[start_idx][seg]
-    run_rec = change & (n_changes[seg] + chg_before_slab >= 1)
-    ord_last = cchg[end_idx]
-    new_run_t = jnp.where(pch[ord_last] >= start_idx,
-                          tch[ord_last], run_t)
-    new_n_changes = n_changes + jax.ops.segment_sum(
-        chg_i, seg, num_segments=u)
-
-    counts = jax.ops.segment_sum(jnp.ones(k, dtype=jnp.int64), seg,
-                                 num_segments=u)
-    sum_vc = jax.ops.segment_sum(vc, seg, num_segments=u)
-    n_out = jax.ops.segment_sum(out.astype(jnp.int64), seg,
-                                num_segments=u)
-
-    return (t[end_idx], v[end_idx], new_run_t, new_n_changes, counts,
-            d_energy, d_energy_corr, d_win, d_win_corr, sum_vc, n_out,
-            cum_e, cum_ec, vc, run_dur, run_rec)
+def _flat_impl(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
+               has_prev, run_t, n_changes, gain, offset, tshift, win_a,
+               win_b, max_hold, env_lo, env_hi, trapezoid: bool,
+               interpret: bool):
+    """The slab in float64 on the device: predecessors, gaps and window
+    openings before the cast, the Pallas kernel on slab-relative 32-bit
+    values, then the jax tier's group fold of the widened increments."""
+    pt, pv, has = _jb.ingest_prev(t, v, seg, first, prev_t, prev_v,
+                                  has_prev)
+    anchor = t[0]
+    a = win_a[seg]
+    f32 = lambda x: x.astype(_p.KFLOAT)
+    i32 = lambda x: x.astype(_p.KINT)
+    args = (f32(v), f32(pv), f32(jnp.where(has, t - pt, 0.0)), i32(has),
+            f32(pt - anchor), i32(has & (pt >= a)),
+            i32(has & (pt - tshift[seg] >= a)), f32(gain[seg]),
+            f32(offset[seg]), f32(tshift[seg]), f32(win_b[seg] - anchor),
+            f32(max_hold[seg]), f32(env_lo[seg]), f32(env_hi[seg]))
+    with _p.x32():
+        outs = _flat_call(*args, trapezoid, interpret)
+    inc, inc_c, w_inc, w_inc_c, vc = (o.astype(_p.FLOAT) for o in outs[:5])
+    change, out = (o != 0 for o in outs[5:])
+    return _jb.ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes,
+                           inc, inc_c, w_inc, w_inc_c, vc, change, out)
 
 
 def stream_ingest(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
@@ -240,256 +390,82 @@ def stream_ingest(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
                   win_a, win_b, max_hold, env_lo, env_hi,
                   trapezoid: bool = False) -> Tuple:
     """Streaming-monitor ingest slab (see the numpy backend's reference
-    docstring); the elementwise + cumsum core runs as a blocked Pallas
-    kernel with the running totals carried in VMEM scratch."""
-    t = np.asarray(t, dtype=np.float64)
-    if t.shape[0] == 0:
-        return _nb.stream_ingest(
-            t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
-            has_prev, run_t, n_changes, gain, offset, tshift, win_a,
-            win_b, max_hold, env_lo, env_hi, trapezoid)
-    with enable_x64():
-        outs = _stream_ingest_impl(
-            jnp.asarray(t, jnp.float64), jnp.asarray(v, jnp.float64),
-            jnp.asarray(seg, jnp.int64), jnp.asarray(first, jnp.bool_),
-            jnp.asarray(start_idx, jnp.int64),
-            jnp.asarray(end_idx, jnp.int64),
-            jnp.asarray(prev_t, jnp.float64),
-            jnp.asarray(prev_v, jnp.float64),
-            jnp.asarray(has_prev, jnp.bool_),
-            jnp.asarray(run_t, jnp.float64),
-            jnp.asarray(n_changes, jnp.int64),
-            jnp.asarray(gain, jnp.float64),
-            jnp.asarray(offset, jnp.float64),
-            jnp.asarray(tshift, jnp.float64),
-            jnp.asarray(win_a, jnp.float64),
-            jnp.asarray(win_b, jnp.float64),
-            jnp.asarray(max_hold, jnp.float64),
-            jnp.asarray(env_lo, jnp.float64),
-            jnp.asarray(env_hi, jnp.float64),
-            bool(trapezoid), _interpret())
-    return tuple(np.asarray(o) for o in outs)
+    docstring): the per-sample arithmetic as one Pallas kernel, the
+    group fold in float64, both on the device."""
+    return _jb.ingest_padded(_flat_impl, t, v, seg, first, start_idx,
+                             end_idx, prev_t, prev_v, has_prev, run_t,
+                             n_changes, gain, offset, tshift, win_a, win_b,
+                             max_hold, env_lo, env_hi, bool(trapezoid),
+                             _interpret())
 
 
-# -- stream_ingest_grid: fused [block_d, M] row-block kernel ----------------
+# -- step_integrate: row-blocked masked sums --------------------------------
 
-def _ingest_grid_kernel(ts_ref, v_ref, pt0_ref, pv0_ref, has0_ref,
-                        rt_ref, nch_ref, g_ref, off_ref, tsh_ref,
-                        wa_ref, wb_ref, mh_ref, el_ref, eh_ref,
-                        nv_ref, nrt_ref, nnc_ref, de_ref, dec_ref,
-                        dw_ref, dwc_ref, sv_ref, sv2_ref, sa_ref,
-                        mx_ref, no_ref, ce_ref, cec_ref, rd_ref,
-                        rr_ref, *, trapezoid: bool):
-    ts = ts_ref[...]
-    v = v_ref[...]
-    d, m = v.shape
-
-    pt = jnp.concatenate(
-        [pt0_ref[...][:, None],
-         jnp.broadcast_to(ts[:-1][None, :], (d, m - 1))], axis=1)
-    pv = jnp.concatenate([pv0_ref[...][:, None], v[:, :-1]], axis=1)
-    has = jnp.concatenate(
-        [has0_ref[...][:, None], jnp.full((d, m - 1), True)], axis=1)
-
-    g = g_ref[...][:, None]
-    off = off_ref[...][:, None]
-    vc = (v - off) / g
-    pvc = (pv - off) / g
-    hold = jnp.minimum(ts[None, :] - pt, mh_ref[...][:, None])
-    dens_r = 0.5 * (pv + v) if trapezoid else pv
-    dens_c = 0.5 * (pvc + vc) if trapezoid else pvc
-    inc = jnp.where(has, dens_r * hold, 0.0)
-    inc_c = jnp.where(has, dens_c * hold, 0.0)
-    cum_e = jnp.cumsum(inc, axis=1)
-    cum_ec = jnp.cumsum(inc_c, axis=1)
-    ce_ref[...] = cum_e
-    cec_ref[...] = cum_ec
-    de_ref[...] = cum_e[:, -1]
-    dec_ref[...] = cum_ec[:, -1]
-
-    a = wa_ref[...][:, None]
-    b = wb_ref[...][:, None]
-    w_inc = jnp.where(
-        has & (pt >= a),
-        dens_r * jnp.maximum(jnp.minimum(pt + hold, b) - pt, 0.0), 0.0)
-    pts = pt - tsh_ref[...][:, None]
-    w_inc_c = jnp.where(
-        has & (pts >= a),
-        dens_c * jnp.maximum(jnp.minimum(pts + hold, b) - pts, 0.0), 0.0)
-    dw_ref[...] = jnp.sum(w_inc, axis=1)
-    dwc_ref[...] = jnp.sum(w_inc_c, axis=1)
-
-    # run tracking: the latest change at-or-before each column via an
-    # in-kernel cummax over change column indices (the pre-slab state is
-    # carried in run_t, so the scan never leaves the block)
-    run_t = rt_ref[...]
-    change = has & (v != pv)
-    cols = lax.broadcasted_iota(jnp.int64, (d, m), 1)
-    ci = jnp.where(change, cols, -1)
-    acc = lax.cummax(ci, axis=1)
-    acc_excl = jnp.concatenate(
-        [jnp.full((d, 1), -1, jnp.int64), acc[:, :-1]], axis=1)
-    run_start = jnp.where(acc_excl >= 0,
-                          ts[jnp.maximum(acc_excl, 0)], run_t[:, None])
-    rd_ref[...] = jnp.where(change, ts[None, :] - run_start, 0.0)
-    cchg = jnp.cumsum(change.astype(jnp.int64), axis=1)
-    rr_ref[...] = change & (
-        nch_ref[...][:, None] + (cchg - change) >= 1)
-    last = acc[:, -1]
-    nrt_ref[...] = jnp.where(last >= 0, ts[jnp.maximum(last, 0)], run_t)
-    nnc_ref[...] = nch_ref[...] + cchg[:, -1]
-    nv_ref[...] = v[:, -1]
-
-    av = jnp.abs(vc)
-    out = (vc < el_ref[...][:, None]) | (vc > eh_ref[...][:, None])
-    sv_ref[...] = jnp.sum(vc, axis=1)
-    sv2_ref[...] = jnp.sum(vc * vc, axis=1)
-    sa_ref[...] = jnp.sum(av, axis=1)
-    mx_ref[...] = jnp.max(av, axis=1)
-    no_ref[...] = jnp.sum(out, axis=1).astype(jnp.int64)
-
-
-@functools.partial(jax.jit, static_argnums=(15, 16))
-def _stream_ingest_grid_impl(ts, v, prev_t, prev_v, has_prev, run_t,
-                             n_changes, gain, offset, tshift, win_a,
-                             win_b, max_hold, env_lo, env_hi,
-                             trapezoid: bool, interpret: bool):
-    d, m = v.shape
-    bd = min(_GRID_BLOCK_D, max(d, 1))
-    dp = -(-d // bd) * bd
-    # neutral device padding (dropped by the [:d] slices below)
-    pad2 = lambda x: jnp.concatenate(
-        [x, jnp.zeros((dp - d, m), dtype=x.dtype)]) if dp != d else x
-    args = (
-        ts, pad2(v), _pad_to(prev_t, dp, 0.0), _pad_to(prev_v, dp, 0.0),
-        _pad_to(has_prev, dp, False), _pad_to(run_t, dp, 0.0),
-        _pad_to(n_changes, dp, 0), _pad_to(gain, dp, 1.0),
-        _pad_to(offset, dp, 0.0), _pad_to(tshift, dp, 0.0),
-        _pad_to(win_a, dp, jnp.inf), _pad_to(win_b, dp, -jnp.inf),
-        _pad_to(max_hold, dp, 0.0), _pad_to(env_lo, dp, -jnp.inf),
-        _pad_to(env_hi, dp, jnp.inf))
-    row = pl.BlockSpec((bd,), lambda i: (i,))
-    mat = pl.BlockSpec((bd, m), lambda i: (i, 0))
-    vec = functools.partial(jax.ShapeDtypeStruct, (dp,))
-    slab = functools.partial(jax.ShapeDtypeStruct, (dp, m))
-    outs = pl.pallas_call(
-        functools.partial(_ingest_grid_kernel, trapezoid=trapezoid),
-        grid=(dp // bd,),
-        in_specs=[pl.BlockSpec((m,), lambda i: (0,))] + [mat]
-        + [row] * 13,
-        out_specs=[row] * 12 + [mat] * 4,
-        out_shape=[vec(jnp.float64), vec(jnp.float64), vec(jnp.int64)]
-        + [vec(jnp.float64)] * 8 + [vec(jnp.int64)]
-        + [slab(jnp.float64), slab(jnp.float64), slab(jnp.float64),
-           slab(jnp.bool_)],
-        interpret=interpret,
-    )(*args)
-    return tuple(o[:d] for o in outs)
-
-
-def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
-                       gain, offset, tshift, win_a, win_b, max_hold,
-                       env_lo, env_hi, trapezoid: bool = False) -> Tuple:
-    """Rectangular-slab streaming ingest (see the numpy backend's
-    reference docstring) as one fused row-block Pallas kernel."""
-    ts = np.asarray(ts, dtype=np.float64)
-    v = np.asarray(v, dtype=np.float64)
-    if v.shape[1] == 0:
-        return _nb.stream_ingest_grid(
-            ts, v, prev_t, prev_v, has_prev, run_t, n_changes, gain,
-            offset, tshift, win_a, win_b, max_hold, env_lo, env_hi,
-            trapezoid)
-    with enable_x64():
-        outs = _stream_ingest_grid_impl(
-            jnp.asarray(ts, jnp.float64), jnp.asarray(v, jnp.float64),
-            jnp.asarray(prev_t, jnp.float64),
-            jnp.asarray(prev_v, jnp.float64),
-            jnp.asarray(has_prev, jnp.bool_),
-            jnp.asarray(run_t, jnp.float64),
-            jnp.asarray(n_changes, jnp.int64),
-            jnp.asarray(gain, jnp.float64),
-            jnp.asarray(offset, jnp.float64),
-            jnp.asarray(tshift, jnp.float64),
-            jnp.asarray(win_a, jnp.float64),
-            jnp.asarray(win_b, jnp.float64),
-            jnp.asarray(max_hold, jnp.float64),
-            jnp.asarray(env_lo, jnp.float64),
-            jnp.asarray(env_hi, jnp.float64),
-            bool(trapezoid), _interpret())
-    return tuple(np.asarray(o) for o in outs)
-
-
-# -- step_integrate: row-blocked window integration -------------------------
-
-def _step_kernel(ts_ref, vals_ref, t0_ref, t1_ref, o_ref, *,
-                 trapezoid: bool):
-    ts = ts_ref[...]
+def _step_kernel(vals_ref, nxt_ref, dt_ref, tail_ref, j0_ref, j1_ref,
+                 o_ref, *, trapezoid: bool, m: int):
     vals = vals_ref[...]
-    t0 = t0_ref[...]
-    t1 = t1_ref[...]
-    n, m = ts.shape
-    nxt = ts[:, 1:]
-    nxt_finite = nxt < jnp.inf
-    dt = jnp.where(nxt_finite, nxt - ts[:, :-1], 0.0)
-    if trapezoid:
-        dens = 0.5 * (vals[:, :-1]
-                      + jnp.where(nxt_finite, vals[:, 1:], 0.0))
-    else:
-        dens = vals[:, :-1]
-    cum = jnp.concatenate(
-        [jnp.zeros((n, 1)), jnp.cumsum(dens * dt, axis=1)], axis=1)
-
-    # counting == binary search on the sorted, inf-padded rows
-    j0 = jnp.sum(ts < t0[:, None], axis=1)
-    j1 = jnp.sum(ts <= t1[:, None], axis=1) - 1
-    j0c = jnp.clip(j0, 0, m - 1)[:, None]
-    j1c = jnp.clip(j1, 0, m - 1)[:, None]
-    core = (jnp.take_along_axis(cum, j1c, axis=1)
-            - jnp.take_along_axis(cum, j0c, axis=1))[:, 0]
-    tail = (jnp.take_along_axis(vals, j1c, axis=1)[:, 0]
-            * (t1 - jnp.take_along_axis(ts, j1c, axis=1)[:, 0]))
-    nonempty = (j1 >= j0) & (j0 < m)
-    o_ref[...] = jnp.where(nonempty, core + tail, 0.0)
+    j0 = j0_ref[...]
+    j1 = j1_ref[...]
+    col = lax.broadcasted_iota(_p.KINT, vals.shape, 1)
+    dens = 0.5 * (vals + nxt_ref[...]) if trapezoid else vals
+    core = jnp.sum(jnp.where((col >= j0) & (col < j1),
+                             dens * dt_ref[...], 0.0), axis=1,
+                   keepdims=True)
+    tail = jnp.sum(jnp.where(col == j1, vals * tail_ref[...], 0.0),
+                   axis=1, keepdims=True)
+    o_ref[...] = jnp.where((j1 >= j0) & (j0 < m), core + tail, 0.0)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _step_integrate_impl(ts, vals, t0, t1, trapezoid: bool,
-                         interpret: bool):
-    n, m = ts.shape
-    bn = min(_STEP_BLOCK_N, max(n, 1))
-    npad = -(-n // bn) * bn
-    if npad != n:
-        # inf-padded rows integrate to zero (j1 = -1 -> nonempty False)
-        ts = jnp.concatenate([ts, jnp.full((npad - n, m), jnp.inf)])
-        vals = jnp.concatenate([vals, jnp.zeros((npad - n, m))])
-        t0 = _pad_to(t0, npad, 0.0)
-        t1 = _pad_to(t1, npad, 0.0)
-    out = pl.pallas_call(
-        functools.partial(_step_kernel, trapezoid=trapezoid),
-        grid=(npad // bn,),
-        in_specs=[pl.BlockSpec((bn, m), lambda i: (i, 0)),
-                  pl.BlockSpec((bn, m), lambda i: (i, 0)),
-                  pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((npad,), jnp.float64),
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _step_call(vals, nxt, dt, tail, j0, j1, trapezoid: bool, m: int,
+               interpret: bool):
+    n, mp = vals.shape
+    bn = min(_STEP_ROWS, n)
+    mat = pl.BlockSpec((bn, mp), lambda i: (i, 0))
+    col = pl.BlockSpec((bn, 1), lambda i: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_step_kernel, trapezoid=trapezoid, m=m),
+        grid=(n // bn,),
+        in_specs=[mat] * 4 + [col] * 2,
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((n, 1), _p.KFLOAT),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(ts, vals, t0, t1)
-    return out[:n]
+    )(vals, nxt, dt, tail, j0, j1)[:, 0]
 
 
 def step_integrate(ts: np.ndarray, vals: np.ndarray, t0: np.ndarray,
                    t1: np.ndarray, trapezoid: bool = False) -> np.ndarray:
     """Batched rectangle/trapezoid step integration (see the numpy
-    backend's reference docstring) as a row-blocked Pallas kernel."""
+    backend's reference docstring): exact float64 window search and
+    gaps on the host, the masked sums in a row-blocked Pallas kernel."""
     ts = np.asarray(ts, dtype=np.float64)
-    if ts.shape[1] == 0:    # no samples at all: every window is 0
-        return np.zeros(ts.shape[0])
-    with enable_x64():
-        return np.asarray(_step_integrate_impl(
-            jnp.asarray(ts, jnp.float64), jnp.asarray(vals, jnp.float64),
-            jnp.asarray(t0, jnp.float64), jnp.asarray(t1, jnp.float64),
-            bool(trapezoid), _interpret()))
+    vals = np.asarray(vals, dtype=np.float64)
+    t0 = np.asarray(t0, dtype=np.float64)
+    t1 = np.asarray(t1, dtype=np.float64)
+    n, m = ts.shape
+    if m == 0 or n == 0:    # no samples at all: every window is 0
+        return np.zeros(n)
+    j0 = _nb.searchsorted_rows(ts, t0[:, None], "left")
+    j1 = _nb.searchsorted_rows(ts, t1[:, None], "right") - 1
+    fin = np.isfinite(ts)
+    nxt_fin = np.zeros_like(fin)
+    nxt_fin[:, :-1] = fin[:, 1:]
+    dt = np.zeros_like(ts)
+    dt[:, :-1] = np.where(nxt_fin[:, :-1], np.diff(np.where(fin, ts, 0.0),
+                                                   axis=1), 0.0)
+    nxt = np.zeros_like(vals)
+    nxt[:, :-1] = np.where(nxt_fin[:, :-1], vals[:, 1:], 0.0)
+    tail = np.where(fin, t1[:, None] - np.where(fin, ts, 0.0), 0.0)
+    np_ = _ceil_to(n, 8) if n <= _STEP_ROWS else _ceil_to(n, _STEP_ROWS)
+    with _p.x32():
+        out = _step_call(_k32(vals, np_, 0.0), _k32(nxt, np_, 0.0),
+                         _k32(dt, np_, 0.0), _k32(tail, np_, 0.0),
+                         _k32(j0, np_, 0, _p.KINT),
+                         _k32(j1, np_, -1, _p.KINT), bool(trapezoid), m,
+                         _interpret())
+    return np.asarray(out)[:n].astype(np.float64)
 
 
 # -- log_filter: blocked sequential scan over segments ----------------------
@@ -501,77 +477,60 @@ def _scan_kernel(a_ref, b_ref, y0_ref, o_ref, carry):
     def _init():
         carry[...] = y0_ref[...]
 
-    a = a_ref[...]
-    b = b_ref[...]
-
     def step(i, y):
-        y = a[i, :] * y + b[i, :]
-        o_ref[i, :] = y
+        y = a_ref[pl.ds(i, 1), :] * y + b_ref[pl.ds(i, 1), :]
+        o_ref[pl.ds(i, 1), :] = y
         return y
 
-    carry[0, :] = lax.fori_loop(0, a.shape[0], step, carry[0, :])
+    carry[...] = lax.fori_loop(0, a_ref.shape[0], step, carry[...])
 
 
-@functools.partial(jax.jit, static_argnums=(5,))
-def _log_filter_impl(tl, ticks, tau, t_lo, t_hi, interpret: bool):
-    # prologue: identical segment coefficients to the jax tier
-    g = ticks.shape[0]
-    r = tl.edges.shape[0]
-    ext_e = jnp.concatenate([jnp.full((r, 1), t_lo), tl.edges,
-                             jnp.full((r, 1), t_hi)], axis=1)
-    ext_p = jnp.concatenate([tl.idle_w[:, None], tl.powers,
-                             tl.idle_w[:, None]], axis=1)
-    n_seg = ext_p.shape[1]
-    dts = jnp.broadcast_to(jnp.diff(ext_e, axis=1), (g, n_seg))
-    sp = jnp.broadcast_to(ext_p, (g, n_seg))
-    decay = jnp.exp(-dts / tau[:, None])
-    a_seg = jnp.where(dts > 0, decay, 1.0)
-    b_seg = jnp.where(dts > 0, sp * (1.0 - decay), 0.0)
-    y0 = jnp.broadcast_to(tl.idle_w, (g,))
-
-    # blocked sequential scan: transpose to [segments, rows], pad the
-    # segment axis with identity steps (a=1, b=0) and the row axis with
-    # zero columns, grid iterates segment chunks innermost
-    ch = min(_SCAN_CHUNK, max(n_seg, 1))
-    bg = min(_SCAN_BLOCK_G, max(g, 1))
-    sp_n = -(-n_seg // ch) * ch
-    gp = -(-g // bg) * bg
-    aT = jnp.ones((sp_n, gp)).at[:n_seg, :g].set(a_seg.T)
-    bT = jnp.zeros((sp_n, gp)).at[:n_seg, :g].set(b_seg.T)
-    y0p = _pad_to(y0, gp, 0.0)[None, :]
-    yT = pl.pallas_call(
+@functools.partial(jax.jit, static_argnums=(3,))
+def _scan_call(aT, bT, y0, interpret: bool):
+    sp_n, gp = aT.shape
+    ch = min(_SCAN_CHUNK, sp_n)
+    bg = min(_SCAN_LANES, gp)
+    tile = pl.BlockSpec((ch, bg), lambda gi, si: (si, gi))
+    return pl.pallas_call(
         _scan_kernel,
         grid=(gp // bg, sp_n // ch),
-        in_specs=[pl.BlockSpec((ch, bg), lambda gi, si: (si, gi)),
-                  pl.BlockSpec((ch, bg), lambda gi, si: (si, gi)),
+        in_specs=[tile, tile,
                   pl.BlockSpec((1, bg), lambda gi, si: (0, gi))],
-        out_specs=pl.BlockSpec((ch, bg), lambda gi, si: (si, gi)),
-        out_shape=jax.ShapeDtypeStruct((sp_n, gp), jnp.float64),
-        scratch_shapes=[pltpu.VMEM((1, bg), jnp.float64)],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((sp_n, gp), _p.KFLOAT),
+        scratch_shapes=[pltpu.VMEM((1, bg), _p.KFLOAT)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(aT, bT, y0p)
-    y = jnp.concatenate([y0[:, None], yT[:n_seg, :g].T], axis=1)
-
-    # epilogue: locate each tick's segment and decay from its entry state
-    ext_e_g = jnp.broadcast_to(ext_e, (g, n_seg + 1))
-    idx = jnp.clip(_jb._searchsorted_rows(ext_e, ticks, "right") - 1,
-                   0, n_seg - 1)
-    y_at = jnp.take_along_axis(y, idx, axis=1)
-    sp_at = jnp.take_along_axis(sp, idx, axis=1)
-    e_at = jnp.take_along_axis(ext_e_g, idx, axis=1)
-    return sp_at + (y_at - sp_at) * jnp.exp(-(ticks - e_at)
-                                            / tau[:, None])
+    )(aT, bT, y0)
 
 
 def log_filter(tl, ticks: np.ndarray, tau: np.ndarray) -> np.ndarray:
     """Logarithmic-filter readings (see the numpy backend's reference
-    docstring); the per-segment affine recurrence runs as a blocked
-    sequential Pallas scan with the filter state carried in VMEM."""
+    docstring): float64 segment coefficients and readout on the host,
+    the per-segment affine recurrence as a blocked sequential Pallas
+    scan with the filter state carried in VMEM."""
+    ticks = np.asarray(ticks, dtype=np.float64)
     tau = np.asarray(tau, dtype=np.float64)
-    t_lo = (min(float(np.min(ticks)), float(np.min(tl.t_start)))
-            - 5.0 * float(np.max(tau)))
-    t_hi = max(float(np.max(ticks)), float(np.max(tl.t_end))) + 1e-9
-    with enable_x64():
-        return np.asarray(_log_filter_impl(
-            tl, jnp.asarray(ticks, jnp.float64), jnp.asarray(tau),
-            jnp.float64(t_lo), jnp.float64(t_hi), _interpret()))
+    ext_e, ext_p, dts = _nb.log_filter_segments(tl, ticks, tau)
+    g, n_seg = ticks.shape[0], ext_p.shape[1]
+    decay = np.exp(-dts / tau[:, None])
+    a_seg = np.where(dts > 0, decay, 1.0)
+    b_seg = np.where(dts > 0, ext_p * (1.0 - decay), 0.0)
+    # [segments, rows], padded with identity steps (a=1, b=0) along the
+    # segment axis and zero columns along the row axis
+    ch = min(_SCAN_CHUNK, _ceil_to(n_seg, 8))
+    sp_n = _ceil_to(n_seg, ch)
+    gp = _ceil_to(g, _LANES) if g <= _SCAN_LANES else _ceil_to(
+        g, _SCAN_LANES)
+    aT = np.ones((sp_n, gp), dtype=_p.KFLOAT)
+    bT = np.zeros((sp_n, gp), dtype=_p.KFLOAT)
+    aT[:n_seg, :g] = np.broadcast_to(a_seg, (g, n_seg)).T
+    bT[:n_seg, :g] = np.broadcast_to(b_seg, (g, n_seg)).T
+    idle = np.broadcast_to(tl.idle_w, (g,))
+    with _p.x32():
+        yT = np.asarray(_scan_call(aT, bT, _k32(idle, gp, 0.0)[None, :],
+                                   _interpret()))
+    y = np.concatenate([idle[:, None], yT[:n_seg, :g].T.astype(np.float64)],
+                       axis=1)
+    return _nb.log_filter_readout(y, ext_e, ext_p, ticks, tau)

@@ -47,11 +47,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine_backend import jax_backend as _jb
+from repro.core.engine_backend import precision as _p
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
                                                TimelineArrays)
 
@@ -100,9 +99,9 @@ def tree_merge_moments(blocks) -> np.ndarray:
     identities under :func:`_chan_pair` — so any shard count works.  The
     tree is unrolled at trace time (k is static); for the shard counts
     this module sees (≤ dozens) that is a handful of fused combines."""
-    with enable_x64():
+    with _p.x64():
         return np.asarray(
-            _tree_merge_impl(jnp.asarray(blocks, jnp.float64)))
+            _tree_merge_impl(jnp.asarray(blocks, _p.FLOAT)))
 
 
 def _local_moments_impl(e, n_true):
@@ -171,9 +170,9 @@ class ShardedBackend:
         self.name = f"shard({self.n_shards})"
 
         def smap(fn, in_specs, out_specs=P("data")):
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=self.mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
 
         D, R = P("data"), P()
         # two variants per timeline kernel: per-device timelines shard
@@ -210,10 +209,10 @@ class ShardedBackend:
         per_dev = tl.n_rows != 1
         if per_dev:
             tl = self._pad_tree(tl, rows)
-        with enable_x64():
+        with _p.x64():
             out = self._boxcar[per_dev](
-                tl, jnp.asarray(_pad_rows(t0, rows), jnp.float64),
-                jnp.asarray(_pad_rows(t1, rows), jnp.float64))
+                tl, jnp.asarray(_pad_rows(t0, rows), _p.FLOAT),
+                jnp.asarray(_pad_rows(t1, rows), _p.FLOAT))
         return np.asarray(out)[:n]
 
     def estimation_means(self, tl: TimelineArrays, t0, t1,
@@ -223,12 +222,12 @@ class ShardedBackend:
         per_dev = tl.n_rows != 1
         if per_dev:
             tl = self._pad_tree(tl, rows)
-        with enable_x64():
+        with _p.x64():
             out = self._estimation[per_dev](
-                tl, jnp.asarray(_pad_rows(t0, rows), jnp.float64),
-                jnp.asarray(_pad_rows(t1, rows), jnp.float64),
+                tl, jnp.asarray(_pad_rows(t0, rows), _p.FLOAT),
+                jnp.asarray(_pad_rows(t1, rows), _p.FLOAT),
                 jnp.asarray(_pad_rows(np.asarray(model_gain), rows),
-                            jnp.float64))
+                            _p.FLOAT))
         return np.asarray(out)[:n]
 
     def log_filter(self, tl: TimelineArrays, ticks, tau) -> np.ndarray:
@@ -242,21 +241,21 @@ class ShardedBackend:
         per_dev = tl.n_rows != 1
         if per_dev:
             tl = self._pad_tree(tl, rows)
-        with enable_x64():
+        with _p.x64():
             out = self._log_filter[per_dev](
-                tl, jnp.asarray(_pad_rows(ticks, rows), jnp.float64),
-                jnp.asarray(_pad_rows(tau, rows), jnp.float64),
-                jnp.float64(t_lo), jnp.float64(t_hi))
+                tl, jnp.asarray(_pad_rows(ticks, rows), _p.FLOAT),
+                jnp.asarray(_pad_rows(tau, rows), _p.FLOAT),
+                _p.FLOAT(t_lo), _p.FLOAT(t_hi))
         return np.asarray(out)[:n]
 
     def query_slots(self, sched: ReadingSchedule, tq) -> np.ndarray:
         n = tq.shape[0]
         rows = self._rows(n)
         sched = self._pad_tree(sched, rows)
-        with enable_x64():
+        with _p.x64():
             out = self._query_slots(
                 sched, jnp.asarray(_pad_rows(np.asarray(tq), rows),
-                                   jnp.float64))
+                                   _p.FLOAT))
         return np.asarray(out)[:n]
 
     def poll_counts(self, sched: ReadingSchedule, grid: PollGrid, a, b):
@@ -267,14 +266,14 @@ class ShardedBackend:
         off = _pad_rows(
             np.broadcast_to(np.asarray(grid.grid_offset, np.float64),
                             (n,)), rows)
-        with enable_x64():
+        with _p.x64():
             counts, slot_b, tail_dt, nonempty = self._poll_counts(
-                sched, jnp.float64(grid.t0), jnp.asarray(t1, jnp.float64),
-                jnp.float64(grid.period_s), jnp.asarray(off, jnp.float64),
+                sched, _p.FLOAT(grid.t0), jnp.asarray(t1, _p.FLOAT),
+                _p.FLOAT(grid.period_s), jnp.asarray(off, _p.FLOAT),
                 jnp.asarray(_pad_rows(np.asarray(a, np.float64), rows),
-                            jnp.float64),
+                            _p.FLOAT),
                 jnp.asarray(_pad_rows(np.asarray(b, np.float64), rows),
-                            jnp.float64))
+                            _p.FLOAT))
         return (np.asarray(counts)[:n], np.asarray(slot_b)[:n],
                 np.asarray(tail_dt)[:n], np.asarray(nonempty)[:n])
 
@@ -290,9 +289,9 @@ class ShardedBackend:
         padded = np.zeros(rows) if rows != n else e
         if rows != n:
             padded[:n] = e
-        with enable_x64():
-            blocks = self._local_moments(jnp.asarray(padded, jnp.float64),
-                                         jnp.float64(n))
+        with _p.x64():
+            blocks = self._local_moments(jnp.asarray(padded, _p.FLOAT),
+                                         _p.FLOAT(n))
             merged = np.asarray(_tree_merge_impl(blocks))
         return (int(merged[0]), float(merged[1]), float(merged[2]),
                 float(merged[3]), float(merged[4]))

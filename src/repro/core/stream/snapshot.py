@@ -39,7 +39,6 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.core.engine_backend import numpy_backend as _nb
 from repro.core.fleet_engine import StreamingMoments
 from repro.core.stream.health import QUARANTINED, STALE
 from repro.core.stream.state import DeviceState
@@ -187,10 +186,9 @@ class MonitorSnapshot:
         tq = np.asarray(tq, dtype=np.float64).ravel()
         st = self.state
         dens, base, ring_t, ring_dens, ring_base = self._flavor(corrected)
-        kernel = getattr(self._be, "snapshot_energy_at",
-                         _nb.snapshot_energy_at)
-        return kernel(tq, st.last_t, dens, st.has, st.first_t, base,
-                      self._max_hold, ring_t, ring_dens, ring_base)
+        return self._be.snapshot_energy_at(
+            tq, st.last_t, dens, st.has, st.first_t, base, self._max_hold,
+            ring_t, ring_dens, ring_base)
 
     def window_energy_batch(self, tq: np.ndarray, corrected: bool = True
                             ) -> np.ndarray:

@@ -52,8 +52,14 @@ def n_chips(mesh) -> int:
 def require_devices(n: int) -> None:
     have = jax.device_count()
     if have < n:
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"mesh needs {n} devices but this {jax.default_backend()} "
+                f"host has {have}; run on a host with {n} chips")
         raise RuntimeError(
             f"mesh needs {n} devices but the backend exposes {have}. "
-            "The dry-run entrypoint must set "
+            "On a CPU host (rehearsal only), set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=<n> before "
-            "any jax import (see launch/dryrun.py).")
+            "any jax import (see launch/dryrun.py). That flag does not "
+            "apply to an accelerator: there the mesh spans real chips, "
+            "all driven from one process.")

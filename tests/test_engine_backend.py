@@ -16,6 +16,8 @@ Three groups:
 import numpy as np
 import pytest
 
+from _tol import assert_tier_close
+
 from repro.core import load as loads
 from repro.core import profiles
 from repro.core.engine_backend import (available_backends, get_backend,
@@ -84,6 +86,41 @@ def test_pallas_backend_listed_and_loadable():
     assert get_backend("pallas").name == "pallas"
 
 
+@needs_jax
+def test_pallas_interpret_follows_platform_only(monkeypatch):
+    import jax
+    from repro.core.engine_backend import pallas_backend
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert pallas_backend._interpret() == (jax.default_backend() == "cpu")
+
+
+@needs_jax
+def test_compile_cache_follows_env(monkeypatch, tmp_path):
+    import jax
+    from repro.core.engine_backend import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+@needs_jax
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    import os
+    import jax
+    from repro.core.engine_backend import use_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = use_compile_cache()
+        assert path == use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+
+
 def test_bank_records_backend_and_propagates_to_views():
     bank = SensorBank.from_catalog(["a100", "v100"], base_seed=0)
     assert bank.backend == "numpy"
@@ -132,7 +169,7 @@ def test_kernel_parity_log_filter(accel_backend):
     ref = npb.log_filter(tls.arrays, ticks, tau)
     # the associative scan reorders the recurrence's float ops, so allow
     # tiny drift — far below one reporting quantum (0.01 W)
-    np.testing.assert_allclose(got, ref, rtol=1e-9, atol=1e-9)
+    assert_tier_close(got, ref, accel_backend, 1e-9, 1e-9)
 
 
 def test_kernel_parity_poll_counts_and_query_slots(accel_backend):
@@ -237,10 +274,12 @@ def test_backend_parity_good_practice_batch(accel_backend):
     est_np = measure_good_practice_batch(b_np, wl, calibs, cfg)
     est_jx = measure_good_practice_batch(b_np, wl, calibs, cfg,
                                          backend=accel_backend)
-    np.testing.assert_allclose(est_jx.joules_per_rep, est_np.joules_per_rep,
-                               rtol=1e-9, atol=1e-6)
-    np.testing.assert_allclose(est_jx.trial_values, est_np.trial_values,
-                               rtol=1e-9, atol=1e-6)
+    # a filter state within float32 rounding can land a reading on the
+    # other side of a quantum boundary: the pallas bound covers that
+    assert_tier_close(est_jx.joules_per_rep, est_np.joules_per_rep,
+                      accel_backend, 1e-9, 1e-6)
+    assert_tier_close(est_jx.trial_values, est_np.trial_values,
+                      accel_backend, 1e-9, 1e-6)
 
 
 def test_backend_parity_fleet_audit_stats(accel_backend):

@@ -12,6 +12,7 @@ core CI job); the CI accelerated jobs run this module explicitly.
 import numpy as np
 import pytest
 
+from _tol import assert_tier_close
 from repro.core import load as loads
 from repro.core.engine_backend import get_backend, has_jax
 from repro.core.engine_backend import numpy_backend as nb
@@ -74,11 +75,8 @@ def test_stream_ingest_kernel_parity(accel_backend, trapezoid):
         outj = jb.stream_ingest(*args)
         assert len(outn) == len(outj)
         for i, (a, b) in enumerate(zip(outn, outj)):
-            np.testing.assert_allclose(
-                np.asarray(a, dtype=np.float64),
-                np.asarray(b, dtype=np.float64),
-                rtol=1e-12, atol=1e-12,
-                err_msg=f"output {i} (trial {trial})")
+            assert_tier_close(b, a, accel_backend, 1e-12, 1e-12,
+                              err_msg=f"output {i} (trial {trial})")
 
 
 @pytest.mark.parametrize("trapezoid", [False, True])
@@ -95,7 +93,7 @@ def test_step_integrate_kernel_parity(accel_backend, trapezoid):
     t1 = t0 + rng.uniform(0.0, 8.0, n)
     outn = nb.step_integrate(ts, vals, t0, t1, trapezoid=trapezoid)
     outj = jb.step_integrate(ts, vals, t0, t1, trapezoid=trapezoid)
-    np.testing.assert_allclose(outj, outn, rtol=1e-12, atol=1e-12)
+    assert_tier_close(outj, outn, accel_backend, 1e-12, 1e-12)
 
 
 def test_monitor_end_to_end_backend_parity(accel_backend):
@@ -109,14 +107,13 @@ def test_monitor_end_to_end_backend_parity(accel_backend):
                       backend="numpy", compare=True)
     rj = stream_fleet(n, profile=MIXED_NAMES, workload=ws, seed=0,
                       backend=accel_backend, compare=True)
-    np.testing.assert_allclose(rj.naive_stream_j, rn.naive_stream_j,
-                               rtol=1e-11)
-    np.testing.assert_allclose(rj.corrected_stream_j,
-                               rn.corrected_stream_j, rtol=1e-11)
-    np.testing.assert_allclose(rj.naive_stream_j, rj.naive_offline_j,
-                               rtol=1e-11)
-    np.testing.assert_allclose(rj.corrected_stream_j,
-                               rj.corrected_offline_j, rtol=1e-11)
+    be = accel_backend
+    assert_tier_close(rj.naive_stream_j, rn.naive_stream_j, be, 1e-11)
+    assert_tier_close(rj.corrected_stream_j, rn.corrected_stream_j, be,
+                      1e-11)
+    assert_tier_close(rj.naive_stream_j, rj.naive_offline_j, be, 1e-11)
+    assert_tier_close(rj.corrected_stream_j, rj.corrected_offline_j, be,
+                      1e-11)
     assert rn.monitor.counters == rj.monitor.counters
 
 
@@ -134,11 +131,11 @@ def test_monitor_messy_stream_matches_numpy(accel_backend):
         mons[be] = mon
     acc = mons[accel_backend]
     assert mons["numpy"].counters == acc.counters
-    np.testing.assert_allclose(acc.state.energy_j,
-                               mons["numpy"].state.energy_j, rtol=1e-12)
-    np.testing.assert_allclose(acc.update_period_s(),
-                               mons["numpy"].update_period_s(),
-                               rtol=1e-9, equal_nan=True)
+    assert_tier_close(acc.state.energy_j, mons["numpy"].state.energy_j,
+                      accel_backend, 1e-12)
+    assert_tier_close(acc.update_period_s(),
+                      mons["numpy"].update_period_s(), accel_backend, 1e-9,
+                      equal_nan=True)
 
 
 @pytest.mark.parametrize("trapezoid", [False, True])
@@ -167,11 +164,8 @@ def test_stream_ingest_grid_kernel_parity(accel_backend, trapezoid):
         outj = jb.stream_ingest_grid(*args)
         assert len(outn) == len(outj) == 16
         for i, (a, b) in enumerate(zip(outn, outj)):
-            np.testing.assert_allclose(
-                np.asarray(a, dtype=np.float64),
-                np.asarray(b, dtype=np.float64),
-                rtol=1e-12, atol=1e-12,
-                err_msg=f"output {i} (trial {trial})")
+            assert_tier_close(b, a, accel_backend, 1e-12, 1e-12,
+                              err_msg=f"output {i} (trial {trial})")
     empty = (np.zeros(0), np.zeros((3, 0)), np.zeros(3), np.ones(3),
              np.ones(3, dtype=bool), np.zeros(3),
              np.zeros(3, dtype=np.int64), np.ones(3), np.zeros(3),
@@ -199,27 +193,27 @@ def test_monitor_grid_path_matches_flat_path(accel_backend):
         replay(bank, mon, 0.0, 1.0, grid=grid)
         mons[grid] = mon
     assert mons[True].counters == mons[False].counters
-    np.testing.assert_allclose(mons[True].state.energy_j,
-                               mons[False].state.energy_j, rtol=1e-11)
-    np.testing.assert_allclose(mons[True].state.energy_corr_j,
-                               mons[False].state.energy_corr_j,
-                               rtol=1e-11)
+    be = accel_backend
+    assert_tier_close(mons[True].state.energy_j, mons[False].state.energy_j,
+                      be, 1e-11)
+    assert_tier_close(mons[True].state.energy_corr_j,
+                      mons[False].state.energy_corr_j, be, 1e-11)
     np.testing.assert_array_equal(mons[True].state.n_changes,
                                   mons[False].state.n_changes)
     np.testing.assert_array_equal(mons[True].state.run_t,
                                   mons[False].state.run_t)
     for arr in ("t", "v", "e_raw", "e_corr"):
-        np.testing.assert_allclose(getattr(mons[True].ring, arr),
-                                   getattr(mons[False].ring, arr),
-                                   rtol=1e-11, err_msg=f"ring.{arr}")
-    np.testing.assert_allclose(mons[True].update_period_s(),
-                               mons[False].update_period_s(),
-                               rtol=1e-12, equal_nan=True)
+        assert_tier_close(getattr(mons[True].ring, arr),
+                          getattr(mons[False].ring, arr), be, 1e-11,
+                          err_msg=f"ring.{arr}")
+    assert_tier_close(mons[True].update_period_s(),
+                      mons[False].update_period_s(), be, 1e-12,
+                      equal_nan=True)
     for lbl, sf in mons[False].reading_stats().items():
         sg = mons[True].reading_stats()[lbl]
         for key, val in sf.items():
-            np.testing.assert_allclose(sg[key], val, rtol=1e-9,
-                                       err_msg=f"{lbl}.{key}")
+            assert_tier_close(sg[key], val, be, 1e-9,
+                              err_msg=f"{lbl}.{key}")
 
 
 def test_monitor_grid_path_falls_back_on_dirty_slabs(accel_backend):

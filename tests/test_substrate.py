@@ -125,10 +125,7 @@ def test_error_feedback_unbiased_over_window(seed, steps):
 
 
 def test_compressed_psum_matches_plain():
-    try:
-        from jax import shard_map
-    except ImportError:  # jax<0.5 keeps it under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     devs = np.asarray(jax.devices()[:1])
     mesh = Mesh(devs.reshape(1), ("x",))

@@ -1,0 +1,146 @@
+"""TPU v5e compiles of the main path's kernels, without a chip.
+
+Each test lowers and compiles one kernel for a described (not attached)
+v5e chip at the width a deployment runs — a 10⁵-device fleet × 20 poll
+ticks, 10⁵-sample flat slabs, 10⁴-device query batches — so a kernel
+the TPU compiler would refuse (an unaligned tile, an unsupported
+in-kernel op, too much VMEM) fails here, at no chip time.  Nothing runs:
+these tests say nothing about results or speed.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library at a time,
+and every test worker imports every test file.
+"""
+import importlib.util
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
+                          SingleDeviceSharding)
+
+from repro.core.engine_backend import jax_backend as jb  # noqa: E402
+from repro.core.engine_backend import pallas_backend as pb  # noqa: E402
+from repro.core.engine_backend import precision  # noqa: E402
+from repro.core.engine_backend.pytrees import TimelineArrays  # noqa: E402
+
+D, M = 102_400, 20          # fleet × poll ticks per slab (padded to 128s)
+K = 131_072                 # flat slab: samples (a power-of-two bucket)
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2.  Skips only where the TPU library is not
+    installed; a library that is present but fails is an error."""
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed")
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off here."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, *args):
+    compiled = fn.lower(*args).compile()
+    return compiled.as_text()
+
+
+def _kernel_in(text):
+    assert "tpu_custom_call" in text
+
+
+def test_pallas_stream_ingest_grid_compiles(one_chip):
+    s = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    r = s((D,))
+    ri = s((D,), I32)
+    with precision.x32():
+        text = _compile(pb._grid_call, s((M,)), s((M,)), s((D, M)), r, r,
+                        r, ri, r, ri, r, r, r, ri, ri, r, r, r, r, False, M,
+                        False)
+    _kernel_in(text)
+
+
+def test_pallas_stream_ingest_compiles(one_chip):
+    s = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    k, ki = s((K,)), s((K,), I32)
+    with precision.x32():
+        text = _compile(pb._flat_call, k, k, k, ki, k, ki, ki, k, k, k, k,
+                        k, k, k, True, False)
+    _kernel_in(text)
+
+
+def test_pallas_step_integrate_compiles(one_chip):
+    s = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    mat = s((D, M))
+    with precision.x32():
+        text = _compile(pb._step_call, mat, mat, mat, mat, s((D, 1), I32),
+                        s((D, 1), I32), True, M, False)
+    _kernel_in(text)
+
+
+def test_pallas_log_filter_compiles(one_chip):
+    s = lambda shape: jax.ShapeDtypeStruct(shape, F32, sharding=one_chip)
+    with precision.x32():
+        text = _compile(pb._scan_call, s((64, D)), s((64, D)), s((1, D)),
+                        False)
+    _kernel_in(text)
+
+
+def test_jax_stream_ingest_grid_compiles(one_chip):
+    s = lambda shape, dt=jnp.float64: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    r = s((D,))
+    with precision.x64():
+        _compile(jb._stream_ingest_grid_impl, s((M,)), s((D, M)), r, r,
+                 s((D,), jnp.bool_), r, s((D,), jnp.int64), r, r, r, r, r,
+                 r, r, r, False)
+
+
+def test_jax_snapshot_energy_at_compiles(one_chip):
+    q, n, ring = 8, 10_000, 16
+    s = lambda shape, dt=jnp.float64: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    v = s((n,))
+    with precision.x64():
+        _compile(jb._snapshot_energy_at_impl, s((q,)), v, v,
+                 s((n,), jnp.bool_), v, v, v, s((n, ring)), s((n, ring)),
+                 s((n, ring)), True)
+
+
+def test_sharded_audit_kernel_compiles_on_2x2_mesh(topo):
+    from repro.core.fleet_engine_shard import ShardedBackend
+    mesh = Mesh(topo.devices, ("data",))
+    sb = ShardedBackend(mesh)
+    rows, segs = D, 16
+    data = NamedSharding(mesh, PartitionSpec("data"))
+    s = lambda shape, dt=jnp.float64: jax.ShapeDtypeStruct(
+        shape, dt, sharding=data)
+    tl = TimelineArrays(s((rows, segs + 1)), s((rows, segs)), s((rows,)),
+                        s((rows,), jnp.int64))
+    with precision.x64():
+        text = _compile(sb._boxcar[True], tl, s((rows, M)), s((rows, M)))
+    assert "all-gather" not in text and "all-reduce" not in text
