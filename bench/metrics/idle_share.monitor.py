@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or not tr["devices"] or not tr["window_s"]:
+        return None
+    return (1.0 - tr["busy_s"] / tr["window_s"]) * 100.0
