@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.common.trace import span
 from repro.core.engine_backend import precision as _p
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
                                                TimelineArrays)
@@ -298,6 +299,7 @@ def step_integrate(ts: np.ndarray, vals: np.ndarray, t0: np.ndarray,
             bool(trapezoid)))
 
 
+@jax.named_scope("ingest_prev")
 def ingest_prev(t, v, seg, first, prev_t, prev_v, has_prev):
     """Each sample's predecessor ``(pt, pv, has)``: the previous sample
     within the slab, or the stored state at group starts."""
@@ -341,6 +343,7 @@ def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
                        inc, inc_c, w_inc, w_inc_c, vc, change, out)
 
 
+@jax.named_scope("ingest_fold")
 def ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes, inc,
                 inc_c, w_inc, w_inc_c, vc, change, out) -> Tuple:
     """Fold one slab's per-sample increments into per-group results (the
@@ -425,7 +428,7 @@ def ingest_padded(impl, t, v, seg, first, start_idx, end_idx, prev_t,
     t = np.asarray(t, dtype=np.float64)
     first = pad(first, kp, False)
     first[k:k + 1] = True
-    with _p.x64():
+    with span("ingest.kernel.pad", samples=k, slots=kp), _p.x64():
         outs = impl(
             jnp.asarray(pad(t, kp, t[-1] if k else 0.0), _p.FLOAT),
             jnp.asarray(pad(np.asarray(v, np.float64), kp, 0.0), _p.FLOAT),

@@ -48,6 +48,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.common.trace import span
 from repro.core.engine_backend import jax_backend as _jb
 from repro.core.engine_backend import numpy_backend as _nb
 from repro.core.engine_backend import precision as _p
@@ -230,6 +231,7 @@ def _grid_call(ts, dts, v, pt0, dt0, pv0, has0, rt, nchp, g, off, tsh, ja,
     outs = pl.pallas_call(
         functools.partial(_grid_kernel, trapezoid=trapezoid,
                           m_real=m_real, tm=tm),
+        name="ingest_grid",
         grid=(rows // bs, mp // tm),
         in_specs=[smem, smem, tick] + [row] * 15,
         out_specs=[tick] * 4 + [row] * 11,
@@ -275,7 +277,7 @@ def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
     # neutral device padding: has=0 zeroes the increments, gain=1 keeps
     # the division defined, the open envelope keeps the padding out of
     # n_out (all of it is sliced off below)
-    with _p.x32():
+    with span("ingest.kernel.pad", samples=d * m, slots=dp * mp), _p.x32():
         outs = _grid_call(
             _k32(ts - anchor, mp, 0.0), _k32(dts, mp, 0.0),
             _k32(np.pad(v, ((0, 0), (0, mp - m)), mode="edge"), dp, 0.0),
@@ -346,6 +348,7 @@ def _flat_call(v, pv, dt, has, pt, win, winc, g, off, tsh, wb, mh, el, eh,
     shape = lambda dt: jax.ShapeDtypeStruct((rows, _LANES), dt)
     outs = pl.pallas_call(
         functools.partial(_flat_kernel, trapezoid=trapezoid),
+        name="ingest_flat",
         grid=(rows // br,),
         in_specs=[spec] * 14,
         out_specs=[spec] * 7,

@@ -20,6 +20,7 @@ from typing import Dict, Optional, Union
 
 import numpy as np
 
+from repro.common.trace import span
 from repro.core.engine_backend import get_backend, resolve_backend
 from repro.core.fleet_engine import StreamingMoments
 from repro.core.stream.estimators import (OnlinePeriodEstimator,
@@ -267,138 +268,155 @@ class IngestCore:
         either way they never touch state.  Returns an
         :class:`IngestReport`.
         """
-        dev = np.asarray(dev, dtype=np.int64).ravel()
-        t = np.asarray(t, dtype=np.float64).ravel()
-        v = np.asarray(v, dtype=np.float64).ravel()
-        if not (dev.shape == t.shape == v.shape):
-            raise ValueError(f"shape mismatch: dev {dev.shape}, "
-                             f"t {t.shape}, v {v.shape}")
-        n_rej = 0
-        if dev.size and (dev.min() < 0 or dev.max() >= self.n_devices):
-            if self.strict_ids:
-                raise ValueError("device id out of range")
-            ok_id = (dev >= 0) & (dev < self.n_devices)
-            n_rej = int(ok_id.size - ok_id.sum())
-            self._n_rejected += n_rej
-            dev, t, v = dev[ok_id], t[ok_id], v[ok_id]
-        k_in = dev.size
-        if k_in == 0:
-            if n_rej:               # counters mutated: publish fresh
-                self.epoch += 1
-            return IngestReport(0, 0, 0, 0, 0, n_rej)
-        # even an all-dropped slab mutates counters: publish fresh
-        self.epoch += 1
+        # a flat slab learns its devices by grouping: its ``devices``
+        # ride on the ``ingest.kernel`` span
+        with span("ingest", samples=np.size(dev)):
+            return self._ingest(dev, t, v)
 
-        ok = np.isfinite(t) & np.isfinite(v)
-        n_invalid = int(k_in - ok.sum())
-        if n_invalid:
-            self._n_invalid += n_invalid
-            dev, t, v = dev[ok], t[ok], v[ok]
-
-        order = np.lexsort((t, dev))
-        dev, t, v = dev[order], t[order], v[order]
-
-        # duplicates: same (device, t) — keep the first arrival
-        dup = np.zeros(len(dev), dtype=bool)
-        dup[1:] = (dev[1:] == dev[:-1]) & (t[1:] == t[:-1])
+    def _ingest(self, dev, t, v) -> IngestReport:
         st = self.state
-        # vs stored state: strictly older samples arrive late, a repeat
-        # of the newest timestamp is a duplicate
-        late = ~dup & st.has[dev] & (t < st.last_t[dev])
-        dup_state = ~dup & st.has[dev] & (t == st.last_t[dev])
-        n_dup = int(np.sum(dup | dup_state))
-        n_late = int(np.sum(late))
-        if n_dup:
-            np.add.at(st.n_dup, dev[dup | dup_state], 1)
-        if n_late:
-            np.add.at(st.n_late, dev[late], 1)
-        keep = ~(dup | dup_state | late)
-        dev, t, v = dev[keep], t[keep], v[keep]
-        k = dev.size
-        if k == 0:
-            return IngestReport(0, n_dup, n_late, n_invalid, 0, n_rej)
+        with span("ingest.prep"):
+            dev = np.asarray(dev, dtype=np.int64).ravel()
+            t = np.asarray(t, dtype=np.float64).ravel()
+            v = np.asarray(v, dtype=np.float64).ravel()
+            if not (dev.shape == t.shape == v.shape):
+                raise ValueError(f"shape mismatch: dev {dev.shape}, "
+                                 f"t {t.shape}, v {v.shape}")
+            n_rej = 0
+            if dev.size and (dev.min() < 0 or dev.max() >= self.n_devices):
+                if self.strict_ids:
+                    raise ValueError("device id out of range")
+                ok_id = (dev >= 0) & (dev < self.n_devices)
+                n_rej = int(ok_id.size - ok_id.sum())
+                self._n_rejected += n_rej
+                dev, t, v = dev[ok_id], t[ok_id], v[ok_id]
+            k_in = dev.size
+            if k_in == 0:
+                if n_rej:               # counters mutated: publish fresh
+                    self.epoch += 1
+                return IngestReport(0, 0, 0, 0, 0, n_rej)
+            # even an all-dropped slab mutates counters: publish fresh
+            self.epoch += 1
 
-        v = v - self.corrections.baseline_w[dev]
+            ok = np.isfinite(t) & np.isfinite(v)
+            n_invalid = int(k_in - ok.sum())
+            if n_invalid:
+                self._n_invalid += n_invalid
+                dev, t, v = dev[ok], t[ok], v[ok]
 
-        # compact to per-slab groups (devices sorted => contiguous)
-        first = np.empty(k, dtype=bool)
-        first[0] = True
-        first[1:] = dev[1:] != dev[:-1]
-        start_idx = np.flatnonzero(first)
-        end_idx = np.concatenate([start_idx[1:] - 1, [k - 1]])
-        u_dev = dev[start_idx]
-        seg = np.cumsum(first) - 1
+            order = np.lexsort((t, dev))
+            dev, t, v = dev[order], t[order], v[order]
 
-        had = st.has[u_dev]
-        c = self.corrections
-        run_t_in = np.where(had, st.run_t[u_dev], t[start_idx])
-        (new_t, new_v, new_run_t, new_nchg, counts, d_e, d_ec, d_w, d_wc,
-         sum_vc, n_out, cum_e, cum_ec, vc, run_dur, run_rec) = \
-            self._be.stream_ingest(
-                t, v, seg, first, start_idx, end_idx,
-                st.last_t[u_dev], st.last_v[u_dev], had,
-                run_t_in, st.n_changes[u_dev],
-                c.gain[u_dev], c.offset_w[u_dev], c.time_shift_s[u_dev],
-                self._win_a[u_dev], self._win_b[u_dev],
-                self._max_hold[u_dev], self._env_lo[u_dev],
-                self._env_hi[u_dev], self.trapezoid)
+            # duplicates: same (device, t) — keep the first arrival
+            dup = np.zeros(len(dev), dtype=bool)
+            dup[1:] = (dev[1:] == dev[:-1]) & (t[1:] == t[:-1])
+            # vs stored state: strictly older samples arrive late, a
+            # repeat of the newest timestamp is a duplicate
+            late = ~dup & st.has[dev] & (t < st.last_t[dev])
+            dup_state = ~dup & st.has[dev] & (t == st.last_t[dev])
+            n_dup = int(np.sum(dup | dup_state))
+            n_late = int(np.sum(late))
+            if n_dup:
+                np.add.at(st.n_dup, dev[dup | dup_state], 1)
+            if n_late:
+                np.add.at(st.n_late, dev[late], 1)
+            keep = ~(dup | dup_state | late)
+            dev, t, v = dev[keep], t[keep], v[keep]
+            k = dev.size
+            if k == 0:
+                return IngestReport(0, n_dup, n_late, n_invalid, 0, n_rej)
+
+            # compact to per-slab groups (devices sorted => contiguous)
+            first = np.empty(k, dtype=bool)
+            first[0] = True
+            first[1:] = dev[1:] != dev[:-1]
+            start_idx = np.flatnonzero(first)
+            end_idx = np.concatenate([start_idx[1:] - 1, [k - 1]])
+            u_dev = dev[start_idx]
+            seg = np.cumsum(first) - 1
+
+        with span("ingest.gather"):
+            v = v - self.corrections.baseline_w[dev]
+            had = st.has[u_dev]
+            c = self.corrections
+            run_t_in = np.where(had, st.run_t[u_dev], t[start_idx])
+            last_t, last_v = st.last_t[u_dev], st.last_v[u_dev]
+            n_chg = st.n_changes[u_dev]
+            gain, off, tsh = (c.gain[u_dev], c.offset_w[u_dev],
+                              c.time_shift_s[u_dev])
+            win_a, win_b = self._win_a[u_dev], self._win_b[u_dev]
+            hold = self._max_hold[u_dev]
+            env_lo, env_hi = self._env_lo[u_dev], self._env_hi[u_dev]
+
+        with span("ingest.kernel", samples=k, devices=len(u_dev)):
+            (new_t, new_v, new_run_t, new_nchg, counts, d_e, d_ec, d_w,
+             d_wc, sum_vc, n_out, cum_e, cum_ec, vc, run_dur, run_rec) = \
+                self._be.stream_ingest(
+                    t, v, seg, first, start_idx, end_idx, last_t, last_v,
+                    had, run_t_in, n_chg, gain, off, tsh, win_a, win_b,
+                    hold, env_lo, env_hi, self.trapezoid)
 
         # ring snapshots see running totals *before* this slab is folded
-        if self.ring.slots:
-            ordinal = np.arange(k) - start_idx[seg]
-            self.ring.write(dev, ordinal, counts[seg], t, v,
-                            st.energy_j[u_dev][seg] + cum_e,
-                            st.energy_corr_j[u_dev][seg] + cum_ec,
-                            u_dev, counts)
-        else:
-            self.ring.n_written[u_dev] += counts
+        with span("ingest.ring"):
+            if self.ring.slots:
+                ordinal = np.arange(k) - start_idx[seg]
+                self.ring.write(dev, ordinal, counts[seg], t, v,
+                                st.energy_j[u_dev][seg] + cum_e,
+                                st.energy_corr_j[u_dev][seg] + cum_ec,
+                                u_dev, counts)
+            else:
+                self.ring.n_written[u_dev] += counts
 
-        old_last_t = st.last_t[u_dev]
-        st.first_t[u_dev] = np.where(had, st.first_t[u_dev], t[start_idx])
-        st.last_t[u_dev] = new_t
-        st.last_v[u_dev] = new_v
-        st.has[u_dev] = True
-        st.n_samples[u_dev] += counts
-        st.energy_j[u_dev] += d_e
-        st.energy_corr_j[u_dev] += d_ec
-        st.win_j[u_dev] += d_w
-        st.win_corr_j[u_dev] += d_wc
-        st.run_t[u_dev] = new_run_t
-        st.n_changes[u_dev] = new_nchg
-        st.n_out[u_dev] += n_out
+        with span("ingest.scatter"):
+            old_last_t = st.last_t[u_dev]
+            st.first_t[u_dev] = np.where(had, st.first_t[u_dev],
+                                         t[start_idx])
+            st.last_t[u_dev] = new_t
+            st.last_v[u_dev] = new_v
+            st.has[u_dev] = True
+            st.n_samples[u_dev] += counts
+            st.energy_j[u_dev] += d_e
+            st.energy_corr_j[u_dev] += d_ec
+            st.win_j[u_dev] += d_w
+            st.win_corr_j[u_dev] += d_wc
+            st.run_t[u_dev] = new_run_t
+            st.n_changes[u_dev] = new_nchg
+            st.n_out[u_dev] += n_out
 
-        # drift EWMA over wall time, one slab-mean step per device
-        mean_vc = sum_vc / counts
-        alpha = np.exp(-np.maximum(new_t - old_last_t, 0.0)
-                       / self.drift_tau_s)
-        st.ewma_w[u_dev] = np.where(
-            had, alpha * st.ewma_w[u_dev] + (1.0 - alpha) * mean_vc,
-            mean_vc)
+            # drift EWMA over wall time, one slab-mean step per device
+            mean_vc = sum_vc / counts
+            alpha = np.exp(-np.maximum(new_t - old_last_t, 0.0)
+                           / self.drift_tau_s)
+            st.ewma_w[u_dev] = np.where(
+                had, alpha * st.ewma_w[u_dev] + (1.0 - alpha) * mean_vc,
+                mean_vc)
 
-        rec = np.asarray(run_rec, dtype=bool)
-        if np.any(rec):
-            self.periods.record(dev[rec], np.asarray(run_dur)[rec])
+        with span("ingest.periods"):
+            rec = np.asarray(run_rec, dtype=bool)
+            if np.any(rec):
+                self.periods.record(dev[rec], np.asarray(run_dur)[rec])
 
         # per-label corrected-reading moments (Chan–Welford): one
         # bincount pass over the slab, O(K + labels) — no per-label
         # masks, so per-device labels stay cheap at fleet scale
-        codes = self._label_codes[dev]
-        nl = len(self._label_names)
-        cnt = np.bincount(codes, minlength=nl)
-        s1 = np.bincount(codes, weights=vc, minlength=nl)
-        s2 = np.bincount(codes, weights=vc * vc, minlength=nl)
-        av = np.abs(vc)
-        sa = np.bincount(codes, weights=av, minlength=nl)
-        mx = np.zeros(nl)
-        np.maximum.at(mx, codes, av)
-        for ci in np.flatnonzero(cnt):
-            nb = int(cnt[ci])
-            mean = s1[ci] / nb
-            m2 = max(float(s2[ci] - nb * mean * mean), 0.0)
-            self._moments.setdefault(
-                self._label_names[ci], StreamingMoments()).merge(
-                    nb, float(mean), m2, float(sa[ci] / nb),
-                    float(mx[ci]))
+        with span("ingest.moments"):
+            codes = self._label_codes[dev]
+            nl = len(self._label_names)
+            cnt = np.bincount(codes, minlength=nl)
+            s1 = np.bincount(codes, weights=vc, minlength=nl)
+            s2 = np.bincount(codes, weights=vc * vc, minlength=nl)
+            av = np.abs(vc)
+            sa = np.bincount(codes, weights=av, minlength=nl)
+            mx = np.zeros(nl)
+            np.maximum.at(mx, codes, av)
+            for ci in np.flatnonzero(cnt):
+                nb = int(cnt[ci])
+                mean = s1[ci] / nb
+                m2 = max(float(s2[ci] - nb * mean * mean), 0.0)
+                self._moments.setdefault(
+                    self._label_names[ci], StreamingMoments()).merge(
+                        nb, float(mean), m2, float(sa[ci] / nb),
+                        float(mx[ci]))
 
         self._maybe_update_health(float(np.max(new_t)))
         return IngestReport(k, n_dup, n_late, n_invalid, len(u_dev), n_rej)
@@ -416,34 +434,40 @@ class IngestCore:
         accepted sample) fall back to the general :meth:`ingest` path
         with identical semantics.
         """
-        dev = np.asarray(dev, dtype=np.int64).ravel()
-        ts = np.asarray(ts, dtype=np.float64).ravel()
-        vals = np.asarray(vals, dtype=np.float64)
-        d, m = dev.size, ts.size
-        if vals.shape != (d, m):
-            raise ValueError(f"vals must be [{d}, {m}], "
-                             f"got {vals.shape}")
-        if d == 0 or m == 0:
-            return IngestReport(0, 0, 0, 0, 0)
-        n_rej = 0
-        if dev.min() < 0 or dev.max() >= self.n_devices:
-            if self.strict_ids:
-                raise ValueError("device id out of range")
-            ok_id = (dev >= 0) & (dev < self.n_devices)
-            n_rej = int(ok_id.size - ok_id.sum()) * m
-            self._n_rejected += n_rej
-            dev, vals = dev[ok_id], vals[ok_id]
-            d = dev.size
-            if d == 0:
-                self.epoch += 1     # counters mutated: publish fresh
-                return IngestReport(0, 0, 0, 0, 0, n_rej)
+        with span("ingest_grid", samples=np.size(vals),
+                  devices=np.size(dev)):
+            return self._ingest_grid(dev, ts, vals)
 
+    def _ingest_grid(self, dev, ts, vals) -> IngestReport:
         st = self.state
-        clean = (np.all(np.diff(dev) > 0)
-                 and np.all(np.diff(ts) > 0)
-                 and bool(np.all(np.isfinite(ts)))
-                 and bool(np.all(np.isfinite(vals)))
-                 and not np.any(st.has[dev] & (ts[0] <= st.last_t[dev])))
+        with span("ingest.prep"):
+            dev = np.asarray(dev, dtype=np.int64).ravel()
+            ts = np.asarray(ts, dtype=np.float64).ravel()
+            vals = np.asarray(vals, dtype=np.float64)
+            d, m = dev.size, ts.size
+            if vals.shape != (d, m):
+                raise ValueError(f"vals must be [{d}, {m}], "
+                                 f"got {vals.shape}")
+            if d == 0 or m == 0:
+                return IngestReport(0, 0, 0, 0, 0)
+            n_rej = 0
+            if dev.min() < 0 or dev.max() >= self.n_devices:
+                if self.strict_ids:
+                    raise ValueError("device id out of range")
+                ok_id = (dev >= 0) & (dev < self.n_devices)
+                n_rej = int(ok_id.size - ok_id.sum()) * m
+                self._n_rejected += n_rej
+                dev, vals = dev[ok_id], vals[ok_id]
+                d = dev.size
+                if d == 0:
+                    self.epoch += 1     # counters mutated: publish fresh
+                    return IngestReport(0, 0, 0, 0, 0, n_rej)
+
+            clean = (np.all(np.diff(dev) > 0)
+                     and np.all(np.diff(ts) > 0)
+                     and bool(np.all(np.isfinite(ts)))
+                     and bool(np.all(np.isfinite(vals)))
+                     and not np.any(st.has[dev] & (ts[0] <= st.last_t[dev])))
         if not clean:
             rep = self.ingest(np.repeat(dev, m), np.tile(ts, d),
                               vals.ravel())
@@ -451,72 +475,82 @@ class IngestCore:
                     if n_rej else rep)
         self.epoch += 1
 
-        c = self.corrections
-        v = vals - c.baseline_w[dev][:, None]
-        had = st.has[dev]
-        run_t_in = np.where(had, st.run_t[dev], ts[0])
-        (new_v, new_run_t, new_nchg, d_e, d_ec, d_w, d_wc,
-         sum_vc, sum_vc2, sum_abs_vc, max_abs_vc, n_out,
-         cum_e, cum_ec, run_dur, run_rec) = \
-            self._be.stream_ingest_grid(
-                ts, v, st.last_t[dev], st.last_v[dev], had, run_t_in,
-                st.n_changes[dev], c.gain[dev], c.offset_w[dev],
-                c.time_shift_s[dev], self._win_a[dev], self._win_b[dev],
-                self._max_hold[dev], self._env_lo[dev],
-                self._env_hi[dev], self.trapezoid)
+        with span("ingest.gather"):
+            c = self.corrections
+            v = vals - c.baseline_w[dev][:, None]
+            had = st.has[dev]
+            run_t_in = np.where(had, st.run_t[dev], ts[0])
+            last_t, last_v = st.last_t[dev], st.last_v[dev]
+            n_chg = st.n_changes[dev]
+            gain, off, tsh = c.gain[dev], c.offset_w[dev], c.time_shift_s[dev]
+            win_a, win_b = self._win_a[dev], self._win_b[dev]
+            hold = self._max_hold[dev]
+            env_lo, env_hi = self._env_lo[dev], self._env_hi[dev]
+
+        with span("ingest.kernel", samples=d * m, devices=d):
+            (new_v, new_run_t, new_nchg, d_e, d_ec, d_w, d_wc,
+             sum_vc, sum_vc2, sum_abs_vc, max_abs_vc, n_out,
+             cum_e, cum_ec, run_dur, run_rec) = \
+                self._be.stream_ingest_grid(
+                    ts, v, last_t, last_v, had, run_t_in, n_chg, gain, off,
+                    tsh, win_a, win_b, hold, env_lo, env_hi, self.trapezoid)
 
         # ring snapshots see running totals *before* this slab is folded
-        if self.ring.slots:
-            self.ring.write_grid(dev, ts, v,
-                                 st.energy_j[dev][:, None] + cum_e,
-                                 st.energy_corr_j[dev][:, None] + cum_ec)
-        else:
-            self.ring.n_written[dev] += m
+        with span("ingest.ring"):
+            if self.ring.slots:
+                self.ring.write_grid(dev, ts, v,
+                                     st.energy_j[dev][:, None] + cum_e,
+                                     st.energy_corr_j[dev][:, None] + cum_ec)
+            else:
+                self.ring.n_written[dev] += m
 
-        old_last_t = st.last_t[dev]
-        st.first_t[dev] = np.where(had, st.first_t[dev], ts[0])
-        st.last_t[dev] = ts[-1]
-        st.last_v[dev] = new_v
-        st.has[dev] = True
-        st.n_samples[dev] += m
-        st.energy_j[dev] += d_e
-        st.energy_corr_j[dev] += d_ec
-        st.win_j[dev] += d_w
-        st.win_corr_j[dev] += d_wc
-        st.run_t[dev] = new_run_t
-        st.n_changes[dev] = new_nchg
-        st.n_out[dev] += n_out
+        with span("ingest.scatter"):
+            old_last_t = st.last_t[dev]
+            st.first_t[dev] = np.where(had, st.first_t[dev], ts[0])
+            st.last_t[dev] = ts[-1]
+            st.last_v[dev] = new_v
+            st.has[dev] = True
+            st.n_samples[dev] += m
+            st.energy_j[dev] += d_e
+            st.energy_corr_j[dev] += d_ec
+            st.win_j[dev] += d_w
+            st.win_corr_j[dev] += d_wc
+            st.run_t[dev] = new_run_t
+            st.n_changes[dev] = new_nchg
+            st.n_out[dev] += n_out
 
-        mean_vc = sum_vc / m
-        alpha = np.exp(-np.maximum(ts[-1] - old_last_t, 0.0)
-                       / self.drift_tau_s)
-        st.ewma_w[dev] = np.where(
-            had, alpha * st.ewma_w[dev] + (1.0 - alpha) * mean_vc,
-            mean_vc)
+            mean_vc = sum_vc / m
+            alpha = np.exp(-np.maximum(ts[-1] - old_last_t, 0.0)
+                           / self.drift_tau_s)
+            st.ewma_w[dev] = np.where(
+                had, alpha * st.ewma_w[dev] + (1.0 - alpha) * mean_vc,
+                mean_vc)
 
-        rec = np.asarray(run_rec, dtype=bool)
-        if np.any(rec):
-            dgrid = np.broadcast_to(dev[:, None], rec.shape)
-            self.periods.record(dgrid[rec], np.asarray(run_dur)[rec])
+        with span("ingest.periods"):
+            rec = np.asarray(run_rec, dtype=bool)
+            if np.any(rec):
+                dgrid = np.broadcast_to(dev[:, None], rec.shape)
+                self.periods.record(dgrid[rec], np.asarray(run_dur)[rec])
 
         # per-label moments straight from the kernel's per-device
         # reductions — O(D + labels) instead of O(D·M)
-        codes = self._label_codes[dev]
-        nl = len(self._label_names)
-        cnt = m * np.bincount(codes, minlength=nl)
-        s1 = np.bincount(codes, weights=sum_vc, minlength=nl)
-        s2 = np.bincount(codes, weights=sum_vc2, minlength=nl)
-        sa = np.bincount(codes, weights=sum_abs_vc, minlength=nl)
-        mx = np.zeros(nl)
-        np.maximum.at(mx, codes, max_abs_vc)
-        for ci in np.flatnonzero(cnt):
-            nb = int(cnt[ci])
-            mean = s1[ci] / nb
-            m2 = max(float(s2[ci] - nb * mean * mean), 0.0)
-            self._moments.setdefault(
-                self._label_names[ci], StreamingMoments()).merge(
-                    nb, float(mean), m2, float(sa[ci] / nb),
-                    float(mx[ci]))
+        with span("ingest.moments"):
+            codes = self._label_codes[dev]
+            nl = len(self._label_names)
+            cnt = m * np.bincount(codes, minlength=nl)
+            s1 = np.bincount(codes, weights=sum_vc, minlength=nl)
+            s2 = np.bincount(codes, weights=sum_vc2, minlength=nl)
+            sa = np.bincount(codes, weights=sum_abs_vc, minlength=nl)
+            mx = np.zeros(nl)
+            np.maximum.at(mx, codes, max_abs_vc)
+            for ci in np.flatnonzero(cnt):
+                nb = int(cnt[ci])
+                mean = s1[ci] / nb
+                m2 = max(float(s2[ci] - nb * mean * mean), 0.0)
+                self._moments.setdefault(
+                    self._label_names[ci], StreamingMoments()).merge(
+                        nb, float(mean), m2, float(sa[ci] / nb),
+                        float(mx[ci]))
 
         self._maybe_update_health(float(ts[-1]))
         return IngestReport(d * m, 0, 0, 0, d, n_rej)
@@ -533,7 +567,8 @@ class IngestCore:
         if t_now < self._next_health_t:
             return
         self._next_health_t = t_now + self.health_every_s
-        self.update_health(t_now, _bump_epoch=False)
+        with span("ingest.health", devices=self.n_devices):
+            self.update_health(t_now, _bump_epoch=False)
 
     def update_health(self, t_now: float, _bump_epoch: bool = True) -> bool:
         """Evaluate one health step at wall-clock ``t_now`` (no-op
