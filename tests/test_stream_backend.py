@@ -79,6 +79,122 @@ def test_stream_ingest_kernel_parity(accel_backend, trapezoid):
                               err_msg=f"output {i} (trial {trial})")
 
 
+def _group_slab(rng, sizes, *, change=True, first_change=False,
+                window=(1.0, 4.0)):
+    """A slab of ``len(sizes)`` groups of the given sample counts.  With
+    ``change`` off every group repeats its stored reading (no change);
+    with ``first_change`` a group's readings are constant and differ from
+    the stored one, so its only change is its first sample."""
+    sizes = np.asarray(sizes)
+    u, k = sizes.size, int(sizes.sum())
+    seg = np.repeat(np.arange(u), sizes)
+    first = np.r_[True, seg[1:] != seg[:-1]]
+    start_idx = np.flatnonzero(first)
+    end_idx = np.r_[start_idx[1:] - 1, k - 1]
+    t = np.concatenate([np.sort(rng.uniform(0.0, 5.0, n)) for n in sizes])
+    level = np.round(rng.uniform(60.0, 250.0, u) / 25.0) * 25.0
+    if change and not first_change:
+        v = np.round(rng.uniform(60.0, 250.0, k) / 50.0) * 50.0
+    else:
+        v = level[seg]
+    prev_v = level + 25.0 if first_change else level
+    prev_t = rng.uniform(-1.0, -0.1, u)
+    st = (prev_t, prev_v, np.ones(u, bool), prev_t - 0.3,
+          rng.integers(0, 3, u), rng.uniform(0.95, 1.05, u),
+          rng.uniform(-3.0, 3.0, u), np.full(u, 0.025),
+          np.full(u, window[0]), np.full(u, window[1]),
+          np.full(u, np.inf), np.zeros(u), np.full(u, 240.0))
+    return (t, v, seg, first, start_idx, end_idx) + st
+
+
+_FOLD_CASES = {
+    # the window lies after every sample: no increment opens it
+    "zero_window": lambda rng: _group_slab(rng, rng.integers(1, 9, 40),
+                                           window=(10.0, 11.0)),
+    "single_sample_groups": lambda rng: _group_slab(rng, np.ones(70, int)),
+    "change_only_at_first": lambda rng: _group_slab(
+        rng, rng.integers(1, 9, 40), first_change=True),
+    "no_change_carries_run_t": lambda rng: _group_slab(
+        rng, rng.integers(1, 9, 40), change=False),
+    # >= 4,096 samples over >= 500 groups: the scans span many levels
+    "large_slab": lambda rng: _group_slab(rng, rng.integers(1, 15, 600)),
+    # slab of exactly 1,024 samples: the padded tail group is empty
+    "no_tail_samples": lambda rng: _group_slab(rng, np.full(128, 8)),
+    # one padded tail sample, and empty padded groups after the tail
+    "one_tail_sample": lambda rng: _group_slab(rng, np.r_[np.full(127, 8),
+                                                          7]),
+}
+
+
+def _reference_by_group(args):
+    """The numpy reference run on each group of the slab alone: groups
+    are independent, so this is the slab's result with no rounding
+    carried from one group into the next (the reference's whole-slab
+    prefix difference for ``cum_e``/``cum_ec`` rounds at the slab's
+    total: 1.1e-12 relative on the large case)."""
+    t, v, seg, first, start_idx, end_idx = args[:6]
+    outs = []
+    for g, (s, e) in enumerate(zip(start_idx, end_idx)):
+        n = e - s + 1
+        outs.append(nb.stream_ingest(
+            t[s:e + 1], v[s:e + 1], np.zeros(n, int), first[s:e + 1],
+            np.array([0]), np.array([n - 1]),
+            *(x[g:g + 1] for x in args[6:19]), args[19]))
+    return [np.concatenate(o) for o in zip(*outs)]
+
+
+@pytest.mark.parametrize("case", sorted(_FOLD_CASES))
+def test_stream_ingest_fold_edge_groups(accel_backend, case):
+    """The segmented-scan group fold matches the numpy reference on the
+    group shapes its scans restart at, with counts exact and all-zero
+    window sums exactly zero."""
+    jb = get_backend(accel_backend)
+    rng = np.random.default_rng(sorted(_FOLD_CASES).index(case))
+    args = _FOLD_CASES[case](rng) + (False,)
+    outn = nb.stream_ingest(*args)
+    outg = _reference_by_group(args)
+    outj = jb.stream_ingest(*args)
+    assert len(outn) == len(outj) == 16
+    for i, (a, b) in enumerate(zip(outg, outj)):
+        assert_tier_close(b, a, accel_backend, 1e-12, 1e-12,
+                          err_msg=f"output {i}")
+    for i in (3, 4, 10, 15):    # new_n_changes, counts, n_out, run_rec
+        np.testing.assert_array_equal(outj[i], outn[i],
+                                      err_msg=f"output {i}")
+    t, start_idx, run_t, n_changes = args[0], args[4], args[9], args[10]
+    if case == "zero_window":
+        assert np.all(outj[7] == 0.0) and np.all(outj[8] == 0.0)
+    if case == "no_change_carries_run_t":
+        np.testing.assert_array_equal(outj[2], run_t)
+        np.testing.assert_array_equal(outj[3], n_changes)
+    if case == "change_only_at_first":
+        np.testing.assert_array_equal(outj[2], t[start_idx])
+        assert_tier_close(outj[14][start_idx], t[start_idx] - run_t,
+                          accel_backend, 1e-12, 1e-12)
+    if case == "single_sample_groups":
+        np.testing.assert_array_equal(outj[4], 1)
+
+
+@needs_jax
+def test_ingest_fold_lowers_without_scatter():
+    """The group fold reduces by scans and gathers: a scatter, which a
+    TPU runs one update at a time, must not come back into it."""
+    import jax
+    from repro.core.engine_backend import jax_backend
+    from repro.core.engine_backend import precision
+
+    k, u = 1024, 16
+    with precision.x64():
+        f = lambda n: jax.ShapeDtypeStruct((n,), precision.FLOAT)
+        i = lambda n: jax.ShapeDtypeStruct((n,), precision.INT)
+        b = jax.ShapeDtypeStruct((k,), bool)
+        text = jax.jit(jax_backend.ingest_fold).lower(
+            f(k), f(k), i(k), i(u), i(u), f(u), i(u),
+            f(k), f(k), f(k), f(k), f(k), b, b).as_text()
+    assert "stablehlo.gather" in text
+    assert "scatter" not in text
+
+
 @pytest.mark.parametrize("trapezoid", [False, True])
 def test_step_integrate_kernel_parity(accel_backend, trapezoid):
     jb = get_backend(accel_backend)
