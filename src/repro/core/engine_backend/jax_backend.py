@@ -603,8 +603,8 @@ def err_moments(e: np.ndarray):
     if e.size == 0:
         return 0, 0.0, 0.0, 0.0, 0.0
     with _p.x64():
-        mean, m2, mean_abs, max_abs = _err_moments_impl(
-            jnp.asarray(e, _p.FLOAT))
+        mean, m2, mean_abs, max_abs = jax.device_get(_err_moments_impl(
+            jnp.asarray(e, _p.FLOAT)))
     return (int(e.size), float(mean), float(m2), float(mean_abs),
             float(max_abs))
 
@@ -662,3 +662,125 @@ def snapshot_energy_at(tq: np.ndarray, last_t: np.ndarray,
             jnp.asarray(ring_dens, _p.FLOAT),
             jnp.asarray(ring_base, _p.FLOAT), with_ring)
     return np.asarray(e)[:q], np.asarray(covered)[:q]
+
+
+# -- the monitor's history tier (see repro.core.stream.state.HistoryTier) --
+# The tier lives on the device as two float64 [slots, N] arrays.  Writes
+# are functional updates: donated (in place) unless a published snapshot
+# holds the arrays, whose bits then never change.
+
+def history_put(a: np.ndarray):
+    """A host tier array placed on the device (float64)."""
+    with _p.x64():
+        return jnp.asarray(np.asarray(a, dtype=np.float64), _p.FLOAT)
+
+
+def _history_write_impl(e_raw, e_corr, rows, cols, v_raw, v_corr):
+    with jax.named_scope("history_write"):
+        return (e_raw.at[rows, cols].set(v_raw, mode="drop"),
+                e_corr.at[rows, cols].set(v_corr, mode="drop"))
+
+
+_history_write_shared = jax.jit(_history_write_impl)
+_history_write_owned = jax.jit(_history_write_impl, donate_argnums=(0, 1))
+
+
+def history_write(e_raw, e_corr, rows: np.ndarray, cols: np.ndarray,
+                  v_raw: np.ndarray, v_corr: np.ndarray, shared: bool):
+    """Store ``v_raw``/``v_corr`` [P] at ``(rows, cols)`` of the tier
+    (see the numpy backend).  Pairs are padded to a power of two with
+    out-of-range rows, which the scatter drops."""
+    p = rows.shape[0]
+    pp = pad_bucket(p, 1024)
+
+    def pad(x, fill, dtype):
+        out = np.full(pp, fill, dtype=dtype)
+        out[:p] = x
+        return out
+
+    fn = _history_write_shared if shared else _history_write_owned
+    with _p.x64():
+        return fn(e_raw, e_corr,
+                  jnp.asarray(pad(rows, e_raw.shape[0], np.int32)),
+                  jnp.asarray(pad(cols, 0, np.int32)),
+                  jnp.asarray(pad(v_raw, 0.0, np.float64), _p.FLOAT),
+                  jnp.asarray(pad(v_corr, 0.0, np.float64), _p.FLOAT))
+
+
+def history_operands(*arrays) -> tuple:
+    """The per-device operands of the tier's kernels, placed on the
+    device once (a snapshot reuses them for every query)."""
+    with _p.x64():
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def _history_rows(tier, rows, bq, tq, lo, hi, last_t, first_t, has,
+                  max_hold, dens, base):
+    tqc = tq[:, None]
+    dt = tqc - last_t[None, :]
+    hold = jnp.minimum(dt, max_hold[None, :])
+    live = has[None, :] & (dt >= 0.0)
+    e_live = jnp.where(live, base[None, :] + dens[None, :] * hold, 0.0)
+    covered = live | ~has[None, :] | (tqc <= first_t[None, :])
+    started = has[None, :] & (tqc > first_t[None, :])
+    e = jnp.where(started, e_live, 0.0)
+    b = bq[:, None]
+    held = (started & (tqc < last_t[None, :]) & (b >= lo[None, :])
+            & (b <= hi[None, :]))
+    e = jnp.where(held, tier[rows], e)
+    covered = covered | held
+    return jnp.where(covered, e, jnp.nan), covered
+
+
+@jax.jit
+def _history_energy_at_impl(tier, rows, bq, tq, *ops):
+    with jax.named_scope("history_energy_at"):
+        return _history_rows(tier, rows, bq, tq, *ops)
+
+
+def history_energy_at(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+                      tq: np.ndarray):
+    """``(e, covered)`` [Q, N] at boundary instants (see the numpy
+    backend), as one jitted kernel; instants padded to a power of two."""
+    q = tq.shape[0]
+    qp = pad_bucket(q, 8)
+    pad = lambda x: np.concatenate([x, np.repeat(x[-1:], qp - q)])  # noqa
+    with _p.x64():
+        e, covered = _history_energy_at_impl(
+            tier, jnp.asarray(pad(rows).astype(np.int32)),
+            jnp.asarray(pad(bq).astype(np.int32)),
+            jnp.asarray(pad(np.asarray(tq, np.float64)), _p.FLOAT),
+            *ops[:8])
+        e, covered = jax.device_get((e, covered))
+    return e[:q], covered[:q]
+
+
+@jax.jit
+def _history_series_impl(tier, rows, bq, tq, step_s, lo, hi, last_t,
+                         first_t, has, max_hold, dens, base, active, tol):
+    with jax.named_scope("history_series"):
+        e, cov = _history_rows(tier, rows, bq, tq, lo, hi, last_t, first_t,
+                               has, max_hold, dens, base)
+        inc = cov & active[None, :]
+        e0 = jnp.where(inc, e, 0.0)
+        sig = tol[None, :] * jnp.abs(e0)
+        both = inc[1:] & inc[:-1]
+        power = jnp.sum(jnp.where(both, e[1:] - e[:-1], 0.0),
+                        axis=1) / step_s
+        count = lambda m: jnp.sum(m.astype(jnp.int32), axis=1)  # noqa
+        return (jnp.sum(e0, axis=1), count(cov), count(inc),
+                jnp.sum(sig * sig, axis=1), jnp.sum(sig, axis=1), power,
+                count(both))
+
+
+def history_series(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+                   tq: np.ndarray, step_s: float):
+    """The fleet reductions over the tier's rows (see the numpy
+    backend), done on the device: only [Q] numbers come back."""
+    with _p.x64():
+        out = _history_series_impl(
+            tier, jnp.asarray(rows.astype(np.int32)),
+            jnp.asarray(bq.astype(np.int32)),
+            jnp.asarray(np.asarray(tq, np.float64), _p.FLOAT),
+            jnp.asarray(float(step_s), _p.FLOAT), *ops)
+        return tuple(jax.device_get(out))

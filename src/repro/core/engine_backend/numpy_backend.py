@@ -635,3 +635,85 @@ def snapshot_energy_at(tq: np.ndarray, last_t: np.ndarray,
         e = np.where(sel, e_past, e)
         covered = covered | sel
     return np.where(covered, e, np.nan), covered
+
+
+# -- the monitor's history tier (see repro.core.stream.state.HistoryTier) --
+
+def history_put(a: np.ndarray) -> np.ndarray:
+    """A tier array as this backend holds it: the host array itself."""
+    return np.asarray(a, dtype=np.float64)
+
+
+def history_write(e_raw, e_corr, rows: np.ndarray, cols: np.ndarray,
+                  v_raw: np.ndarray, v_corr: np.ndarray, shared: bool):
+    """Store ``v_raw``/``v_corr`` [P] at ``(rows, cols)`` of the tier's
+    ``[slots, N]`` arrays; returns the arrays written.  ``shared``
+    arrays (held by a published snapshot) are left as they are and
+    copies are written."""
+    if shared:
+        e_raw, e_corr = e_raw.copy(), e_corr.copy()
+    e_raw[rows, cols] = v_raw
+    e_corr[rows, cols] = v_corr
+    return e_raw, e_corr
+
+
+def history_operands(*arrays) -> tuple:
+    """The per-device operands of :func:`history_energy_at` and
+    :func:`history_series` as this backend reads them (host arrays)."""
+    return arrays
+
+
+def _history_rows(tier, rows, bq, tq, lo, hi, last_t, first_t, has,
+                  max_hold, dens, base):
+    """Energy since first sample at ``Q`` boundary instants for all
+    ``N`` devices: :func:`snapshot_energy_at`'s rule with the tier in
+    place of the ring.  ``tier`` [S, N]; ``rows`` [Q] the slot of each
+    instant, ``bq`` [Q] its boundary index; ``lo``/``hi`` [N] the
+    boundaries each device's slots hold (indices relative to one
+    reference boundary).  A device's instant between its first and
+    newest sample is answered from its slot where the slot holds that
+    boundary, and is not covered otherwise."""
+    tq = tq[:, None]
+    dt = tq - last_t[None, :]
+    hold = np.minimum(dt, max_hold[None, :])
+    live = has[None, :] & (dt >= 0.0)
+    e_live = np.where(live, base[None, :] + dens[None, :] * hold, 0.0)
+    covered = live | ~has[None, :] | (tq <= first_t[None, :])
+    started = has[None, :] & (tq > first_t[None, :])
+    e = np.where(started, e_live, 0.0)
+    b = bq[:, None]
+    held = (started & (tq < last_t[None, :]) & (b >= lo[None, :])
+            & (b <= hi[None, :]))
+    e = np.where(held, tier[rows], e)
+    covered = covered | held
+    return np.where(covered, e, np.nan), covered
+
+
+def history_energy_at(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+                      tq: np.ndarray):
+    """``(e, covered)`` [Q, N] at boundary instants ``tq`` (see
+    :func:`_history_rows`; ``ops`` from :func:`history_operands`:
+    ``lo, hi, last_t, first_t, has, max_hold, dens, base, active,
+    tol``)."""
+    return _history_rows(tier, rows, bq, np.asarray(tq, np.float64),
+                         *ops[:8])
+
+
+def history_series(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+                   tq: np.ndarray, step_s: float):
+    """The fleet reductions of :func:`history_energy_at`'s rows, per
+    instant [Q]: the energy of the included devices (covered and
+    ``active``), the covered and included counts, the sums of squared
+    and of plain per-device sigmas (``tol · |e|``); and per step
+    [Q - 1]: the energy change of devices included at both ends over
+    ``step_s`` (their power), and how many they are."""
+    e, cov = history_energy_at(tier, ops, rows, bq, tq)
+    active, tol = ops[8], ops[9]
+    inc = cov & active[None, :]
+    e0 = np.where(inc, e, 0.0)
+    sig = tol[None, :] * np.abs(e0)
+    both = inc[1:] & inc[:-1]
+    power = np.sum(np.where(both, e[1:] - e[:-1], 0.0), axis=1) / step_s
+    return (np.sum(e0, axis=1), np.sum(cov, axis=1), np.sum(inc, axis=1),
+            np.sum(sig * sig, axis=1), np.sum(sig, axis=1), power,
+            np.sum(both, axis=1))

@@ -32,8 +32,8 @@ the ``pallas_call`` (on the host, or for ``stream_ingest`` in the
 float64 jit around it).
 Where the platform is the CPU the same kernels run in the Pallas
 interpreter.  Gather-bound kernels with no streaming inner loop
-(``boxcar_means``, ``poll_counts``, ``snapshot_energy_at``, …) are the
-jax tier's.
+(``boxcar_means``, ``poll_counts``, ``snapshot_energy_at``, the history
+tier's ``history_*``, …) are the jax tier's.
 """
 from __future__ import annotations
 
@@ -74,6 +74,11 @@ poll_counts = _jb.poll_counts
 query_slots = _jb.query_slots
 err_moments = _jb.err_moments
 snapshot_energy_at = _jb.snapshot_energy_at
+history_put = _jb.history_put
+history_write = _jb.history_write
+history_operands = _jb.history_operands
+history_energy_at = _jb.history_energy_at
+history_series = _jb.history_series
 
 
 def _interpret() -> bool:

@@ -10,8 +10,9 @@ the fleet is still running.
 
 Layers (see ``docs/streaming.md``):
 
-* :mod:`~repro.core.stream.state` — stacked per-device accumulators and
-  the recent-sample ring buffer (no per-device Python objects);
+* :mod:`~repro.core.stream.state` — stacked per-device accumulators,
+  the recent-sample ring buffer and the optional per-boundary history
+  tier (no per-device Python objects);
 * :mod:`~repro.core.stream.estimators` — the online update-period
   estimator and the stacked §5 correction parameters;
 * :mod:`~repro.core.stream.ingest` — :class:`IngestCore`, the mutable
@@ -48,20 +49,22 @@ from repro.core.stream.estimators import (OnlinePeriodEstimator,
 from repro.core.stream.health import (HEALTHY, QUARANTINED, STALE,
                                       HealthPolicy, HealthTracker)
 from repro.core.stream.ingest import IngestCore
-from repro.core.stream.monitor import (FleetEnergy, IngestReport,
-                                       MonitorService)
+from repro.core.stream.monitor import (FleetEnergy, FleetSeries,
+                                       IngestReport, MonitorService)
 from repro.core.stream.replay import (FaultInjector, FaultSpec,
                                       InjectionLog, StreamFleetResult,
                                       replay, stream_fleet)
 from repro.core.stream.schema import SCHEMA_VERSION, SchemaError
 from repro.core.stream.snapshot import MonitorSnapshot
-from repro.core.stream.state import DeviceState, IngestBuffer
+from repro.core.stream.state import (DeviceState, HistoryTier,
+                                     IngestBuffer)
 from repro.core.stream.supervisor import MonitorSupervisor, SupervisorReport
 
 __all__ = [
-    "DeviceState", "IngestBuffer",
+    "DeviceState", "IngestBuffer", "HistoryTier",
     "OnlinePeriodEstimator", "StreamCorrections", "default_calibrations",
-    "FleetEnergy", "IngestReport", "IngestCore", "MonitorService",
+    "FleetEnergy", "FleetSeries", "IngestReport", "IngestCore",
+    "MonitorService",
     "MonitorSnapshot", "SCHEMA_VERSION", "SchemaError",
     "HEALTHY", "STALE", "QUARANTINED", "HealthPolicy", "HealthTracker",
     "CheckpointError", "MissingCheckpointError",

@@ -44,6 +44,9 @@ class OnlinePeriodEstimator:
         self.edges = np.geomspace(lo_s, hi_s, n_bins - 1)
         self.counts = np.zeros((n_devices, n_bins), dtype=np.int64)
         self.sums = np.zeros((n_devices, n_bins))
+        # (counts, sums, min_runs, estimates, stale rows) of the last
+        # estimates() call: record() marks the rows it changes stale
+        self._est = None
 
     @property
     def n_bins(self) -> int:
@@ -61,6 +64,8 @@ class OnlinePeriodEstimator:
         b = np.searchsorted(self.edges, durations, side="right")
         np.add.at(self.counts, (dev, b), 1)
         np.add.at(self.sums, (dev, b), durations)
+        if self._est is not None:
+            self._est[4][dev] = True
 
     @property
     def n_runs(self) -> np.ndarray:
@@ -69,14 +74,33 @@ class OnlinePeriodEstimator:
     def estimates(self) -> np.ndarray:
         """[N] update-period estimates; nan below ``min_runs`` complete
         runs (the offline estimator's guard against phase-biased
-        short captures)."""
-        n = self.n_runs
-        cum = np.cumsum(self.counts, axis=1)
+        short captures).  Only the rows :meth:`record` changed since
+        the last call are computed again, unless they are most rows: one
+        pass over every row is then cheaper than gathering them."""
+        c = self._est
+        if (c is None or c[0] is not self.counts or c[1] is not self.sums
+                or c[2] != self.min_runs
+                or 2 * np.count_nonzero(c[4]) > c[4].size):
+            est = self._estimate(self.counts, self.sums)
+            stale = np.zeros(self.counts.shape[0], dtype=bool)
+            self._est = (self.counts, self.sums, self.min_runs, est, stale)
+        else:
+            est, stale = c[3], c[4]
+            rows = np.flatnonzero(stale)
+            if rows.size:
+                est[rows] = self._estimate(self.counts[rows],
+                                           self.sums[rows])
+                stale[rows] = False
+        return est.copy()
+
+    def _estimate(self, counts: np.ndarray, sums: np.ndarray) -> np.ndarray:
+        n = counts.sum(axis=1)
+        cum = np.cumsum(counts, axis=1)
         need = (n + 1) // 2
         bstar = np.argmax(cum >= need[:, None], axis=1)
-        rows = np.arange(self.counts.shape[0])
-        cnt = self.counts[rows, bstar]
-        est = self.sums[rows, bstar] / np.maximum(cnt, 1)
+        rows = np.arange(counts.shape[0])
+        cnt = counts[rows, bstar]
+        est = sums[rows, bstar] / np.maximum(cnt, 1)
         return np.where((n >= self.min_runs) & (cnt > 0), est, np.nan)
 
 

@@ -26,7 +26,7 @@ from repro.core.fleet_engine import StreamingMoments
 from repro.core.stream.estimators import (OnlinePeriodEstimator,
                                           StreamCorrections)
 from repro.core.stream.health import HealthPolicy, HealthTracker
-from repro.core.stream.state import DeviceState, IngestBuffer
+from repro.core.stream.state import DeviceState, HistoryTier, IngestBuffer
 
 _INTEGRATIONS = ("rectangle", "trapezoid")
 
@@ -67,6 +67,8 @@ class IngestCore:
                  strict_ids: bool = True,
                  health: Optional[HealthPolicy] = None,
                  health_every_s: float = 0.0,
+                 history_step_s: Optional[float] = None,
+                 history_steps: int = 0,
                  backend: Optional[str] = None):
         if n_devices < 1:
             raise ValueError("need at least one device")
@@ -115,6 +117,10 @@ class IngestCore:
 
         self.state = DeviceState.zeros(n)
         self.ring = IngestBuffer(n, ring_slots)
+        if history_steps and history_step_s is None:
+            raise ValueError("history_steps needs history_step_s")
+        self.history = (HistoryTier(n, history_step_s, history_steps,
+                                    self._be) if history_steps else None)
         self.periods = OnlinePeriodEstimator(n, n_bins=period_bins,
                                              min_runs=min_runs)
         # windows disabled until registered: [+inf, -inf] selects nothing
@@ -165,7 +171,9 @@ class IngestCore:
         state without a schema update fails here first."""
         return (self.state.nbytes() + self.ring.nbytes()
                 + self.periods.nbytes()
-                + (self.health.nbytes() if self.health is not None else 0))
+                + (self.health.nbytes() if self.health is not None else 0)
+                + (self.history.nbytes() if self.history is not None
+                   else 0))
 
     def grow(self, n_new: int, *,
              corrections: Optional[StreamCorrections] = None,
@@ -243,6 +251,8 @@ class IngestCore:
                                            "HealthTracker"):
                 setattr(self.health, k, np.concatenate(
                     [getattr(self.health, k), getattr(hp, k)]))
+        if self.history is not None:
+            self.history.grow(n_add)
 
         # config vectors: tail rows take a fresh monitor's defaults
         self._max_hold = np.concatenate([self._max_hold,
@@ -366,6 +376,10 @@ class IngestCore:
                                 u_dev, counts)
             else:
                 self.ring.n_written[u_dev] += counts
+
+        if self.history is not None:
+            self._history_flat(u_dev, had, t, v, start_idx, end_idx,
+                               cum_e, cum_ec)
 
         with span("ingest.scatter"):
             old_last_t = st.last_t[u_dev]
@@ -504,6 +518,9 @@ class IngestCore:
             else:
                 self.ring.n_written[dev] += m
 
+        if self.history is not None:
+            self._history_grid(dev, had, ts, v, cum_e, cum_ec)
+
         with span("ingest.scatter"):
             old_last_t = st.last_t[dev]
             st.first_t[dev] = np.where(had, st.first_t[dev], ts[0])
@@ -554,6 +571,62 @@ class IngestCore:
 
         self._maybe_update_health(float(ts[-1]))
         return IngestReport(d * m, 0, 0, 0, d, n_rej)
+
+    # -- history tier ------------------------------------------------------
+    # Boundaries are written before the slab's state is scattered: a
+    # boundary held from the device's previous newest sample reads that
+    # sample's stored energy and reading.
+
+    def _history_grid(self, dev, had, ts, v, cum_e, cum_ec) -> None:
+        """Write the boundaries a rectangular slab passes: the sample
+        each boundary holds from is the same column for every device."""
+        st = self.state
+        row, b = self.history.plan(
+            dev, np.where(had, st.first_t[dev], ts[0]), ts[-1])
+        with span("ingest.history", devices=dev.size, boundaries=b.size):
+            j = np.searchsorted(ts, b * self.history.step_s,
+                                side="right") - 1
+            jc = np.maximum(j, 0)
+            self._history_record(dev[row], b, j >= 0, ts[jc], v[row, jc],
+                                 cum_e[row, jc], cum_ec[row, jc])
+
+    def _history_flat(self, u_dev, had, t, v, start_idx, end_idx, cum_e,
+                      cum_ec) -> None:
+        """Write the boundaries a sorted, grouped slab passes: each
+        boundary's sample is found by bisection within its device's
+        group."""
+        st = self.state
+        row, b = self.history.plan(
+            u_dev, np.where(had, st.first_t[u_dev], t[start_idx]),
+            t[end_idx])
+        with span("ingest.history", devices=u_dev.size, boundaries=b.size):
+            bt = b * self.history.step_s
+            lo, hi = start_idx[row], end_idx[row] + 1
+            width = int(np.max(hi - lo, initial=0))
+            for _ in range(width.bit_length()):
+                mid = np.minimum((lo + hi) // 2, t.size - 1)
+                right = (lo < hi) & (t[mid] <= bt)
+                hi = np.where((lo < hi) & ~right, mid, hi)
+                lo = np.where(right, mid + 1, lo)
+            j = lo - 1
+            jc = np.maximum(j, 0)
+            self._history_record(u_dev[row], b, j >= start_idx[row], t[jc],
+                                 v[jc], cum_e[jc], cum_ec[jc])
+
+    def _history_record(self, d, b, in_slab, t_j, v_j, cum_j, cumc_j):
+        """Held energy at boundaries ``b`` of devices ``d`` [P]: from the
+        slab's sample ``j`` where ``in_slab`` (its running energy is the
+        stored one plus ``cum_j``), else from the device's stored newest
+        sample — the ring's rule, in the same operations."""
+        st, c = self.state, self.corrections
+        e_raw, e_corr = st.energy_j[d], st.energy_corr_j[d]
+        e_raw = np.where(in_slab, e_raw + cum_j, e_raw)
+        e_corr = np.where(in_slab, e_corr + cumc_j, e_corr)
+        t_j = np.where(in_slab, t_j, st.last_t[d])
+        v_j = np.where(in_slab, v_j, st.last_v[d])
+        hold = np.minimum(b * self.history.step_s - t_j, self._max_hold[d])
+        vc = (v_j - c.offset_w[d]) / c.gain[d]
+        self.history.write(d, b, e_raw + v_j * hold, e_corr + vc * hold)
 
     # -- health -----------------------------------------------------------
     def _maybe_update_health(self, t_now: float) -> None:

@@ -38,9 +38,11 @@ import numpy as np
 from repro.core.stream.estimators import StreamCorrections
 from repro.core.stream.health import HealthPolicy
 from repro.core.stream.ingest import IngestCore, IngestReport
-from repro.core.stream.snapshot import FleetEnergy, MonitorSnapshot
+from repro.core.stream.snapshot import (FleetEnergy, FleetSeries,
+                                         MonitorSnapshot)
 
-__all__ = ["FleetEnergy", "HealthPolicy", "IngestReport", "MonitorService"]
+__all__ = ["FleetEnergy", "FleetSeries", "HealthPolicy", "IngestReport",
+           "MonitorService"]
 
 
 class MonitorService:
@@ -67,6 +69,14 @@ class MonitorService:
     :class:`~repro.core.stream.snapshot.MonitorSnapshot` (see
     :meth:`snapshot`); hold one to pin a consistent view across several
     queries while ingestion continues.
+
+    History beyond the ring (off by default): ``history_steps > 0``
+    keeps each device's running energy at every ``history_step_s``
+    boundary, ``history_steps`` boundaries back
+    (:class:`~repro.core.stream.state.HistoryTier`, held on the device
+    by the accelerated backends).  Boundary instants within that horizon
+    are then answered from it in every query, and :meth:`fleet_series`
+    serves fleet energy and power over it.
     """
 
     def __init__(self, n_devices: int, *,
@@ -85,6 +95,8 @@ class MonitorService:
                  strict_ids: bool = True,
                  health: Optional[HealthPolicy] = None,
                  health_every_s: float = 0.0,
+                 history_step_s: Optional[float] = None,
+                 history_steps: int = 0,
                  backend: Optional[str] = None):
         self._core = IngestCore(
             n_devices, corrections=corrections, labels=labels,
@@ -94,7 +106,8 @@ class MonitorService:
             silent_after_s=silent_after_s, drift_tau_s=drift_tau_s,
             drift_rel=drift_rel, drift_abs_w=drift_abs_w,
             strict_ids=strict_ids, health=health,
-            health_every_s=health_every_s, backend=backend)
+            health_every_s=health_every_s, history_step_s=history_step_s,
+            history_steps=history_steps, backend=backend)
         self._snap: Optional[MonitorSnapshot] = None
 
     # -- layer access ------------------------------------------------------
@@ -105,9 +118,10 @@ class MonitorService:
 
     def snapshot(self) -> MonitorSnapshot:
         """The current epoch's immutable published view, created lazily
-        and reused until the next slab lands — copy-on-write: holding an
-        old snapshot while ingestion continues is free and its answers
-        stay bitwise stable."""
+        and reused until the next slab lands — copy-on-write: the next
+        slab writes the ring and the history tier in place unless a
+        snapshot is still held, and then copies what it writes first, so
+        a held snapshot's answers stay bitwise stable."""
         if self._snap is None or self._snap.epoch != self._core.epoch:
             self._snap = MonitorSnapshot.publish(self._core)
         return self._snap
@@ -156,6 +170,11 @@ class MonitorService:
     def periods(self):
         return self._core.periods
 
+    @property
+    def history(self):
+        """The history tier (None unless ``history_steps > 0``)."""
+        return self._core.history
+
     # -- configuration -----------------------------------------------------
     def set_windows(self, a, b) -> None:
         self._core.set_windows(a, b)
@@ -174,11 +193,13 @@ class MonitorService:
 
     # -- ingestion ---------------------------------------------------------
     def ingest(self, dev, t, v) -> IngestReport:
+        self._snap = None       # let the slab write in place if unheld
         return self._core.ingest(dev, t, v)
 
     ingest.__doc__ = IngestCore.ingest.__doc__
 
     def ingest_grid(self, dev, ts, vals) -> IngestReport:
+        self._snap = None
         return self._core.ingest_grid(dev, ts, vals)
 
     ingest_grid.__doc__ = IngestCore.ingest_grid.__doc__
@@ -208,6 +229,12 @@ class MonitorService:
         return self.snapshot().by_label(t0, t1, corrected)
 
     by_label.__doc__ = MonitorSnapshot.by_label.__doc__
+
+    def fleet_series(self, t0: float, t1: float, step_s: float,
+                     corrected: bool = True):
+        return self.snapshot().fleet_series(t0, t1, step_s, corrected)
+
+    fleet_series.__doc__ = MonitorSnapshot.fleet_series.__doc__
 
     def reading_stats(self) -> Dict[str, Dict[str, float]]:
         return self.snapshot().reading_stats()

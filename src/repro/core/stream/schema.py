@@ -33,7 +33,9 @@ import numpy as np
 #: v2: health-machine arrays (``health.*``, present only when the
 #: monitor tracks health) + ``strict_ids``/``health``/``health_every_s``
 #: /``next_health_t``/``n_rejected`` meta.
-SCHEMA_VERSION = 2
+#: v3: history-tier arrays (``history.*``, present only when the monitor
+#: keeps a history tier) + ``history_step_s``/``history_steps`` meta.
+SCHEMA_VERSION = 3
 
 # -- field registries (name -> expected dtype kind) -------------------------
 DEVICE_STATE_FIELDS = {
@@ -73,6 +75,14 @@ MOMENT_FIELDS = {"n": "i8", "mean": "f8", "m2": "f8",
 #: with a :class:`~repro.core.stream.health.HealthPolicy`.
 HEALTH_FIELDS = {"code": "i1", "since_t": "f8", "clean_t": "f8",
                  "clean": "b1", "last_n_out": "i8", "n_quarantines": "i8"}
+
+
+#: history tier: each device's written boundary range (host arrays) and
+#: the ``[slots, N]`` running-energy arrays the backend holds (host
+#: arrays on the numpy backend, hence optional in the registry walk);
+#: present only when the monitor keeps a history tier.
+HISTORY_FIELDS = {"b_first": "i8", "b_last": "i8"}
+HISTORY_TIER_FIELDS = {"e_raw": "f8", "e_corr": "f8"}
 
 
 class SchemaError(RuntimeError):
@@ -177,6 +187,15 @@ def pack_monitor(mon) -> Tuple[Dict[str, np.ndarray], Dict[str, Any]]:
         for k, v in check_registry(core.health, HEALTH_FIELDS,
                                    "HealthTracker").items():
             arrays[f"health.{k}"] = v.copy()
+    history = core.history
+    if history is not None:
+        check_registry(history, HISTORY_FIELDS, "HistoryTier",
+                       optional=HISTORY_TIER_FIELDS)
+        for k in HISTORY_FIELDS:
+            arrays[f"history.{k}"] = getattr(history, k).copy()
+        for k in HISTORY_TIER_FIELDS:
+            arrays[f"history.{k}"] = np.array(getattr(history, k),
+                                              dtype=np.float64)
     meta = {
         "schema_version": SCHEMA_VERSION,
         "n_devices": int(core.n_devices),
@@ -195,6 +214,9 @@ def pack_monitor(mon) -> Tuple[Dict[str, np.ndarray], Dict[str, Any]]:
         "health": (None if core.health_policy is None
                    else core.health_policy.to_meta()),
         "health_every_s": float(core.health_every_s),
+        "history_step_s": (None if history is None
+                           else float(history.step_s)),
+        "history_steps": 0 if history is None else int(history.steps),
         # -inf (never evaluated) is not JSON-able; None stands in
         "next_health_t": (None if core._next_health_t == -np.inf
                           else float(core._next_health_t)),
@@ -218,6 +240,9 @@ def expected_keys(meta: Dict[str, Any]) -> set:
     keys |= {f"moments.{k}" for k in MOMENT_FIELDS}
     if meta.get("health") is not None:
         keys |= {f"health.{k}" for k in HEALTH_FIELDS}
+    if meta.get("history_steps"):
+        keys |= {f"history.{k}"
+                 for k in {**HISTORY_FIELDS, **HISTORY_TIER_FIELDS}}
     return keys
 
 
@@ -269,6 +294,8 @@ def unpack_monitor(arrays: Dict[str, np.ndarray], meta: Dict[str, Any],
         strict_ids=bool(meta["strict_ids"]),
         health=policy,
         health_every_s=float(meta["health_every_s"]),
+        history_step_s=meta["history_step_s"],
+        history_steps=int(meta["history_steps"]),
         backend=backend if backend is not None else meta["backend"])
     core = mon._core
     for k in DEVICE_STATE_FIELDS:
@@ -296,6 +323,12 @@ def unpack_monitor(arrays: Dict[str, np.ndarray], meta: Dict[str, Any],
     if core.health is not None:
         for k in HEALTH_FIELDS:
             setattr(core.health, k, arrays[f"health.{k}"].copy())
+    if core.history is not None:
+        for k in HISTORY_FIELDS:
+            setattr(core.history, k, arrays[f"history.{k}"].copy())
+        for k in HISTORY_TIER_FIELDS:
+            setattr(core.history, k, core._be.history_put(
+                arrays[f"history.{k}"].copy()))
     core._n_invalid = int(meta["n_invalid"])
     core._n_rejected = int(meta["n_rejected"])
     core._next_health_t = (-np.inf if meta["next_health_t"] is None

@@ -3,10 +3,12 @@
 :class:`MonitorSnapshot` is the read side of the ingest/serve split: a
 compact copy-on-write capture of everything queries need — the
 :class:`~repro.core.stream.state.DeviceState` accumulators, the ring
-buffer *pre-sorted* per device, the online period estimates, per-label
-moments and ingestion counters — published at a slab boundary and never
-mutated again (every captured array is marked read-only; writing to one
-raises).  Readers therefore never touch mutable ingest state: a held
+buffer (held by reference and sorted per device on first use; the
+ingest core copies it before writing while a snapshot holds it), the
+online period estimates, per-label moments and ingestion counters —
+published at a slab boundary and never mutated again (every copied
+array is marked read-only; writing to one raises).  Readers therefore
+never touch mutable ingest state: a held
 snapshot keeps answering bitwise-identically while ingestion races
 ahead, and the :attr:`epoch` tag makes results cacheable by
 ``(query, epoch)``.
@@ -20,7 +22,8 @@ edge contract, pinned by ``tests/test_serving.py``:
   wherever covered.
 * Instants beyond the ring horizon (older than the oldest retained
   sample of a reporting device) answer ``nan`` with ``covered=False``
-  — never a silently-wrong number.
+  — never a silently-wrong number.  With a history tier the same holds
+  beyond the tier's horizon for boundary instants.
 * ``by_label`` groups with no covered device report ``mean_j``/
   ``std_j`` of ``nan`` (and ``total_j`` 0.0) — including every group of
   a never-ingested monitor.
@@ -31,6 +34,15 @@ devices as one array op — the substrate of the
 :class:`~repro.serve.monitor_service.MonitorQueryService` executor —
 and are elementwise-identical to the single-instant paths (the scalar
 methods are the ``Q=1`` case of the same kernel).
+
+On a monitor with a history tier
+(:class:`~repro.core.stream.state.HistoryTier`) the snapshot holds the
+tier's arrays by reference — publication copies none of them — and
+every query answers an instant that is a tier boundary within the
+tier's horizon from the tier (:meth:`on_tier`), any other instant from
+the ring.
+:meth:`fleet_series` reduces the tier over devices on the backend and
+returns per-instant fleet numbers only.
 """
 from __future__ import annotations
 
@@ -39,9 +51,11 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.common.trace import span
 from repro.core.fleet_engine import StreamingMoments
 from repro.core.stream.health import QUARANTINED, STALE
-from repro.core.stream.state import DeviceState
+from repro.core.stream.state import (DeviceState, HistoryView,
+                                     boundary_from)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,10 +93,66 @@ class FleetEnergy:
     n_quarantined: int = 0
 
 
+@dataclasses.dataclass(frozen=True)
+class FleetSeries:
+    """A ``fleet_series`` answer: fleet energy at every step-aligned
+    instant ``t`` [Q] of a range, and fleet power over each step [Q - 1].
+
+    Per instant, the reductions of :class:`FleetEnergy`: ``total_j`` over
+    included devices (covered and not quarantined), ``n_covered``,
+    ``n_quarantined`` (covered but quarantined), ``coverage`` (included
+    share of the fleet) and the two sigma bounds with the same
+    degraded-mode widening.  Per step, ``power_w`` is the energy change
+    over ``step_s`` of the devices included at both ends, ``n_power``
+    how many they are.
+    """
+
+    t: np.ndarray
+    step_s: float
+    corrected: bool
+    total_j: np.ndarray
+    n_covered: np.ndarray
+    n_quarantined: np.ndarray
+    coverage: np.ndarray
+    sigma_independent_j: np.ndarray
+    sigma_worstcase_j: np.ndarray
+    power_w: np.ndarray
+    n_power: np.ndarray
+
+
+def series_range(t0: float, t1: float, step_s: float) -> tuple:
+    """Validate a series range: finite ``t0 <= t1`` and a finite,
+    positive ``step_s``.  Returns them as floats."""
+    t0, t1, step_s = float(t0), float(t1), float(step_s)
+    if not (np.isfinite(t0) and np.isfinite(t1) and t1 >= t0):
+        raise ValueError(f"bad series range [{t0}, {t1}]")
+    if not (np.isfinite(step_s) and step_s > 0.0):
+        raise ValueError(f"series step must be a positive number, "
+                         f"got {step_s}")
+    return t0, t1, step_s
+
+
+def series_multiple(step_s: float, history_step_s: float) -> int:
+    """How many history steps one series step spans; raises
+    ``ValueError`` unless ``step_s`` is a whole multiple of
+    ``history_step_s``."""
+    m = int(round(step_s / history_step_s))
+    if m < 1 or abs(m * history_step_s - step_s) > 1e-9 * step_s:
+        raise ValueError(f"series step {step_s} is not a multiple of "
+                         f"the history step {history_step_s}")
+    return m
+
+
 def _frozen(arr: np.ndarray) -> np.ndarray:
     out = arr.copy()
     out.setflags(write=False)
     return out
+
+
+def _frozen_new(arr: np.ndarray) -> np.ndarray:
+    """``arr``, a new array, made read-only."""
+    arr.setflags(write=False)
+    return arr
 
 
 def _copy_moments(sm: StreamingMoments) -> StreamingMoments:
@@ -101,19 +171,24 @@ class MonitorSnapshot:
     def __init__(self, *, epoch, n_devices, backend, be, state, ring_view,
                  ring_slots, period_est, moments, counters, corrections,
                  labels, win_a, win_b, max_hold, silent_after_s,
-                 drift_tau_s, drift_rel, drift_abs_w, health_code=None):
+                 drift_tau_s, drift_rel, drift_abs_w, health_code=None,
+                 history: Optional[HistoryView] = None, label_codes=None,
+                 label_names=None):
         self.epoch = epoch
         self.n_devices = n_devices
         self.backend = backend
         self._be = be
         self.state = state
-        self._ring_view = ring_view          # (t, v, e_raw, e_corr) or None
+        self._ring_view = ring_view          # RingView or None
+        self._ring_sorted: Optional[tuple] = None
         self.ring_slots = ring_slots
         self._period_est = period_est
         self._moments = moments
         self._counters = counters
         self.corrections = corrections
         self.labels = labels
+        self._label_codes = label_codes      # [N] index into label_names
+        self._label_names = label_names      # sorted distinct labels
         self._win_a = win_a
         self._win_b = win_b
         self._max_hold = max_hold
@@ -122,21 +197,30 @@ class MonitorSnapshot:
         self.drift_rel = drift_rel
         self.drift_abs_w = drift_abs_w
         self._health_code = health_code      # [N] i1 codes or None
+        self._history = history
         self._flavor_cache: Dict[bool, tuple] = {}
+        self._history_ops_cache: Dict[Optional[bool], tuple] = {}
 
     @classmethod
     def publish(cls, core) -> "MonitorSnapshot":
         """Capture a copy-on-write view of an
         :class:`~repro.core.stream.ingest.IngestCore` at its current
-        epoch.  The ring is captured already sorted oldest→newest (one
-        gather here instead of one per query)."""
+        epoch.  The ring and the history tier are held by reference, not
+        copied (the ingest core copies them before a write while a
+        snapshot still holds them); the ring is sorted oldest→newest on
+        the first query that reads it."""
+        with span("snapshot.publish"):
+            return cls._publish(core)
+
+    @classmethod
+    def _publish(cls, core) -> "MonitorSnapshot":
         st = core.state
         state = DeviceState(**{
             f.name: _frozen(getattr(st, f.name))
             for f in dataclasses.fields(DeviceState)})
         ring_view = None
         if core.ring.slots:
-            ring_view = tuple(_frozen(a) for a in core.ring.sorted_view())
+            ring_view = core.ring.share()
         return cls(
             epoch=core.epoch, n_devices=core.n_devices,
             backend=core.backend, be=core._be, state=state,
@@ -145,28 +229,38 @@ class MonitorSnapshot:
             moments={k: _copy_moments(v) for k, v in core._moments.items()},
             counters=dict(core.counters),
             corrections=core.corrections, labels=_frozen(core.labels),
+            label_codes=_frozen(core._label_codes),
+            label_names=list(core._label_names),
             win_a=_frozen(core._win_a), win_b=_frozen(core._win_b),
             max_hold=_frozen(core._max_hold),
             silent_after_s=core.silent_after_s,
             drift_tau_s=core.drift_tau_s, drift_rel=core.drift_rel,
             drift_abs_w=core.drift_abs_w,
             health_code=(_frozen(core.health.code)
-                         if core.health is not None else None))
+                         if core.health is not None else None),
+            history=(core.history.share() if core.history is not None
+                     else None))
 
     # -- batched kernels --------------------------------------------------
+    def _tail(self, corrected: bool):
+        """Per-flavour (raw/corrected) density and running energy of
+        each device's newest sample."""
+        st, c = self.state, self.corrections
+        if corrected:
+            return (st.last_v - c.offset_w) / c.gain, st.energy_corr_j
+        return st.last_v, st.energy_j
+
     def _flavor(self, corrected: bool):
         """Per-flavour (raw/corrected) tail + ring arrays for the
         snapshot-view kernel, computed once per snapshot."""
         if corrected not in self._flavor_cache:
-            st, c = self.state, self.corrections
-            if corrected:
-                dens = (st.last_v - c.offset_w) / c.gain
-                base = st.energy_corr_j
-            else:
-                dens = st.last_v
-                base = st.energy_j
+            c = self.corrections
+            dens, base = self._tail(corrected)
             if self._ring_view is not None:
-                ts, vs, er, ec = self._ring_view
+                if self._ring_sorted is None:
+                    self._ring_sorted = tuple(
+                        _frozen_new(a) for a in self._ring_view.sorted_view())
+                ts, vs, er, ec = self._ring_sorted
                 if corrected:
                     ring_dens = (vs - c.offset_w[:, None]) / c.gain[:, None]
                     ring_base = ec
@@ -178,12 +272,75 @@ class MonitorSnapshot:
                                              ring_base)
         return self._flavor_cache[corrected]
 
+    def on_tier(self, tq: np.ndarray) -> np.ndarray:
+        """[Q] bool: which instants the history tier answers (tier
+        boundaries within its horizon); all False without a tier."""
+        tq = np.asarray(tq, dtype=np.float64).ravel()
+        if self._history is None:
+            return np.zeros(tq.shape, dtype=bool)
+        return self._history.boundary_of(tq)[1]
+
+    def _history_ops(self, corrected: bool) -> tuple:
+        """The tier kernels' per-device operands, placed by the
+        backend once per snapshot: each device's covered boundaries
+        relative to the newest (int32), its tail state (the flavour's
+        density and running energy placed once per flavour), the health
+        mask and the sigma tolerance."""
+        if corrected not in self._history_ops_cache:
+            if None not in self._history_ops_cache:
+                self._history_ops_cache[None] = self._history_shared_ops()
+            shared = self._history_ops_cache[None]
+            tail = self._be.history_operands(*self._tail(corrected))
+            self._history_ops_cache[corrected] = (shared[:6] + tail
+                                                  + shared[6:])
+        return self._history_ops_cache[corrected]
+
+    def _history_shared_ops(self) -> tuple:
+        from repro.core.telemetry import (CALIBRATED_TOLERANCE,
+                                          SHUNT_TOLERANCE)
+        h, st = self._history, self.state
+        ref, lim = h.b_newest, 2 ** 30
+        empty = h.b_hi < h.b_lo
+        lo = np.where(empty, 1, np.clip(h.b_lo - ref, -lim, lim))
+        hi = np.where(empty, 0, np.clip(h.b_hi - ref, -lim, lim))
+        active = self.active_mask
+        if active is None:
+            active = np.ones(self.n_devices, dtype=bool)
+        tol = np.where(self.corrections.calibrated,
+                       CALIBRATED_TOLERANCE, SHUNT_TOLERANCE)
+        return self._be.history_operands(
+            lo.astype(np.int32), hi.astype(np.int32), st.last_t,
+            st.first_t, st.has, self._max_hold, active, tol)
+
+    def _history_args(self, tq: np.ndarray, corrected: bool) -> tuple:
+        """``(tier, ops, rows, b)`` for the tier kernels at boundary
+        instants ``tq``."""
+        h = self._history
+        b = h.boundary_of(tq)[0]
+        lim = 2 ** 30 + 1           # past every clipped device bound
+        return ((h.e_corr if corrected else h.e_raw),
+                self._history_ops(corrected), b % h.slots,
+                np.clip(b - h.b_newest, -lim, lim))
+
     def energy_at_batch(self, tq: np.ndarray, corrected: bool = True
                         ) -> Tuple[np.ndarray, np.ndarray]:
         """Energy since first sample at instants ``tq`` [Q] for every
         device: ``(e, covered)`` [Q, N], nan where an instant predates
-        ring coverage."""
+        coverage: the history tier's for instants it answers
+        (:meth:`on_tier`), the ring's for the others."""
         tq = np.asarray(tq, dtype=np.float64).ravel()
+        on = self.on_tier(tq)
+        if not on.any():
+            return self._ring_energy_at(tq, corrected)
+        e = np.empty((tq.size, self.n_devices))
+        covered = np.empty((tq.size, self.n_devices), dtype=bool)
+        e[on], covered[on] = self._be.history_energy_at(
+            *self._history_args(tq[on], corrected), tq[on])
+        if not on.all():
+            e[~on], covered[~on] = self._ring_energy_at(tq[~on], corrected)
+        return e, covered
+
+    def _ring_energy_at(self, tq: np.ndarray, corrected: bool):
         st = self.state
         dens, base, ring_t, ring_dens, ring_base = self._flavor(corrected)
         return self._be.snapshot_energy_at(
@@ -310,6 +467,55 @@ class MonitorSnapshot:
             np.array([float(t0), float(t1)]), corrected)
         return self.between_from_rows(em[0], cm[0], em[1], cm[1])
 
+    def series_instants(self, t0: float, t1: float,
+                        step_s: float) -> np.ndarray:
+        """The instants of :meth:`fleet_series`: the multiples of
+        ``step_s`` in ``[t0, t1]``, each formed as a boundary of the
+        history tier.  Raises ``ValueError`` without a tier, for a bad
+        range (:func:`series_range`), or where ``step_s`` is not a whole
+        multiple of the tier's step."""
+        t0, t1, step_s = series_range(t0, t1, step_s)
+        h = self._history
+        if h is None:
+            raise ValueError("fleet_series needs a history tier "
+                             "(history_steps > 0)")
+        m = series_multiple(step_s, h.step_s)
+        b_lo = int(boundary_from(t0, h.step_s))
+        b_hi = int(boundary_from(t1, h.step_s))
+        if b_hi * h.step_s > t1:
+            b_hi -= 1
+        return np.arange(-(-b_lo // m), b_hi // m + 1) * m * h.step_s
+
+    def fleet_series(self, t0: float, t1: float, step_s: float,
+                     corrected: bool = True) -> FleetSeries:
+        """Fleet energy at every multiple of ``step_s`` in ``[t0, t1]``
+        and fleet power over each step, from the history tier: the
+        reductions of :meth:`fleet_from_rows` and
+        :meth:`between_from_rows` (quarantined devices excluded, sigmas
+        widened), done over devices by the backend, so only [Q] numbers
+        reach the host.  Instants older than the tier's horizon leave
+        devices that had reported by then not covered."""
+        tq = self.series_instants(t0, t1, step_s)
+        with span("serve.series", instants=tq.size, devices=self.n_devices):
+            total, n_cov, n_inc, s2, s1, power, n_pow = \
+                self._be.history_series(*self._history_args(tq, corrected),
+                                        tq, step_s)
+        n_cov, n_inc = n_cov.astype(np.int64), n_inc.astype(np.int64)
+        n_q = n_cov - n_inc
+        with np.errstate(divide="ignore", invalid="ignore"):
+            widen = n_cov / n_inc
+            si = np.where(n_q == 0, np.sqrt(s2),
+                          np.where(n_inc > 0, widen * np.sqrt(s2), np.inf))
+            sw = np.where(n_q == 0, s1,
+                          np.where(n_inc > 0, widen * s1, np.inf))
+        out = dict(t=tq, total_j=total, n_covered=n_cov, n_quarantined=n_q,
+                   coverage=n_inc / self.n_devices, sigma_independent_j=si,
+                   sigma_worstcase_j=sw, power_w=power,
+                   n_power=n_pow.astype(np.int64))
+        return FleetSeries(step_s=float(step_s), corrected=corrected,
+                           **{k: _frozen(np.asarray(v))
+                              for k, v in out.items()})
+
     def by_label(self, t0: Optional[float] = None,
                  t1: Optional[float] = None,
                  corrected: bool = True) -> Dict[str, Dict[str, float]]:
@@ -331,10 +537,19 @@ class MonitorSnapshot:
         else:
             e, covered = self.energy_between(t0, t1, corrected)
             covered = covered & st.has
+        return self.by_label_rows(e, covered)
+
+    def by_label_rows(self, e: np.ndarray,
+                      covered: np.ndarray) -> Dict[str, Dict[str, float]]:
+        """The by-label grouping of one [N] energy row (the reductions
+        :meth:`by_label` and the batched executor share), over the
+        integer label codes the ingest core keeps."""
         active = self.active_mask
+        codes = self._label_codes
+        n_dev = np.bincount(codes, minlength=len(self._label_names))
         out: Dict[str, Dict[str, float]] = {}
-        for label in np.unique(self.labels):
-            sel = (self.labels == label) & covered
+        for ci, label in enumerate(self._label_names):
+            sel = (codes == ci) & covered
             n_q = 0
             if active is not None:
                 n_q = int(np.sum(sel & ~active))
@@ -343,8 +558,8 @@ class MonitorSnapshot:
             sm = StreamingMoments().update(vals, self._be)
             stats = sm.stats()
             n_cov = int(np.sum(sel))
-            out[str(label)] = {
-                "n_devices": int(np.sum(self.labels == label)),
+            out[label] = {
+                "n_devices": int(n_dev[ci]),
                 "n_covered": n_cov,
                 "n_quarantined": n_q,
                 "total_j": float(np.sum(vals)) if vals.size else 0.0,

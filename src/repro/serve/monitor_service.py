@@ -3,12 +3,16 @@
 The serving counterpart of :mod:`repro.serve.engine`'s slot loop, for
 monitor queries instead of token decoding: callers ``submit`` any mix
 of ``fleet_energy`` / ``window_energy`` / ``energy_between`` /
-``by_label`` requests, and ``flush`` executes the whole batch against
-**one** immutable :class:`~repro.core.stream.snapshot.MonitorSnapshot`:
+``by_label`` / ``fleet_series`` requests, and ``flush`` executes the
+whole batch against **one** immutable
+:class:`~repro.core.stream.snapshot.MonitorSnapshot`:
 
 * all distinct query instants of a flavour collapse into a single
-  ``snapshot_energy_at`` kernel call ([Q, N] — one vectorized array op
-  however many thousand requests are queued);
+  energy-at call ([Q, N] — one vectorized array op however many
+  thousand requests are queued; the history tier's kernel for
+  instants on its boundaries, ``snapshot_energy_at`` for the rest);
+* each ``fleet_series`` (a dashboard panel over the history tier) is
+  one series kernel call that reduces over devices on the backend;
 * results are memoised in an LRU cache keyed ``(query, epoch)`` —
   an epoch tag in every key means a result can never be served against
   a different snapshot than the one that computed it;
@@ -34,10 +38,13 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.common.trace import span
 from repro.core.stream.monitor import MonitorService
-from repro.core.stream.snapshot import MonitorSnapshot
+from repro.core.stream.snapshot import (MonitorSnapshot, series_multiple,
+                                        series_range)
 
-_KINDS = ("fleet_energy", "window_energy", "energy_between", "by_label")
+_KINDS = ("fleet_energy", "window_energy", "energy_between", "by_label",
+          "fleet_series")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,6 +58,7 @@ class MonitorQuery:
     t0: Optional[float] = None
     t1: Optional[float] = None
     corrected: bool = True
+    step: Optional[float] = None
 
     def __post_init__(self):
         if self.kind not in _KINDS:
@@ -89,6 +97,18 @@ class MonitorQuery:
                 raise ValueError(f"bad window [{t0}, {t1}]")
         return cls("by_label", t0=t0, t1=t1, corrected=corrected)
 
+    @classmethod
+    def fleet_series(cls, t0: float, t1: float, step: float,
+                     corrected: bool = True) -> "MonitorQuery":
+        """Fleet energy at each multiple of ``step`` in ``[t0, t1]`` and
+        power over each step (a dashboard panel).  Raises for a
+        non-finite or reversed range and a step that is not a positive
+        number; a step that is not a multiple of the monitor's history
+        step raises at :meth:`MonitorQueryService.submit`."""
+        t0, t1, step = series_range(t0, t1, step)
+        return cls("fleet_series", t0=t0, t1=t1, corrected=corrected,
+                   step=step)
+
 
 class MonitorQueryService:
     """Queue + batch executor + ``(query, epoch)`` LRU over one monitor.
@@ -112,6 +132,12 @@ class MonitorQueryService:
         self.n_hits = 0
         self.n_misses = 0
         self.n_flushes = 0
+        # where each executed instant was answered from, and series
+        # kernel calls
+        self.n_instants_tier = 0
+        self.n_instants_ring = 0
+        self.n_instants_uncovered = 0
+        self.n_series_calls = 0
 
     # -- request management ------------------------------------------------
     def submit(self, query: MonitorQuery) -> int:
@@ -120,6 +146,12 @@ class MonitorQueryService:
         if not isinstance(query, MonitorQuery):
             raise TypeError(f"submit takes a MonitorQuery, "
                             f"got {type(query).__name__}")
+        if query.kind == "fleet_series":
+            history = self.monitor.history
+            if history is None:
+                raise ValueError("fleet_series needs a monitor with a "
+                                 "history tier (history_steps > 0)")
+            series_multiple(query.step, history.step_s)
         ticket = self._next_ticket
         self._next_ticket += 1
         self.n_submitted += 1
@@ -173,19 +205,26 @@ class MonitorQueryService:
                 misses.append(q)
                 self.n_misses += len(tickets_for[q])
 
-        for q, res in self._execute(snap, misses).items():
-            results[q] = res
-            if self.cache_size:
-                self._cache[(q, epoch)] = res
-        while len(self._cache) > self.cache_size:
-            self._cache.popitem(last=False)
+        with span("serve.flush", queries=len(pending), misses=len(misses)):
+            for q, res in self._execute(snap, misses).items():
+                results[q] = res
+                if self.cache_size:
+                    self._cache[(q, epoch)] = res
+            while len(self._cache) > self.cache_size:
+                self._cache.popitem(last=False)
 
-        return {ticket: results[q]
-                for q, ts in tickets_for.items() for ticket in ts}
+            return {ticket: results[q]
+                    for q, ts in tickets_for.items() for ticket in ts}
 
     def _execute(self, snap: MonitorSnapshot,
                  misses: List[MonitorQuery]) -> Dict[MonitorQuery, Any]:
         """Run the deduplicated cache misses against one snapshot."""
+        with span("serve.execute"):
+            return self._execute_misses(snap, misses)
+
+    def _execute_misses(self, snap: MonitorSnapshot,
+                        misses: List[MonitorQuery]
+                        ) -> Dict[MonitorQuery, Any]:
         out: Dict[MonitorQuery, Any] = {}
         # collect every energy-at instant per corrected flavour:
         # fleet_energy(t) needs one row, energy_between(t0, t1) two
@@ -209,7 +248,12 @@ class MonitorQueryService:
                         and q.t0 is not None:
                     plan.append((q, (row_of(q.t0), row_of(q.t1))))
             if plan:
-                e, cov = snap.energy_at_batch(np.array(instants), corrected)
+                tq = np.array(instants)
+                e, cov = snap.energy_at_batch(tq, corrected)
+                on = snap.on_tier(tq)
+                self.n_instants_tier += int(np.sum(on))
+                self.n_instants_ring += int(np.sum(~on))
+                self.n_instants_uncovered += int(np.sum(~cov.all(axis=1)))
                 for q, rows in plan:
                     if q.kind == "fleet_energy":
                         (r,) = rows
@@ -222,8 +266,8 @@ class MonitorQueryService:
                         if q.kind == "energy_between":
                             out[q] = (de, dc)
                         else:
-                            out[q] = self._by_label_from_rows(
-                                snap, de, dc & snap.state.has)
+                            out[q] = snap.by_label_rows(
+                                de, dc & snap.state.has)
 
             # window_energy: all instants of a flavour in one broadcast
             wq = [q for q in misses
@@ -240,6 +284,16 @@ class MonitorQueryService:
                 for q in wq:
                     out[q] = we[wseen[q.t]].copy()
 
+        # each series is one reduction kernel over the history tier
+        for q in misses:
+            if q.kind == "fleet_series":
+                res = snap.fleet_series(q.t0, q.t1, q.step, q.corrected)
+                out[q] = res
+                self.n_series_calls += 1
+                self.n_instants_tier += int(res.t.size)
+                self.n_instants_uncovered += int(
+                    np.sum(res.n_covered < snap.n_devices))
+
         # the t=None / since-start variants read snapshot arrays directly
         for q in misses:
             if q in out:
@@ -254,38 +308,11 @@ class MonitorQueryService:
                 raise AssertionError(f"unplanned query {q}")
         return out
 
-    @staticmethod
-    def _by_label_from_rows(snap: MonitorSnapshot, e: np.ndarray,
-                            covered: np.ndarray) -> Dict[str, Dict[str, float]]:
-        """The by-label grouping over a precomputed energy row (same
-        reductions — including the degraded-mode quarantine exclusion —
-        as ``MonitorSnapshot.by_label``)."""
-        from repro.core.fleet_engine import StreamingMoments
-        active = snap.active_mask
-        out: Dict[str, Dict[str, float]] = {}
-        for label in np.unique(snap.labels):
-            sel = (snap.labels == label) & covered
-            n_q = 0
-            if active is not None:
-                n_q = int(np.sum(sel & ~active))
-                sel = sel & active
-            vals = e[sel]
-            sm = StreamingMoments().update(vals, snap._be)
-            stats = sm.stats()
-            n_cov = int(np.sum(sel))
-            out[str(label)] = {
-                "n_devices": int(np.sum(snap.labels == label)),
-                "n_covered": n_cov,
-                "n_quarantined": n_q,
-                "total_j": float(np.sum(vals)) if vals.size else 0.0,
-                "mean_j": stats["mean_err"] if n_cov else float("nan"),
-                "std_j": stats["std_err"] if n_cov else float("nan"),
-            }
-        return out
-
     # -- accounting --------------------------------------------------------
     def stats(self) -> Dict[str, float]:
-        """Executor counters: submissions, cache hit rate, flushes."""
+        """Executor counters: submissions, cache hit rate, flushes, the
+        instants executed from the history tier, from the ring and with
+        some device not covered, and series kernel calls."""
         answered = self.n_hits + self.n_misses
         return {
             "n_submitted": self.n_submitted,
@@ -296,4 +323,8 @@ class MonitorQueryService:
             "cache_hit_rate": (self.n_hits / answered) if answered else 0.0,
             "cache_entries": len(self._cache),
             "n_flushes": self.n_flushes,
+            "instants_tier": self.n_instants_tier,
+            "instants_ring": self.n_instants_ring,
+            "instants_uncovered": self.n_instants_uncovered,
+            "series_calls": self.n_series_calls,
         }

@@ -280,6 +280,36 @@ def test_grow_bitwise_equals_upfront_construction():
     _assert_monitor_equal(grown, upfront)
 
 
+def test_grow_with_history_bitwise_equals_upfront_construction():
+    """Hot-adding devices to a monitor with a history tier widens the
+    tier: the grown monitor's tier and series equal, bit for bit, those
+    of a monitor built at the full width from the start."""
+    hist = {"history_step_s": 0.25, "history_steps": 8}
+    uuids, batch = _stream_rows()
+    pipe = CollectorPipeline(slab_samples=128, now=0.0, monitor_kwargs=hist)
+    for chunk in _chunks(batch, 37):
+        pipe.feed(chunk)
+    grown = pipe.finish()
+    assert grown.n_devices == 4
+
+    reg = DeviceRegistry(uuids)
+    asm = SlabAssembler(reg, slab_samples=128)
+    upfront = MonitorService(4, strict_ids=False, backend="numpy", **hist)
+    for chunk in _chunks(batch, 37):
+        for dev, t, v in asm.push(chunk):
+            upfront.ingest(dev, t, v)
+    for dev, t, v in asm.flush():
+        upfront.ingest(dev, t, v)
+    _assert_monitor_equal(grown, upfront)
+    for arr in ("b_first", "b_last", "e_raw", "e_corr"):
+        np.testing.assert_array_equal(getattr(grown.history, arr),
+                                      getattr(upfront.history, arr), arr)
+    a, b = grown.fleet_series(1.0, 2.75, 0.25), upfront.fleet_series(
+        1.0, 2.75, 0.25)
+    np.testing.assert_array_equal(a.total_j, b.total_j)
+    np.testing.assert_array_equal(a.n_covered, b.n_covered)
+
+
 def test_slab_boundaries_independent_of_feed_chunking():
     """Pipeline state depends on (stream, slab_samples) only — not on
     how the file reader chunked its batches."""

@@ -140,7 +140,7 @@ def test_snapshot_arrays_refuse_writes():
     with pytest.raises((ValueError, RuntimeError)):
         snap.labels[0] = "oops"
     with pytest.raises((ValueError, RuntimeError)):
-        snap._ring_view[0][0, 0] = -1.0
+        snap._ring_view.t[0, 0] = -1.0
     # and the capture really is a copy: mutating live state (as the next
     # ingest does) leaves the snapshot untouched
     live_before = float(snap.state.energy_corr_j[0])
@@ -447,6 +447,60 @@ def test_restore_resumes_bitwise(backend, tmp_path):
     for arr in ("t", "v", "e_raw", "e_corr", "n_written"):
         np.testing.assert_array_equal(getattr(resumed.ring, arr),
                                       getattr(ref.ring, arr), arr)
+
+
+def _history_fingerprint(mon):
+    """Series and boundary answers of a monitor with a history tier
+    (step 0.5 s, 6 steps)."""
+    fs = mon.fleet_series(0.5, 4.0, 0.5)
+    e, c = mon.snapshot().energy_at_batch(np.arange(0.0, 4.5, 0.5))
+    return {"series_total": fs.total_j, "series_power": fs.power_w,
+            "series_cov": fs.n_covered, "series_sig": fs.sigma_worstcase_j,
+            "at_e": e, "at_cov": c}
+
+
+def test_restore_with_history_resumes_bitwise(backend, tmp_path):
+    """Kill and resume a monitor with a history tier at a slab boundary:
+    the tier itself and every answer read from it continue bitwise."""
+    n = 10
+    slabs = _slabs(n, n_slabs=8, seed=19)
+    kw = dict(history_step_s=0.5, history_steps=6)
+    ref = _monitor(n, backend, seed=19, **kw)
+    for dev, t, v in slabs:
+        ref.ingest(dev, t, v)
+    live = _monitor(n, backend, seed=19, **kw)
+    for dev, t, v in slabs[:5]:
+        live.ingest(dev, t, v)
+    save_monitor(live, str(tmp_path / "ckpt"))
+    resumed = restore_monitor(str(tmp_path / "ckpt"), backend=backend)
+    del live
+    for dev, t, v in slabs[5:]:
+        resumed.ingest(dev, t, v)
+    _assert_fingerprints_equal(_query_fingerprint(resumed),
+                               _query_fingerprint(ref))
+    _assert_fingerprints_equal(_history_fingerprint(resumed),
+                               _history_fingerprint(ref))
+    for arr in ("b_first", "b_last", "e_raw", "e_corr"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(resumed.history, arr)),
+            np.asarray(getattr(ref.history, arr)), arr)
+
+
+def test_restore_with_history_needs_no_jax(tmp_path):
+    """A tier written by an accelerated backend restores on numpy."""
+    mon = _monitor(5, "numpy", history_step_s=0.5, history_steps=4)
+    for dev, t, v in _slabs(5, 4, seed=2):
+        mon.ingest(dev, t, v)
+    save_monitor(mon, str(tmp_path / "ckpt"))
+    back = restore_monitor(str(tmp_path / "ckpt"), backend="numpy")
+    assert isinstance(back.history.e_corr, np.ndarray)
+    np.testing.assert_array_equal(back.history.e_corr, mon.history.e_corr)
+    arrays, meta = stream_schema.pack_monitor(mon)
+    assert meta["history_steps"] == 4 and "history.e_raw" in arrays
+    missing = dict(arrays)
+    missing.pop("history.b_last")
+    with pytest.raises(SchemaError, match="b_last"):
+        stream_schema.unpack_monitor(missing, meta)
 
 
 def test_restore_into_fresh_process_bitwise(backend, tmp_path):

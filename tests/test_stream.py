@@ -365,6 +365,33 @@ def test_online_period_estimator_unit():
     assert est.n_runs[0] == 9
 
 
+@pytest.mark.parametrize("change", ["record", "record_most", "replace",
+                                    "min_runs"])
+def test_online_period_estimates_recompute_only_changed_rows(change):
+    """Estimates kept between calls equal a fresh estimator's on the same
+    histograms, whether rows were recorded, the arrays replaced (growth,
+    restore) or ``min_runs`` changed in between."""
+    rng = np.random.default_rng(5)
+    est = OnlinePeriodEstimator(40, min_runs=3)
+    for _ in range(3):
+        est.record(rng.integers(0, 40, 60), rng.uniform(0.01, 0.3, 60))
+        first = est.estimates()
+    first[:] = -1.0                     # a copy: the cache is untouched
+    if change == "record":
+        est.record(rng.integers(0, 8, 30), rng.uniform(0.5, 2.0, 30))
+    elif change == "record_most":
+        est.record(np.arange(30), rng.uniform(0.5, 2.0, 30))
+    elif change == "replace":
+        est.counts = np.concatenate([est.counts, est.counts[:5] * 3])
+        est.sums = np.concatenate([est.sums, est.sums[:5] * 3])
+    else:
+        est.min_runs = 6
+    fresh = OnlinePeriodEstimator(est.counts.shape[0],
+                                  min_runs=est.min_runs)
+    fresh.counts, fresh.sums = est.counts.copy(), est.sums.copy()
+    np.testing.assert_array_equal(est.estimates(), fresh.estimates())
+
+
 def test_online_period_matches_offline_estimator():
     """Streaming the §4.1 square-wave capture through the monitor lands
     on the same update period as the offline median-of-complete-runs."""

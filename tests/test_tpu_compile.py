@@ -144,3 +144,27 @@ def test_sharded_audit_kernel_compiles_on_2x2_mesh(topo):
     with precision.x64():
         text = _compile(sb._boxcar[True], tl, s((rows, M)), s((rows, M)))
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+def test_jax_history_kernels_compile(one_chip):
+    """The history tier's series, energy-at and write kernels at the
+    dashboard deployment's width: 301 boundaries × 10⁵ devices."""
+    slots, n, q, pairs = 301, 100_000, 301, 16_384
+    s = lambda shape, dt=jnp.float64: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    v, b = s((n,)), s((n,), jnp.bool_)
+    ops = (s((n,), I32), s((n,), I32), v, v, b, v, v, v, b, v)
+    tier = s((slots, n))
+    with precision.x64():
+        texts = [
+            _compile(jb._history_series_impl, tier, s((q,), I32),
+                     s((q,), I32), s((q,)), s(()), *ops),
+            _compile(jb._history_energy_at_impl, tier, s((8,), I32),
+                     s((8,), I32), s((8,)), *ops[:8]),
+            _compile(jb._history_write_shared, tier, tier, s((pairs,), I32),
+                     s((pairs,), I32), s((pairs,)), s((pairs,)))]
+    # each kernel's operations carry its scope in their op names, which a
+    # device trace reports as their ``tf_op``
+    for text, scope in zip(texts, ("history_series", "history_energy_at",
+                                   "history_write")):
+        assert f"/{scope}/" in text, scope
