@@ -437,48 +437,87 @@ def stream_ingest(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
                          max_hold, env_lo, env_hi, bool(trapezoid))
 
 
+def fill_rows(out: np.ndarray, cols) -> np.ndarray:
+    """Each row of ``out`` [R, N] set from one ``(values, fill)`` pair of
+    ``cols``: the values cast to ``out``'s dtype, then the fill.  The
+    padding wrappers pack a slab's operands this way, one host buffer
+    per dtype, so that the slab makes one transfer each way."""
+    for row, (x, fill) in zip(out, cols):
+        n = len(x)
+        row[:n] = x
+        row[n:] = fill
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
+def _ingest_packed(impl, fbuf, ibuf, kp: int, static: tuple):
+    """``impl`` on a slab packed by dtype, its outputs packed the same way.
+
+    ``fbuf`` (float64) holds ``t`` and ``v`` [Kp], then ``prev_t``,
+    ``prev_v``, ``run_t``, ``gain``, ``offset``, ``tshift``, ``win_a``,
+    ``win_b``, ``max_hold``, ``env_lo`` and ``env_hi`` [Up]; ``ibuf``
+    (int64) holds ``seg`` and ``first`` [Kp], then ``start_idx``,
+    ``end_idx``, ``has_prev`` and ``n_changes`` [Up].  Out come the eight
+    float per-group results [8, Up] and the four float per-sample ones
+    [4, Kp] as one float64 buffer, and the three integer per-group
+    results [3, Up] and ``run_rec`` [Kp] as one int64 buffer."""
+    up = (fbuf.shape[0] - 2 * kp) // 11
+    t, v = fbuf[:2 * kp].reshape(2, kp)
+    (prev_t, prev_v, run_t, gain, offset, tshift, win_a, win_b, max_hold,
+     env_lo, env_hi) = fbuf[2 * kp:].reshape(11, up)
+    seg, first = ibuf[:2 * kp].reshape(2, kp)
+    start_idx, end_idx, has_prev, n_changes = ibuf[2 * kp:].reshape(4, up)
+    (new_t, new_v, new_run_t, new_n_changes, counts, d_energy,
+     d_energy_corr, d_win, d_win_corr, sum_vc, n_out, e_cum, e_cum_c, vc,
+     run_dur, run_rec) = impl(
+         t, v, seg, first != 0, start_idx, end_idx, prev_t, prev_v,
+         has_prev != 0, run_t, n_changes, gain, offset, tshift, win_a,
+         win_b, max_hold, env_lo, env_hi, *static)
+    return (jnp.concatenate([new_t, new_v, new_run_t, d_energy,
+                             d_energy_corr, d_win, d_win_corr, sum_vc,
+                             e_cum, e_cum_c, vc, run_dur]),
+            jnp.concatenate([new_n_changes, counts, n_out,
+                             run_rec.astype(_p.INT)]))
+
+
 def ingest_padded(impl, t, v, seg, first, start_idx, end_idx, prev_t,
                   prev_v, has_prev, run_t, n_changes, gain, offset, tshift,
                   win_a, win_b, max_hold, env_lo, env_hi, *static) -> Tuple:
     """Run the jitted slab kernel ``impl`` under 64-bit mode on the slab
     padded to power-of-two ``(K, U)`` buckets (the tail samples form one
     extra, inert group), so slabs of varying size share a few
-    compilations; ``static`` follows the 19 array arguments."""
+    compilations; ``static`` follows the 19 array arguments.  The slab
+    goes to the device in one put of one float64 and one int64 buffer,
+    and its results come back in one fetch of two such buffers
+    (:func:`_ingest_packed`)."""
     k, u = len(t), len(start_idx)
     kp, up = pad_bucket(k, 1024), pad_bucket(u + 1, 8)
-
-    def pad(x, n, fill):
-        x = np.asarray(x)
-        return np.concatenate([x, np.full(n - len(x), fill, x.dtype)])
-
     t = np.asarray(t, dtype=np.float64)
-    first = pad(first, kp, False)
-    first[k:k + 1] = True
-    with span("ingest.kernel.pad", samples=k, slots=kp), _p.x64():
-        outs = impl(
-            jnp.asarray(pad(t, kp, t[-1] if k else 0.0), _p.FLOAT),
-            jnp.asarray(pad(np.asarray(v, np.float64), kp, 0.0), _p.FLOAT),
-            jnp.asarray(pad(seg, kp, u), _p.INT),
-            jnp.asarray(first, jnp.bool_),
-            jnp.asarray(np.concatenate(
-                [start_idx, [k], np.full(up - u - 1, kp - 1)]), _p.INT),
-            jnp.asarray(pad(end_idx, up, kp - 1), _p.INT),
-            jnp.asarray(pad(prev_t, up, 0.0), _p.FLOAT),
-            jnp.asarray(pad(prev_v, up, 0.0), _p.FLOAT),
-            jnp.asarray(pad(has_prev, up, False), jnp.bool_),
-            jnp.asarray(pad(run_t, up, 0.0), _p.FLOAT),
-            jnp.asarray(pad(n_changes, up, 0), _p.INT),
-            jnp.asarray(pad(gain, up, 1.0), _p.FLOAT),
-            jnp.asarray(pad(offset, up, 0.0), _p.FLOAT),
-            jnp.asarray(pad(tshift, up, 0.0), _p.FLOAT),
-            jnp.asarray(pad(win_a, up, np.inf), _p.FLOAT),
-            jnp.asarray(pad(win_b, up, -np.inf), _p.FLOAT),
-            jnp.asarray(pad(max_hold, up, 0.0), _p.FLOAT),
-            jnp.asarray(pad(env_lo, up, -np.inf), _p.FLOAT),
-            jnp.asarray(pad(env_hi, up, np.inf), _p.FLOAT),
-            *static)
-    return (tuple(np.asarray(o)[:u] for o in outs[:11])
-            + tuple(np.asarray(o)[:k] for o in outs[11:]))
+    fbuf = np.empty(2 * kp + 11 * up)
+    fill_rows(fbuf[:2 * kp].reshape(2, kp),
+              [(t, t[-1] if k else 0.0), (v, 0.0)])
+    fill_rows(fbuf[2 * kp:].reshape(11, up), [
+        (prev_t, 0.0), (prev_v, 0.0), (run_t, 0.0), (gain, 1.0),
+        (offset, 0.0), (tshift, 0.0), (win_a, np.inf), (win_b, -np.inf),
+        (max_hold, 0.0), (env_lo, -np.inf), (env_hi, np.inf)])
+    ibuf = np.empty(2 * kp + 4 * up, np.int64)
+    per_sample = fill_rows(ibuf[:2 * kp].reshape(2, kp),
+                           [(seg, u), (first, 0)])
+    per_sample[1, k:k + 1] = 1      # the tail samples' group starts
+    fill_rows(ibuf[2 * kp:].reshape(4, up), [
+        (np.r_[start_idx, k], kp - 1), (end_idx, kp - 1), (has_prev, 0),
+        (n_changes, 0)])
+    # the float64 put stays inside x64: outside it, jax would cast the
+    # buffer to float32
+    with span("ingest.kernel.pad", samples=k, slots=kp, h2d=2,
+              d2h=2), _p.x64():
+        fo, io = jax.device_get(_ingest_packed(
+            impl, *jax.device_put((fbuf, ibuf)), kp, static))
+    g = fo[:8 * up].reshape(8, up)[:, :u]
+    s = fo[8 * up:].reshape(4, kp)[:, :k]
+    gi = io[:3 * up].reshape(3, up)[:, :u]
+    return (g[0], g[1], g[2], gi[0], gi[1], g[3], g[4], g[5], g[6], g[7],
+            gi[2], s[0], s[1], s[2], s[3], io[3 * up:3 * up + k] != 0)
 
 
 @functools.partial(jax.jit, static_argnums=(15,))

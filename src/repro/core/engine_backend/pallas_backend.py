@@ -214,19 +214,34 @@ def _grid_kernel(ts_ref, dts_ref, v_ref, pt0_ref, dt0_ref, pv0_ref,
     nchg_ref[...] = nchg
 
 
-@functools.partial(jax.jit, static_argnums=(18, 19, 20))
-def _grid_call(ts, dts, v, pt0, dt0, pv0, has0, rt, nchp, g, off, tsh, ja,
-               jac, wb, mh, el, eh, trapezoid: bool, m_real: int,
-               interpret: bool):
-    """``v`` [Dp, Mp] → per-tick outputs [Dp, Mp], per-device [Dp]."""
-    dp, mp = v.shape
-    rows = dp // _LANES
+def _grid_ticks(m: int) -> int:
+    """Padded tick count of an ``m``-tick slab."""
+    return _ceil_to(m, min(m, _GRID_TICKS))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _grid_call(fbuf, ibuf, trapezoid: bool, m_real: int, interpret: bool):
+    """The slab's operands packed by dtype → its results packed by dtype.
+
+    Every buffer is [rows of 128 device lanes], tick-major as the kernel
+    reads and writes it, so packing costs the device no transposes.
+    ``fbuf`` (float32) holds the readings [Mp, Dp], the 11 per-device
+    float operands [11, Dp], then ``ts`` and ``dts``, each padded to
+    whole rows; ``ibuf`` (int32) holds ``has0``, ``nchp``, ``ja`` and
+    ``jac`` [4, Dp].  Out come the ``ce``, ``cec`` and ``rd`` ticks
+    [3, Mp, Dp] and the 8 float sums [8, Dp] as one float32 buffer, and
+    the ``rr`` ticks [Mp, Dp] then ``no``, ``last`` and ``nchg`` [3, Dp]
+    as one int32 buffer."""
+    rows, mp = ibuf.shape[0] // 4, _grid_ticks(m_real)
+    vt = fbuf[:mp * rows].reshape(mp, rows, _LANES)
+    pt0, dt0, pv0, rt, g, off, tsh, wb, mh, el, eh = (
+        fbuf[mp * rows:(mp + 11) * rows].reshape(11, rows, _LANES))
+    ts, dts = fbuf[(mp + 11) * rows:].reshape(2, -1)[:, :mp]
+    has0, nchp, ja, jac = ibuf.reshape(4, rows, _LANES)
     tm = min(mp, _GRID_TICKS)
     bs = min(_GRID_SUBLANES, rows)
-    tile = lambda x: x.reshape(rows, _LANES)
-    vt = v.T.reshape(mp, rows, _LANES)
-    per_dev = [tile(x) for x in (pt0, dt0, pv0, has0, rt, nchp, g, off,
-                                 tsh, ja, jac, wb, mh, el, eh)]
+    per_dev = [pt0, dt0, pv0, has0, rt, nchp, g, off, tsh, ja, jac, wb, mh,
+               el, eh]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     tick = pl.BlockSpec((tm, bs, _LANES), lambda i, m: (m, i, 0))
     row = pl.BlockSpec((bs, _LANES), lambda i, m: (i, 0))
@@ -247,8 +262,8 @@ def _grid_call(ts, dts, v, pt0, dt0, pv0, has0, rt, nchp, g, off, tsh, ja,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ts, dts, vt, *per_dev)
-    per_tick = [o.reshape(mp, dp).T for o in outs[:4]]
-    return tuple(per_tick) + tuple(o.reshape(dp) for o in outs[4:])
+    pack = lambda xs: jnp.concatenate([x.reshape(-1, _LANES) for x in xs])
+    return pack(outs[:3] + outs[4:12]), pack(outs[3:4] + outs[12:])
 
 
 def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
@@ -265,47 +280,55 @@ def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
             offset, tshift, win_a, win_b, max_hold, env_lo, env_hi,
             trapezoid)
     anchor = ts[0]
-    rows = -(-d // _LANES)
-    dp = _ceil_to(d, _LANES * min(_GRID_SUBLANES, rows))
-    mp = _ceil_to(m, min(m, _GRID_TICKS))
+    dp = _ceil_to(d, _LANES * min(_GRID_SUBLANES, -(-d // _LANES)))
+    mp = _grid_ticks(m)
     prev_t = np.asarray(prev_t, dtype=np.float64)
     win_a = np.asarray(win_a, dtype=np.float64)
     tshift = np.asarray(tshift, dtype=np.float64)
-    dts = np.zeros(mp)
-    dts[1:m] = np.diff(ts)
     # window openings as tick columns, decided in float64: column j >= 1
     # starts its hold at ts[j-1], column 0 at the stored prev_t
     ja = 1 + _first_at_least(ts, win_a, 0.0)
     jac = 1 + _first_at_least(ts, win_a, tshift)
     ja = np.where(has_prev & (prev_t >= win_a), 0, ja)
     jac = np.where(has_prev & (prev_t - tshift >= win_a), 0, jac)
-    # neutral device padding: has=0 zeroes the increments, gain=1 keeps
-    # the division defined, the open envelope keeps the padding out of
-    # n_out (all of it is sliced off below)
-    with span("ingest.kernel.pad", samples=d * m, slots=dp * mp), _p.x32():
-        outs = _grid_call(
-            _k32(ts - anchor, mp, 0.0), _k32(dts, mp, 0.0),
-            _k32(np.pad(v, ((0, 0), (0, mp - m)), mode="edge"), dp, 0.0),
-            _k32(prev_t - anchor, dp, 0.0),
-            _k32(np.where(has_prev, ts[0] - prev_t, 0.0), dp, 0.0),
-            _k32(prev_v, dp, 0.0), _k32(has_prev, dp, 0, _p.KINT),
-            _k32(np.asarray(run_t) - anchor, dp, 0.0),
-            _k32(np.asarray(n_changes) >= 1, dp, 0, _p.KINT),
-            _k32(gain, dp, 1.0), _k32(offset, dp, 0.0),
-            _k32(tshift, dp, 0.0), _k32(ja, dp, mp, _p.KINT),
-            _k32(jac, dp, mp, _p.KINT),
-            _k32(np.asarray(win_b) - anchor, dp, -np.inf),
-            _k32(max_hold, dp, 0.0), _k32(env_lo, dp, -np.inf),
-            _k32(env_hi, dp, np.inf), bool(trapezoid), m, _interpret())
-    (ce, cec, rd, rr, de, dec, dw, dwc, sv, sv2, sa, mx, no, last,
-     nchg) = (np.asarray(o)[:d] for o in outs)
-    f64 = lambda x: x.astype(np.float64)
+    # the operands packed by dtype (layout: _grid_call).  Neutral device
+    # padding: has=0 zeroes the increments, gain=1 keeps the division
+    # defined, the open envelope keeps the padding out of n_out (all of
+    # it is sliced off below); padded ticks repeat the last reading
+    rows, tr = dp // _LANES, -(-mp // _LANES)
+    fbuf = np.empty(((mp + 11) * rows + 2 * tr, _LANES), _p.KFLOAT)
+    fv = fbuf[:mp * rows].reshape(mp, dp)
+    fv[:m, :d] = v.T
+    fv[m:, :d] = v[:, -1]
+    fv[:, d:] = 0.0
+    _jb.fill_rows(fbuf[mp * rows:(mp + 11) * rows].reshape(11, dp), [
+        (prev_t - anchor, 0.0),
+        (np.where(has_prev, ts[0] - prev_t, 0.0), 0.0), (prev_v, 0.0),
+        (np.asarray(run_t) - anchor, 0.0), (gain, 1.0), (offset, 0.0),
+        (tshift, 0.0), (np.asarray(win_b) - anchor, -np.inf),
+        (max_hold, 0.0), (env_lo, -np.inf), (env_hi, np.inf)])
+    _jb.fill_rows(fbuf[(mp + 11) * rows:].reshape(2, tr * _LANES),
+                  [(ts - anchor, 0.0), (np.r_[0.0, np.diff(ts)], 0.0)])
+    ibuf = _jb.fill_rows(np.empty((4, dp), _p.KINT), [
+        (has_prev, 0), (np.asarray(n_changes) >= 1, 0), (ja, mp),
+        (jac, mp)])
+    with span("ingest.kernel.pad", samples=d * m, slots=dp * mp, h2d=2,
+              d2h=2), _p.x32():
+        fo, io = jax.device_get(_grid_call(
+            *jax.device_put((fbuf, ibuf.reshape(4 * rows, _LANES))),
+            bool(trapezoid), m, _interpret()))
+    fo, io = fo.reshape(-1, dp), io.reshape(-1, dp)
+    # the ticks come back tick-major: one transposing copy each, on the
+    # host, into the [D, M] the callers read
+    ticks = lambda x, dt: np.ascontiguousarray(x[:m, :d].T, dtype=dt)
+    ce, cec, rd = (ticks(fo[i * mp:], np.float64) for i in range(3))
+    de, dec, dw, dwc, sv, sv2, sa, mx = fo[3 * mp:, :d].astype(np.float64)
+    no, last, nchg = io[mp:, :d]
     new_run_t = np.where(last >= 0, ts[np.maximum(last, 0)], run_t)
     new_n_changes = np.asarray(n_changes, dtype=np.int64) + nchg
-    return (v[:, -1].copy(), new_run_t, new_n_changes, f64(de), f64(dec),
-            f64(dw), f64(dwc), f64(sv), f64(sv2), f64(sa), f64(mx),
-            no.astype(np.int64), f64(ce[:, :m]), f64(cec[:, :m]),
-            f64(rd[:, :m]), rr[:, :m] != 0)
+    return (v[:, -1].copy(), new_run_t, new_n_changes, de, dec, dw, dwc, sv,
+            sv2, sa, mx, no.astype(np.int64), ce, cec, rd,
+            ticks(io, io.dtype) != 0)
 
 
 # -- stream_ingest: fused elementwise kernel, float64 fold on the device ---

@@ -73,12 +73,13 @@ def _kernel_in(text):
 def test_pallas_stream_ingest_grid_compiles(one_chip):
     s = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt,
                                                    sharding=one_chip)
-    r = s((D,))
-    ri = s((D,), I32)
+    # the packed operands in rows of 128 lanes: the readings, 11
+    # per-device rows and one row each of ts and dts in float32, 4
+    # per-device rows in int32
+    rows = D // 128
     with precision.x32():
-        text = _compile(pb._grid_call, s((M,)), s((M,)), s((D, M)), r, r,
-                        r, ri, r, ri, r, r, r, ri, ri, r, r, r, r, False, M,
-                        False)
+        text = _compile(pb._grid_call, s(((M + 11) * rows + 2, 128)),
+                        s((4 * rows, 128), I32), False, M, False)
     _kernel_in(text)
 
 
@@ -89,6 +90,19 @@ def test_pallas_stream_ingest_compiles(one_chip):
     with precision.x32():
         text = _compile(pb._flat_call, k, k, k, ki, k, ki, ki, k, k, k, k,
                         k, k, k, True, False)
+    _kernel_in(text)
+
+
+def test_pallas_stream_ingest_packed_slab_compiles(one_chip):
+    """The whole flat slab program: the packed float64 and int64 operands
+    unpacked, the float64 predecessors and fold around the kernel, the
+    results packed again."""
+    u = 16_384
+    s = lambda n, dt: jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+    with precision.x64():
+        text = _compile(jb._ingest_packed, pb._flat_impl,
+                        s(2 * K + 11 * u, jnp.float64),
+                        s(2 * K + 4 * u, jnp.int64), K, (False, False))
     _kernel_in(text)
 
 

@@ -93,7 +93,9 @@ def test_ingest_spans_nest_by_stage(tmp_path):
     # the pallas grid kernel computes over 128-lane device tiles
     pad = [e for e, p in ev if p == kern]
     assert [e[0] for e in pad] == ["ingest.kernel.pad"]
-    assert pad[0][3] == {"samples": dev.size * m, "slots": 128 * m}
+    # ... and moves the slab in one put and one fetch of two buffers each
+    assert pad[0][3] == {"samples": dev.size * m, "slots": 128 * m,
+                         "h2d": 2, "d2h": 2}
 
     # the fallback: an ``ingest`` nested in the grid root, its stages
     # inside it
@@ -111,7 +113,8 @@ def test_ingest_spans_nest_by_stage(tmp_path):
     k = ev[fk][0][3]["samples"]
     assert k < 310                      # duplicates dropped in prep
     assert [e[3] for e, p in ev if p == fk] == [{"samples": k,
-                                                 "slots": 1024}]
+                                                 "slots": 1024, "h2d": 2,
+                                                 "d2h": 2}]
 
 
 def test_span_is_a_trace_annotation_once_jax_is_imported():
