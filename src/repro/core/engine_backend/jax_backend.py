@@ -30,9 +30,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.common.trace import span
+from repro.core.engine_backend import numpy_backend as _nb
 from repro.core.engine_backend import precision as _p
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
-                                               TimelineArrays)
+                                               SlabOperands, TimelineArrays,
+                                               pad_operands)
 
 name = "jax"
 
@@ -300,47 +302,46 @@ def step_integrate(ts: np.ndarray, vals: np.ndarray, t0: np.ndarray,
 
 
 @jax.named_scope("ingest_prev")
-def ingest_prev(t, v, seg, first, prev_t, prev_v, has_prev):
+def ingest_prev(t, v, seg, first, ops: SlabOperands):
     """Each sample's predecessor ``(pt, pv, has)``: the previous sample
     within the slab, or the stored state at group starts."""
     shift_t = jnp.concatenate([jnp.zeros(1, t.dtype), t[:-1]])
     shift_v = jnp.concatenate([jnp.zeros(1, v.dtype), v[:-1]])
-    pt = jnp.where(first, prev_t[seg], shift_t)
-    pv = jnp.where(first, prev_v[seg], shift_v)
-    has = jnp.where(first, has_prev[seg], True)
+    pt = jnp.where(first, ops.prev_t[seg], shift_t)
+    pv = jnp.where(first, ops.prev_v[seg], shift_v)
+    has = jnp.where(first, ops.has_prev[seg], True)
     return pt, pv, has
 
 
-@functools.partial(jax.jit, static_argnums=(19,))
-def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx, prev_t,
-                        prev_v, has_prev, run_t, n_changes, gain, offset,
-                        tshift, win_a, win_b, max_hold, env_lo, env_hi,
-                        trapezoid: bool):
-    pt, pv, has = ingest_prev(t, v, seg, first, prev_t, prev_v, has_prev)
-    g = gain[seg]
-    off = offset[seg]
+@functools.partial(jax.jit, static_argnums=(7,))
+def _stream_ingest_impl(t, v, seg, first, start_idx, end_idx,
+                        ops: SlabOperands, trapezoid: bool):
+    pt, pv, has = ingest_prev(t, v, seg, first, ops)
+    g = ops.gain[seg]
+    off = ops.offset[seg]
     vc = (v - off) / g
     pvc = (pv - off) / g
     dt = t - pt
-    hold = jnp.minimum(dt, max_hold[seg])
+    hold = jnp.minimum(dt, ops.max_hold[seg])
     dens_r = 0.5 * (pv + v) if trapezoid else pv
     dens_c = 0.5 * (pvc + vc) if trapezoid else pvc
     inc = jnp.where(has, dens_r * hold, 0.0)
     inc_c = jnp.where(has, dens_c * hold, 0.0)
 
-    a = win_a[seg]
-    b = win_b[seg]
+    a = ops.win_a[seg]
+    b = ops.win_b[seg]
     w_inc = jnp.where(
         has & (pt >= a),
         dens_r * jnp.maximum(jnp.minimum(pt + hold, b) - pt, 0.0), 0.0)
-    pts = pt - tshift[seg]
+    pts = pt - ops.tshift[seg]
     w_inc_c = jnp.where(
         has & (pts >= a),
         dens_c * jnp.maximum(jnp.minimum(pts + hold, b) - pts, 0.0), 0.0)
     change = has & (v != pv)
-    out = (vc < env_lo[seg]) | (vc > env_hi[seg])
-    return ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes,
-                       inc, inc_c, w_inc, w_inc_c, vc, change, out)
+    out = (vc < ops.env_lo[seg]) | (vc > ops.env_hi[seg])
+    return ingest_fold(t, v, seg, start_idx, end_idx, ops.run_t,
+                       ops.n_changes, inc, inc_c, w_inc, w_inc_c, vc, change,
+                       out)
 
 
 def _shift(x, d: int, fill):
@@ -425,16 +426,12 @@ def ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes, inc,
             n_out_g.astype(_p.INT), sums[0], sums[1], vc, run_dur, run_rec)
 
 
-def stream_ingest(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
-                  has_prev, run_t, n_changes, gain, offset, tshift,
-                  win_a, win_b, max_hold, env_lo, env_hi,
+def stream_ingest(t, v, seg, first, start_idx, end_idx, ops: SlabOperands,
                   trapezoid: bool = False) -> Tuple:
     """Streaming-monitor ingest slab (see the numpy backend's reference
     docstring), fused into one jitted kernel."""
     return ingest_padded(_stream_ingest_impl, t, v, seg, first, start_idx,
-                         end_idx, prev_t, prev_v, has_prev, run_t,
-                         n_changes, gain, offset, tshift, win_a, win_b,
-                         max_hold, env_lo, env_hi, bool(trapezoid))
+                         end_idx, ops, bool(trapezoid))
 
 
 def fill_rows(out: np.ndarray, cols) -> np.ndarray:
@@ -449,30 +446,34 @@ def fill_rows(out: np.ndarray, cols) -> np.ndarray:
     return out
 
 
+# the operands the flat slab packs as float64 rows, in the record's order;
+# the other two travel in the int64 buffer
+_F64_OPS = tuple(f for f in SlabOperands._fields
+                 if f not in ("has_prev", "n_changes"))
+
+
 @functools.partial(jax.jit, static_argnums=(0, 3, 4))
 def _ingest_packed(impl, fbuf, ibuf, kp: int, static: tuple):
     """``impl`` on a slab packed by dtype, its outputs packed the same way.
 
-    ``fbuf`` (float64) holds ``t`` and ``v`` [Kp], then ``prev_t``,
-    ``prev_v``, ``run_t``, ``gain``, ``offset``, ``tshift``, ``win_a``,
-    ``win_b``, ``max_hold``, ``env_lo`` and ``env_hi`` [Up]; ``ibuf``
-    (int64) holds ``seg`` and ``first`` [Kp], then ``start_idx``,
-    ``end_idx``, ``has_prev`` and ``n_changes`` [Up].  Out come the eight
-    float per-group results [8, Up] and the four float per-sample ones
-    [4, Kp] as one float64 buffer, and the three integer per-group
-    results [3, Up] and ``run_rec`` [Kp] as one int64 buffer."""
-    up = (fbuf.shape[0] - 2 * kp) // 11
+    ``fbuf`` (float64) holds ``t`` and ``v`` [Kp], then the float
+    operands ``_F64_OPS`` [11, Up]; ``ibuf`` (int64) holds ``seg`` and
+    ``first`` [Kp], then ``start_idx``, ``end_idx``, ``has_prev`` and
+    ``n_changes`` [Up].  Out come the eight float per-group results
+    [8, Up] and the four float per-sample ones [4, Kp] as one float64
+    buffer, and the three integer per-group results [3, Up] and
+    ``run_rec`` [Kp] as one int64 buffer."""
+    up = (fbuf.shape[0] - 2 * kp) // len(_F64_OPS)
     t, v = fbuf[:2 * kp].reshape(2, kp)
-    (prev_t, prev_v, run_t, gain, offset, tshift, win_a, win_b, max_hold,
-     env_lo, env_hi) = fbuf[2 * kp:].reshape(11, up)
     seg, first = ibuf[:2 * kp].reshape(2, kp)
     start_idx, end_idx, has_prev, n_changes = ibuf[2 * kp:].reshape(4, up)
+    ops = SlabOperands(
+        has_prev=has_prev != 0, n_changes=n_changes,
+        **dict(zip(_F64_OPS, fbuf[2 * kp:].reshape(len(_F64_OPS), up))))
     (new_t, new_v, new_run_t, new_n_changes, counts, d_energy,
      d_energy_corr, d_win, d_win_corr, sum_vc, n_out, e_cum, e_cum_c, vc,
-     run_dur, run_rec) = impl(
-         t, v, seg, first != 0, start_idx, end_idx, prev_t, prev_v,
-         has_prev != 0, run_t, n_changes, gain, offset, tshift, win_a,
-         win_b, max_hold, env_lo, env_hi, *static)
+     run_dur, run_rec) = impl(t, v, seg, first != 0, start_idx, end_idx,
+                              ops, *static)
     return (jnp.concatenate([new_t, new_v, new_run_t, d_energy,
                              d_energy_corr, d_win, d_win_corr, sum_vc,
                              e_cum, e_cum_c, vc, run_dur]),
@@ -480,33 +481,32 @@ def _ingest_packed(impl, fbuf, ibuf, kp: int, static: tuple):
                              run_rec.astype(_p.INT)]))
 
 
-def ingest_padded(impl, t, v, seg, first, start_idx, end_idx, prev_t,
-                  prev_v, has_prev, run_t, n_changes, gain, offset, tshift,
-                  win_a, win_b, max_hold, env_lo, env_hi, *static) -> Tuple:
+def ingest_padded(impl, t, v, seg, first, start_idx, end_idx,
+                  ops: SlabOperands, *static) -> Tuple:
     """Run the jitted slab kernel ``impl`` under 64-bit mode on the slab
     padded to power-of-two ``(K, U)`` buckets (the tail samples form one
     extra, inert group), so slabs of varying size share a few
-    compilations; ``static`` follows the 19 array arguments.  The slab
-    goes to the device in one put of one float64 and one int64 buffer,
-    and its results come back in one fetch of two such buffers
+    compilations; ``static`` follows the operands.  The slab goes to the
+    device in one put of one float64 and one int64 buffer, and its
+    results come back in one fetch of two such buffers
     (:func:`_ingest_packed`)."""
     k, u = len(t), len(start_idx)
     kp, up = pad_bucket(k, 1024), pad_bucket(u + 1, 8)
     t = np.asarray(t, dtype=np.float64)
-    fbuf = np.empty(2 * kp + 11 * up)
+    ops = pad_operands(ops, up)
+    fbuf = np.empty(2 * kp + len(_F64_OPS) * up)
     fill_rows(fbuf[:2 * kp].reshape(2, kp),
               [(t, t[-1] if k else 0.0), (v, 0.0)])
-    fill_rows(fbuf[2 * kp:].reshape(11, up), [
-        (prev_t, 0.0), (prev_v, 0.0), (run_t, 0.0), (gain, 1.0),
-        (offset, 0.0), (tshift, 0.0), (win_a, np.inf), (win_b, -np.inf),
-        (max_hold, 0.0), (env_lo, -np.inf), (env_hi, np.inf)])
+    np.stack([getattr(ops, f) for f in _F64_OPS],
+             out=fbuf[2 * kp:].reshape(len(_F64_OPS), up))
     ibuf = np.empty(2 * kp + 4 * up, np.int64)
     per_sample = fill_rows(ibuf[:2 * kp].reshape(2, kp),
                            [(seg, u), (first, 0)])
     per_sample[1, k:k + 1] = 1      # the tail samples' group starts
-    fill_rows(ibuf[2 * kp:].reshape(4, up), [
-        (np.r_[start_idx, k], kp - 1), (end_idx, kp - 1), (has_prev, 0),
-        (n_changes, 0)])
+    per_group = ibuf[2 * kp:].reshape(4, up)
+    fill_rows(per_group[:2], [(np.r_[start_idx, k], kp - 1),
+                              (end_idx, kp - 1)])
+    per_group[2:] = ops.has_prev, ops.n_changes
     # the float64 put stays inside x64: outside it, jax would cast the
     # buffer to float32
     with span("ingest.kernel.pad", samples=k, slots=kp, h2d=2,
@@ -520,11 +520,10 @@ def ingest_padded(impl, t, v, seg, first, start_idx, end_idx, prev_t,
             gi[2], s[0], s[1], s[2], s[3], io[3 * up:3 * up + k] != 0)
 
 
-@functools.partial(jax.jit, static_argnums=(15,))
-def _stream_ingest_grid_impl(ts, v, prev_t, prev_v, has_prev, run_t,
-                             n_changes, gain, offset, tshift, win_a,
-                             win_b, max_hold, env_lo, env_hi,
-                             trapezoid: bool):
+@functools.partial(jax.jit, static_argnums=(3,))
+def _stream_ingest_grid_impl(ts, v, ops: SlabOperands, trapezoid: bool):
+    (prev_t, prev_v, has_prev, run_t, n_changes, gain, offset, tshift,
+     win_a, win_b, max_hold, env_lo, env_hi) = ops
     d, m = v.shape
     pt = jnp.concatenate(
         [prev_t[:, None],
@@ -590,41 +589,19 @@ def _stream_ingest_grid_impl(ts, v, prev_t, prev_v, has_prev, run_t,
             cum_e, cum_ec, run_dur, run_rec)
 
 
-def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
-                       gain, offset, tshift, win_a, win_b, max_hold,
-                       env_lo, env_hi, trapezoid: bool = False) -> Tuple:
+def stream_ingest_grid(ts, v, ops: SlabOperands,
+                       trapezoid: bool = False) -> Tuple:
     """Rectangular-slab streaming ingest (see the numpy backend's
     reference docstring) fused into one jitted kernel; compiled once per
     (D, M) slab shape, so a fixed-tick replay reuses one compilation."""
     ts = np.asarray(ts, dtype=np.float64)
     v = np.asarray(v, dtype=np.float64)
-    d, m = v.shape
-    if m == 0:      # empty slab: state passes through untouched
-        z = np.zeros((d, 0))
-        return (np.array(prev_v, dtype=np.float64),
-                np.array(run_t, dtype=np.float64),
-                np.array(n_changes, dtype=np.int64),
-                np.zeros(d), np.zeros(d), np.zeros(d), np.zeros(d),
-                np.zeros(d), np.zeros(d), np.zeros(d), np.zeros(d),
-                np.zeros(d, dtype=np.int64), z, z, z,
-                np.zeros((d, 0), dtype=bool))
+    if v.shape[1] == 0:     # empty slab: state passes through untouched
+        return _nb.stream_ingest_grid(ts, v, ops, trapezoid)
     with _p.x64():
         outs = _stream_ingest_grid_impl(
             jnp.asarray(ts, _p.FLOAT), jnp.asarray(v, _p.FLOAT),
-            jnp.asarray(prev_t, _p.FLOAT),
-            jnp.asarray(prev_v, _p.FLOAT),
-            jnp.asarray(has_prev, jnp.bool_),
-            jnp.asarray(run_t, _p.FLOAT),
-            jnp.asarray(n_changes, _p.INT),
-            jnp.asarray(gain, _p.FLOAT),
-            jnp.asarray(offset, _p.FLOAT),
-            jnp.asarray(tshift, _p.FLOAT),
-            jnp.asarray(win_a, _p.FLOAT),
-            jnp.asarray(win_b, _p.FLOAT),
-            jnp.asarray(max_hold, _p.FLOAT),
-            jnp.asarray(env_lo, _p.FLOAT),
-            jnp.asarray(env_hi, _p.FLOAT),
-            bool(trapezoid))
+            jax.tree.map(jnp.asarray, ops), bool(trapezoid))
     return tuple(np.asarray(o) for o in outs)
 
 

@@ -38,7 +38,7 @@ from typing import Tuple
 import numpy as np
 
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
-                                               TimelineArrays)
+                                               SlabOperands, TimelineArrays)
 
 name = "numpy"
 
@@ -351,13 +351,7 @@ def step_integrate(ts: np.ndarray, vals: np.ndarray, t0: np.ndarray,
 
 def stream_ingest(t: np.ndarray, v: np.ndarray, seg: np.ndarray,
                   first: np.ndarray, start_idx: np.ndarray,
-                  end_idx: np.ndarray, prev_t: np.ndarray,
-                  prev_v: np.ndarray, has_prev: np.ndarray,
-                  run_t: np.ndarray, n_changes: np.ndarray,
-                  gain: np.ndarray, offset: np.ndarray,
-                  tshift: np.ndarray, win_a: np.ndarray,
-                  win_b: np.ndarray, max_hold: np.ndarray,
-                  env_lo: np.ndarray, env_hi: np.ndarray,
+                  end_idx: np.ndarray, ops: SlabOperands,
                   trapezoid: bool = False) -> Tuple:
     """One slab of the streaming monitor's hot path.
 
@@ -366,16 +360,13 @@ def stream_ingest(t: np.ndarray, v: np.ndarray, seg: np.ndarray,
     id (0..U-1, contiguous and ascending), ``first`` [K] marks each
     group's first sample, ``start_idx``/``end_idx`` [U] are the group
     boundary positions (host-computed so the jax twin stays static-shape).
-    The remaining [U] vectors are the gathered per-device monitor state
-    (``prev_*``, ``has_prev``, ``run_t``, ``n_changes`` — ``run_t``
-    pre-initialised to the slab's first sample time for brand-new
-    devices) and correction parameters: ``gain``/``offset`` invert the
-    calibrated transform, ``tshift`` re-synchronises reported timestamps
-    (a reading at ``t`` covers ``[t - tshift, t]``), ``win_a``/``win_b``
-    bound each device's registered measurement window, ``max_hold`` caps
-    how long one reading may be extrapolated across a sampling gap
-    (``inf`` = plain rectangle), ``env_lo``/``env_hi`` the calibrated
-    plausibility envelope.
+    ``ops`` holds each group's device operands, every field [U]: its
+    gathered monitor state and correction parameters
+    (:class:`~repro.core.engine_backend.pytrees.SlabOperands`), with
+    ``run_t`` pre-initialised to the group's first sample time for a
+    device with no history.  A tier that pads the slab to a bucket fills
+    the padding groups with
+    :data:`~repro.core.engine_backend.pytrees.SLAB_PAD`.
 
     Returns, per group [U]: ``new_t, new_v, new_run_t, new_n_changes,
     counts, d_energy, d_energy_corr, d_win, d_win_corr, sum_vc, n_out``
@@ -385,6 +376,8 @@ def stream_ingest(t: np.ndarray, v: np.ndarray, seg: np.ndarray,
     *complete* run — bounded by a reading change on both sides — for the
     online update-period histogram).
     """
+    (prev_t, prev_v, has_prev, run_t, n_changes, gain, offset, tshift,
+     win_a, win_b, max_hold, env_lo, env_hi) = ops
     t = np.asarray(t, dtype=np.float64)
     v = np.asarray(v, dtype=np.float64)
     k = t.shape[0]
@@ -466,13 +459,7 @@ def stream_ingest(t: np.ndarray, v: np.ndarray, seg: np.ndarray,
             cum_e, cum_ec, vc, run_dur, run_rec)
 
 
-def stream_ingest_grid(ts: np.ndarray, v: np.ndarray, prev_t: np.ndarray,
-                       prev_v: np.ndarray, has_prev: np.ndarray,
-                       run_t: np.ndarray, n_changes: np.ndarray,
-                       gain: np.ndarray, offset: np.ndarray,
-                       tshift: np.ndarray, win_a: np.ndarray,
-                       win_b: np.ndarray, max_hold: np.ndarray,
-                       env_lo: np.ndarray, env_hi: np.ndarray,
+def stream_ingest_grid(ts: np.ndarray, v: np.ndarray, ops: SlabOperands,
                        trapezoid: bool = False) -> Tuple:
     """Rectangular fast path of :func:`stream_ingest`: ``D`` devices share
     one strictly-increasing time axis ``ts`` [M] with readings ``v``
@@ -484,9 +471,9 @@ def stream_ingest_grid(ts: np.ndarray, v: np.ndarray, prev_t: np.ndarray,
     with no sorting, no group compaction and no segmented reductions:
     every accumulator is a row-wise cumulative sum or reduction over the
     [D, M] block, so the per-sample cost is a handful of vector ops.
-    The per-device state/parameter vectors are all [D]; ``run_t`` must be
-    pre-initialised to ``ts[0]`` for devices without history, exactly as
-    the generic kernel's caller does.
+    ``ops``'s fields are all [D]; ``run_t`` must be pre-initialised to
+    ``ts[0]`` for devices without history, exactly as the generic
+    kernel's caller does.
 
     Returns, per device [D]: ``new_v, new_run_t, new_n_changes,
     d_energy, d_energy_corr, d_win, d_win_corr, sum_vc, sum_vc2,
@@ -496,6 +483,8 @@ def stream_ingest_grid(ts: np.ndarray, v: np.ndarray, prev_t: np.ndarray,
     reading moment sums replace the flattened ``vc`` vector, so label
     statistics merge from [D] reductions instead of [D·M] samples.)
     """
+    (prev_t, prev_v, has_prev, run_t, n_changes, gain, offset, tshift,
+     win_a, win_b, max_hold, env_lo, env_hi) = ops
     ts = np.asarray(ts, dtype=np.float64)
     v = np.asarray(v, dtype=np.float64)
     d, m = v.shape

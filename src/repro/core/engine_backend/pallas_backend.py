@@ -52,6 +52,7 @@ from repro.common.trace import span
 from repro.core.engine_backend import jax_backend as _jb
 from repro.core.engine_backend import numpy_backend as _nb
 from repro.core.engine_backend import precision as _p
+from repro.core.engine_backend.pytrees import SlabOperands, pad_operands
 
 name = "pallas"
 
@@ -266,52 +267,41 @@ def _grid_call(fbuf, ibuf, trapezoid: bool, m_real: int, interpret: bool):
     return pack(outs[:3] + outs[4:12]), pack(outs[3:4] + outs[12:])
 
 
-def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
-                       gain, offset, tshift, win_a, win_b, max_hold,
-                       env_lo, env_hi, trapezoid: bool = False) -> Tuple:
+def stream_ingest_grid(ts, v, ops: SlabOperands,
+                       trapezoid: bool = False) -> Tuple:
     """Rectangular-slab streaming ingest (see the numpy backend's
     reference docstring) as one Pallas kernel over device-lane tiles."""
     ts = np.asarray(ts, dtype=np.float64)
     v = np.asarray(v, dtype=np.float64)
     d, m = v.shape
     if m == 0 or d == 0:
-        return _nb.stream_ingest_grid(
-            ts, v, prev_t, prev_v, has_prev, run_t, n_changes, gain,
-            offset, tshift, win_a, win_b, max_hold, env_lo, env_hi,
-            trapezoid)
+        return _nb.stream_ingest_grid(ts, v, ops, trapezoid)
     anchor = ts[0]
     dp = _ceil_to(d, _LANES * min(_GRID_SUBLANES, -(-d // _LANES)))
     mp = _grid_ticks(m)
-    prev_t = np.asarray(prev_t, dtype=np.float64)
-    win_a = np.asarray(win_a, dtype=np.float64)
-    tshift = np.asarray(tshift, dtype=np.float64)
+    # padding lanes hold SLAB_PAD and zero readings, padded ticks repeat
+    # the last reading; all of it is sliced off below
+    o = pad_operands(ops, dp)
     # window openings as tick columns, decided in float64: column j >= 1
     # starts its hold at ts[j-1], column 0 at the stored prev_t
-    ja = 1 + _first_at_least(ts, win_a, 0.0)
-    jac = 1 + _first_at_least(ts, win_a, tshift)
-    ja = np.where(has_prev & (prev_t >= win_a), 0, ja)
-    jac = np.where(has_prev & (prev_t - tshift >= win_a), 0, jac)
-    # the operands packed by dtype (layout: _grid_call).  Neutral device
-    # padding: has=0 zeroes the increments, gain=1 keeps the division
-    # defined, the open envelope keeps the padding out of n_out (all of
-    # it is sliced off below); padded ticks repeat the last reading
+    ja = 1 + _first_at_least(ts, o.win_a, 0.0)
+    jac = 1 + _first_at_least(ts, o.win_a, o.tshift)
+    ja = np.where(o.has_prev & (o.prev_t >= o.win_a), 0, ja)
+    jac = np.where(o.has_prev & (o.prev_t - o.tshift >= o.win_a), 0, jac)
+    # the operands packed by dtype (layout: _grid_call)
     rows, tr = dp // _LANES, -(-mp // _LANES)
     fbuf = np.empty(((mp + 11) * rows + 2 * tr, _LANES), _p.KFLOAT)
     fv = fbuf[:mp * rows].reshape(mp, dp)
     fv[:m, :d] = v.T
     fv[m:, :d] = v[:, -1]
     fv[:, d:] = 0.0
-    _jb.fill_rows(fbuf[mp * rows:(mp + 11) * rows].reshape(11, dp), [
-        (prev_t - anchor, 0.0),
-        (np.where(has_prev, ts[0] - prev_t, 0.0), 0.0), (prev_v, 0.0),
-        (np.asarray(run_t) - anchor, 0.0), (gain, 1.0), (offset, 0.0),
-        (tshift, 0.0), (np.asarray(win_b) - anchor, -np.inf),
-        (max_hold, 0.0), (env_lo, -np.inf), (env_hi, np.inf)])
+    np.stack([o.prev_t - anchor, np.where(o.has_prev, ts[0] - o.prev_t, 0.0),
+              o.prev_v, o.run_t - anchor, o.gain, o.offset, o.tshift,
+              o.win_b - anchor, o.max_hold, o.env_lo, o.env_hi],
+             out=fbuf[mp * rows:(mp + 11) * rows].reshape(11, dp))
     _jb.fill_rows(fbuf[(mp + 11) * rows:].reshape(2, tr * _LANES),
                   [(ts - anchor, 0.0), (np.r_[0.0, np.diff(ts)], 0.0)])
-    ibuf = _jb.fill_rows(np.empty((4, dp), _p.KINT), [
-        (has_prev, 0), (np.asarray(n_changes) >= 1, 0), (ja, mp),
-        (jac, mp)])
+    ibuf = np.array([o.has_prev, o.n_changes >= 1, ja, jac], _p.KINT)
     with span("ingest.kernel.pad", samples=d * m, slots=dp * mp, h2d=2,
               d2h=2), _p.x32():
         fo, io = jax.device_get(_grid_call(
@@ -324,8 +314,8 @@ def stream_ingest_grid(ts, v, prev_t, prev_v, has_prev, run_t, n_changes,
     ce, cec, rd = (ticks(fo[i * mp:], np.float64) for i in range(3))
     de, dec, dw, dwc, sv, sv2, sa, mx = fo[3 * mp:, :d].astype(np.float64)
     no, last, nchg = io[mp:, :d]
-    new_run_t = np.where(last >= 0, ts[np.maximum(last, 0)], run_t)
-    new_n_changes = np.asarray(n_changes, dtype=np.int64) + nchg
+    new_run_t = np.where(last >= 0, ts[np.maximum(last, 0)], ops.run_t)
+    new_n_changes = np.asarray(ops.n_changes, dtype=np.int64) + nchg
     return (v[:, -1].copy(), new_run_t, new_n_changes, de, dec, dw, dwc, sv,
             sv2, sa, mx, no.astype(np.int64), ce, cec, rd,
             ticks(io, io.dtype) != 0)
@@ -389,45 +379,39 @@ def _flat_call(v, pv, dt, has, pt, win, winc, g, off, tsh, wb, mh, el, eh,
     return tuple(o.reshape(kp) for o in outs)
 
 
-@functools.partial(jax.jit, static_argnums=(19, 20))
-def _flat_impl(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
-               has_prev, run_t, n_changes, gain, offset, tshift, win_a,
-               win_b, max_hold, env_lo, env_hi, trapezoid: bool,
-               interpret: bool):
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _flat_impl(t, v, seg, first, start_idx, end_idx, ops: SlabOperands,
+               trapezoid: bool, interpret: bool):
     """The slab in float64 on the device: predecessors, gaps and window
     openings before the cast, the Pallas kernel on slab-relative 32-bit
     values, then the jax tier's group fold of the widened increments."""
-    pt, pv, has = _jb.ingest_prev(t, v, seg, first, prev_t, prev_v,
-                                  has_prev)
+    pt, pv, has = _jb.ingest_prev(t, v, seg, first, ops)
     anchor = t[0]
-    a = win_a[seg]
+    a = ops.win_a[seg]
     f32 = lambda x: x.astype(_p.KFLOAT)
     i32 = lambda x: x.astype(_p.KINT)
     args = (f32(v), f32(pv), f32(jnp.where(has, t - pt, 0.0)), i32(has),
             f32(pt - anchor), i32(has & (pt >= a)),
-            i32(has & (pt - tshift[seg] >= a)), f32(gain[seg]),
-            f32(offset[seg]), f32(tshift[seg]), f32(win_b[seg] - anchor),
-            f32(max_hold[seg]), f32(env_lo[seg]), f32(env_hi[seg]))
+            i32(has & (pt - ops.tshift[seg] >= a)), f32(ops.gain[seg]),
+            f32(ops.offset[seg]), f32(ops.tshift[seg]),
+            f32(ops.win_b[seg] - anchor), f32(ops.max_hold[seg]),
+            f32(ops.env_lo[seg]), f32(ops.env_hi[seg]))
     with _p.x32():
         outs = _flat_call(*args, trapezoid, interpret)
     inc, inc_c, w_inc, w_inc_c, vc = (o.astype(_p.FLOAT) for o in outs[:5])
     change, out = (o != 0 for o in outs[5:])
-    return _jb.ingest_fold(t, v, seg, start_idx, end_idx, run_t, n_changes,
-                           inc, inc_c, w_inc, w_inc_c, vc, change, out)
+    return _jb.ingest_fold(t, v, seg, start_idx, end_idx, ops.run_t,
+                           ops.n_changes, inc, inc_c, w_inc, w_inc_c, vc,
+                           change, out)
 
 
-def stream_ingest(t, v, seg, first, start_idx, end_idx, prev_t, prev_v,
-                  has_prev, run_t, n_changes, gain, offset, tshift,
-                  win_a, win_b, max_hold, env_lo, env_hi,
+def stream_ingest(t, v, seg, first, start_idx, end_idx, ops: SlabOperands,
                   trapezoid: bool = False) -> Tuple:
     """Streaming-monitor ingest slab (see the numpy backend's reference
     docstring): the per-sample arithmetic as one Pallas kernel, the
     group fold in float64, both on the device."""
     return _jb.ingest_padded(_flat_impl, t, v, seg, first, start_idx,
-                             end_idx, prev_t, prev_v, has_prev, run_t,
-                             n_changes, gain, offset, tshift, win_a, win_b,
-                             max_hold, env_lo, env_hi, bool(trapezoid),
-                             _interpret())
+                             end_idx, ops, bool(trapezoid), _interpret())
 
 
 # -- step_integrate: row-blocked masked sums --------------------------------

@@ -5,6 +5,9 @@
 arrays, which makes it a JAX pytree for free: it can be passed straight
 into ``jax.jit``-compiled kernels (leaves trace as ``jnp`` arrays) while
 staying a zero-cost tuple of ``np.ndarray`` views on the NumPy path.
+:class:`SlabOperands` is the streaming-ingest kernels' per-device operand
+record in the same form; :data:`SLAB_PAD` is the one table of the values
+its padding rows take.
 
 The container carries no behaviour on purpose: backends implement the
 kernels as pure functions over these arrays, so the same signature works
@@ -80,3 +83,51 @@ class PollGrid(NamedTuple):
     t1: np.ndarray
     period_s: float
     grid_offset: "float | np.ndarray" = 0.0
+
+
+class SlabOperands(NamedTuple):
+    """The per-device operands of one streaming-ingest slab, each ``[U]``:
+    one row per device the slab holds.
+
+    The device's stored state: ``prev_t``/``prev_v`` its newest sample,
+    ``has_prev`` whether it has one, ``run_t`` the start of its current
+    reading run (the slab's first sample time for a device with no
+    history), ``n_changes`` the reading changes seen so far.  Its
+    configuration: ``gain``/``offset`` invert the calibrated transform,
+    ``tshift`` re-synchronises reported timestamps (a reading at ``t``
+    covers ``[t - tshift, t]``), ``win_a``/``win_b`` bound its registered
+    measurement window, ``max_hold`` caps how long one reading may be
+    extrapolated across a sampling gap (``inf`` = plain rectangle),
+    ``env_lo``/``env_hi`` are its calibrated plausibility envelope.
+    """
+
+    prev_t: np.ndarray
+    prev_v: np.ndarray
+    has_prev: np.ndarray
+    run_t: np.ndarray
+    n_changes: np.ndarray
+    gain: np.ndarray
+    offset: np.ndarray
+    tshift: np.ndarray
+    win_a: np.ndarray
+    win_b: np.ndarray
+    max_hold: np.ndarray
+    env_lo: np.ndarray
+    env_hi: np.ndarray
+
+
+#: The value each operand takes in a padding row: no stored sample, no
+#: hold, a unit gain (the correction stays finite), a window that never
+#: opens and an open envelope, so a padding row folds to zeros.
+SLAB_PAD = SlabOperands(prev_t=0.0, prev_v=0.0, has_prev=False, run_t=0.0,
+                        n_changes=0, gain=1.0, offset=0.0, tshift=0.0,
+                        win_a=np.inf, win_b=-np.inf, max_hold=0.0,
+                        env_lo=-np.inf, env_hi=np.inf)
+
+
+def pad_operands(ops: SlabOperands, n: int) -> SlabOperands:
+    """``ops`` extended to ``n`` rows with :data:`SLAB_PAD`, each field
+    keeping its dtype."""
+    return SlabOperands(*(
+        np.concatenate([x, np.full(n - len(x), pad, np.asarray(x).dtype)])
+        for x, pad in zip(ops, SLAB_PAD)))

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.common.trace import span
 from repro.core.engine_backend import get_backend, resolve_backend
+from repro.core.engine_backend.pytrees import SlabOperands
 from repro.core.fleet_engine import StreamingMoments
 from repro.core.stream.estimators import (OnlinePeriodEstimator,
                                           StreamCorrections)
@@ -292,13 +293,8 @@ class IngestCore:
             if not (dev.shape == t.shape == v.shape):
                 raise ValueError(f"shape mismatch: dev {dev.shape}, "
                                  f"t {t.shape}, v {v.shape}")
-            n_rej = 0
-            if dev.size and (dev.min() < 0 or dev.max() >= self.n_devices):
-                if self.strict_ids:
-                    raise ValueError("device id out of range")
-                ok_id = (dev >= 0) & (dev < self.n_devices)
-                n_rej = int(ok_id.size - ok_id.sum())
-                self._n_rejected += n_rej
+            ok_id, n_rej = self._known_ids(dev, 1)
+            if ok_id is not None:
                 dev, t, v = dev[ok_id], t[ok_id], v[ok_id]
             k_in = dev.size
             if k_in == 0:
@@ -347,24 +343,13 @@ class IngestCore:
 
         with span("ingest.gather"):
             v = v - self.corrections.baseline_w[dev]
-            had = st.has[u_dev]
-            c = self.corrections
-            run_t_in = np.where(had, st.run_t[u_dev], t[start_idx])
-            last_t, last_v = st.last_t[u_dev], st.last_v[u_dev]
-            n_chg = st.n_changes[u_dev]
-            gain, off, tsh = (c.gain[u_dev], c.offset_w[u_dev],
-                              c.time_shift_s[u_dev])
-            win_a, win_b = self._win_a[u_dev], self._win_b[u_dev]
-            hold = self._max_hold[u_dev]
-            env_lo, env_hi = self._env_lo[u_dev], self._env_hi[u_dev]
+            ops = self._operands(u_dev, t[start_idx])
 
         with span("ingest.kernel", samples=k, devices=len(u_dev)):
             (new_t, new_v, new_run_t, new_nchg, counts, d_e, d_ec, d_w,
              d_wc, sum_vc, n_out, cum_e, cum_ec, vc, run_dur, run_rec) = \
-                self._be.stream_ingest(
-                    t, v, seg, first, start_idx, end_idx, last_t, last_v,
-                    had, run_t_in, n_chg, gain, off, tsh, win_a, win_b,
-                    hold, env_lo, env_hi, self.trapezoid)
+                self._be.stream_ingest(t, v, seg, first, start_idx, end_idx,
+                                       ops, self.trapezoid)
 
         # ring snapshots see running totals *before* this slab is folded
         with span("ingest.ring"):
@@ -378,60 +363,15 @@ class IngestCore:
                 self.ring.n_written[u_dev] += counts
 
         if self.history is not None:
-            self._history_flat(u_dev, had, t, v, start_idx, end_idx,
-                               cum_e, cum_ec)
-
-        with span("ingest.scatter"):
-            old_last_t = st.last_t[u_dev]
-            st.first_t[u_dev] = np.where(had, st.first_t[u_dev],
-                                         t[start_idx])
-            st.last_t[u_dev] = new_t
-            st.last_v[u_dev] = new_v
-            st.has[u_dev] = True
-            st.n_samples[u_dev] += counts
-            st.energy_j[u_dev] += d_e
-            st.energy_corr_j[u_dev] += d_ec
-            st.win_j[u_dev] += d_w
-            st.win_corr_j[u_dev] += d_wc
-            st.run_t[u_dev] = new_run_t
-            st.n_changes[u_dev] = new_nchg
-            st.n_out[u_dev] += n_out
-
-            # drift EWMA over wall time, one slab-mean step per device
-            mean_vc = sum_vc / counts
-            alpha = np.exp(-np.maximum(new_t - old_last_t, 0.0)
-                           / self.drift_tau_s)
-            st.ewma_w[u_dev] = np.where(
-                had, alpha * st.ewma_w[u_dev] + (1.0 - alpha) * mean_vc,
-                mean_vc)
-
-        with span("ingest.periods"):
-            rec = np.asarray(run_rec, dtype=bool)
-            if np.any(rec):
-                self.periods.record(dev[rec], np.asarray(run_dur)[rec])
-
-        # per-label corrected-reading moments (Chan–Welford): one
-        # bincount pass over the slab, O(K + labels) — no per-label
-        # masks, so per-device labels stay cheap at fleet scale
-        with span("ingest.moments"):
-            codes = self._label_codes[dev]
-            nl = len(self._label_names)
-            cnt = np.bincount(codes, minlength=nl)
-            s1 = np.bincount(codes, weights=vc, minlength=nl)
-            s2 = np.bincount(codes, weights=vc * vc, minlength=nl)
-            av = np.abs(vc)
-            sa = np.bincount(codes, weights=av, minlength=nl)
-            mx = np.zeros(nl)
-            np.maximum.at(mx, codes, av)
-            for ci in np.flatnonzero(cnt):
-                nb = int(cnt[ci])
-                mean = s1[ci] / nb
-                m2 = max(float(s2[ci] - nb * mean * mean), 0.0)
-                self._moments.setdefault(
-                    self._label_names[ci], StreamingMoments()).merge(
-                        nb, float(mean), m2, float(sa[ci] / nb),
-                        float(mx[ci]))
-
+            self._history_flat(u_dev, ops.has_prev, t, v, start_idx,
+                               end_idx, cum_e, cum_ec)
+        self._write_back(u_dev, ops, t[start_idx], new_t, counts, new_v,
+                         new_run_t, new_nchg, d_e, d_ec, d_w, d_wc, sum_vc,
+                         n_out)
+        self._record_periods(dev, run_dur, run_rec)
+        # label sums from the slab's corrected readings, O(K + labels)
+        av = np.abs(vc)
+        self._merge_moments(dev, 1, vc, vc * vc, av, av)
         self._maybe_update_health(float(np.max(new_t)))
         return IngestReport(k, n_dup, n_late, n_invalid, len(u_dev), n_rej)
 
@@ -464,13 +404,8 @@ class IngestCore:
                                  f"got {vals.shape}")
             if d == 0 or m == 0:
                 return IngestReport(0, 0, 0, 0, 0)
-            n_rej = 0
-            if dev.min() < 0 or dev.max() >= self.n_devices:
-                if self.strict_ids:
-                    raise ValueError("device id out of range")
-                ok_id = (dev >= 0) & (dev < self.n_devices)
-                n_rej = int(ok_id.size - ok_id.sum()) * m
-                self._n_rejected += n_rej
+            ok_id, n_rej = self._known_ids(dev, m)
+            if ok_id is not None:
                 dev, vals = dev[ok_id], vals[ok_id]
                 d = dev.size
                 if d == 0:
@@ -490,24 +425,14 @@ class IngestCore:
         self.epoch += 1
 
         with span("ingest.gather"):
-            c = self.corrections
-            v = vals - c.baseline_w[dev][:, None]
-            had = st.has[dev]
-            run_t_in = np.where(had, st.run_t[dev], ts[0])
-            last_t, last_v = st.last_t[dev], st.last_v[dev]
-            n_chg = st.n_changes[dev]
-            gain, off, tsh = c.gain[dev], c.offset_w[dev], c.time_shift_s[dev]
-            win_a, win_b = self._win_a[dev], self._win_b[dev]
-            hold = self._max_hold[dev]
-            env_lo, env_hi = self._env_lo[dev], self._env_hi[dev]
+            v = vals - self.corrections.baseline_w[dev][:, None]
+            ops = self._operands(dev, ts[0])
 
         with span("ingest.kernel", samples=d * m, devices=d):
             (new_v, new_run_t, new_nchg, d_e, d_ec, d_w, d_wc,
              sum_vc, sum_vc2, sum_abs_vc, max_abs_vc, n_out,
              cum_e, cum_ec, run_dur, run_rec) = \
-                self._be.stream_ingest_grid(
-                    ts, v, last_t, last_v, had, run_t_in, n_chg, gain, off,
-                    tsh, win_a, win_b, hold, env_lo, env_hi, self.trapezoid)
+                self._be.stream_ingest_grid(ts, v, ops, self.trapezoid)
 
         # ring snapshots see running totals *before* this slab is folded
         with span("ingest.ring"):
@@ -519,47 +444,100 @@ class IngestCore:
                 self.ring.n_written[dev] += m
 
         if self.history is not None:
-            self._history_grid(dev, had, ts, v, cum_e, cum_ec)
+            self._history_grid(dev, ops.has_prev, ts, v, cum_e, cum_ec)
+        self._write_back(dev, ops, ts[0], ts[-1], m, new_v, new_run_t,
+                         new_nchg, d_e, d_ec, d_w, d_wc, sum_vc, n_out)
+        self._record_periods(dev[:, None], run_dur, run_rec)
+        # label sums from the kernel's per-device sums, O(D + labels)
+        self._merge_moments(dev, m, sum_vc, sum_vc2, sum_abs_vc, max_abs_vc)
+        self._maybe_update_health(float(ts[-1]))
+        return IngestReport(d * m, 0, 0, 0, d, n_rej)
 
+    # -- the slab fold both entry points share -----------------------------
+    def _known_ids(self, dev, per_id: int):
+        """``(mask, rejected)``: the mask of ``dev``'s in-range ids, or
+        None when all are, and the samples rejected with the others,
+        ``per_id`` each, added to the counter.  Out-of-range ids raise
+        instead under ``strict_ids``."""
+        if not dev.size or (dev.min() >= 0 and dev.max() < self.n_devices):
+            return None, 0
+        if self.strict_ids:
+            raise ValueError("device id out of range")
+        ok_id = (dev >= 0) & (dev < self.n_devices)
+        n_rej = int(ok_id.size - ok_id.sum()) * per_id
+        self._n_rejected += n_rej
+        return ok_id, n_rej
+
+    def _operands(self, idx, t0) -> SlabOperands:
+        """The kernel's operands for devices ``idx``: their stored state
+        and configuration; a device with no stored sample starts its run
+        at ``t0``, its first sample in the slab."""
+        st, c = self.state, self.corrections
+        had = st.has[idx]
+        return SlabOperands(
+            prev_t=st.last_t[idx], prev_v=st.last_v[idx], has_prev=had,
+            run_t=np.where(had, st.run_t[idx], t0),
+            n_changes=st.n_changes[idx], gain=c.gain[idx],
+            offset=c.offset_w[idx], tshift=c.time_shift_s[idx],
+            win_a=self._win_a[idx], win_b=self._win_b[idx],
+            max_hold=self._max_hold[idx], env_lo=self._env_lo[idx],
+            env_hi=self._env_hi[idx])
+
+    def _write_back(self, idx, ops, t0, t_end, counts, new_v, new_run_t,
+                    new_nchg, d_e, d_ec, d_w, d_wc, sum_vc, n_out) -> None:
+        """Fold the kernel's per-device results into the state of devices
+        ``idx``, whose operands were ``ops``.  ``t0``/``t_end`` are each
+        device's first and last sample time in the slab and ``counts``
+        its sample count: [U] on the flat path, scalars that every device
+        shares on the grid path."""
+        st, had = self.state, ops.has_prev
         with span("ingest.scatter"):
-            old_last_t = st.last_t[dev]
-            st.first_t[dev] = np.where(had, st.first_t[dev], ts[0])
-            st.last_t[dev] = ts[-1]
-            st.last_v[dev] = new_v
-            st.has[dev] = True
-            st.n_samples[dev] += m
-            st.energy_j[dev] += d_e
-            st.energy_corr_j[dev] += d_ec
-            st.win_j[dev] += d_w
-            st.win_corr_j[dev] += d_wc
-            st.run_t[dev] = new_run_t
-            st.n_changes[dev] = new_nchg
-            st.n_out[dev] += n_out
+            st.first_t[idx] = np.where(had, st.first_t[idx], t0)
+            st.last_t[idx] = t_end
+            st.last_v[idx] = new_v
+            st.has[idx] = True
+            st.n_samples[idx] += counts
+            st.energy_j[idx] += d_e
+            st.energy_corr_j[idx] += d_ec
+            st.win_j[idx] += d_w
+            st.win_corr_j[idx] += d_wc
+            st.run_t[idx] = new_run_t
+            st.n_changes[idx] = new_nchg
+            st.n_out[idx] += n_out
 
-            mean_vc = sum_vc / m
-            alpha = np.exp(-np.maximum(ts[-1] - old_last_t, 0.0)
+            # drift EWMA over wall time, one slab-mean step per device
+            mean_vc = sum_vc / counts
+            alpha = np.exp(-np.maximum(t_end - ops.prev_t, 0.0)
                            / self.drift_tau_s)
-            st.ewma_w[dev] = np.where(
-                had, alpha * st.ewma_w[dev] + (1.0 - alpha) * mean_vc,
+            st.ewma_w[idx] = np.where(
+                had, alpha * st.ewma_w[idx] + (1.0 - alpha) * mean_vc,
                 mean_vc)
 
+    def _record_periods(self, dev, run_dur, run_rec) -> None:
+        """Record the slab's complete reading runs; ``dev`` broadcasts to
+        the kernel's per-sample shape."""
         with span("ingest.periods"):
             rec = np.asarray(run_rec, dtype=bool)
             if np.any(rec):
-                dgrid = np.broadcast_to(dev[:, None], rec.shape)
-                self.periods.record(dgrid[rec], np.asarray(run_dur)[rec])
+                self.periods.record(np.broadcast_to(dev, rec.shape)[rec],
+                                    np.asarray(run_dur)[rec])
 
-        # per-label moments straight from the kernel's per-device
-        # reductions — O(D + labels) instead of O(D·M)
+    def _merge_moments(self, dev, n_each, s1, s2, sa, mx) -> None:
+        """Merge one slab's corrected-reading moments into the per-label
+        accumulators (Chan–Welford).  Entry ``i`` covers ``n_each`` readings
+        of device ``dev[i]``: their sum ``s1``, sum of squares ``s2``, sum
+        of magnitudes ``sa`` and largest magnitude ``mx``.  One bincount
+        pass per sum, O(entries + labels): no per-label masks, so
+        per-device labels stay cheap at fleet scale."""
         with span("ingest.moments"):
             codes = self._label_codes[dev]
             nl = len(self._label_names)
-            cnt = m * np.bincount(codes, minlength=nl)
-            s1 = np.bincount(codes, weights=sum_vc, minlength=nl)
-            s2 = np.bincount(codes, weights=sum_vc2, minlength=nl)
-            sa = np.bincount(codes, weights=sum_abs_vc, minlength=nl)
-            mx = np.zeros(nl)
-            np.maximum.at(mx, codes, max_abs_vc)
+            cnt = n_each * np.bincount(codes, minlength=nl)
+            s1 = np.bincount(codes, weights=s1, minlength=nl)
+            s2 = np.bincount(codes, weights=s2, minlength=nl)
+            sa = np.bincount(codes, weights=sa, minlength=nl)
+            mxl = np.zeros(nl)
+            np.maximum.at(mxl, codes, mx)
             for ci in np.flatnonzero(cnt):
                 nb = int(cnt[ci])
                 mean = s1[ci] / nb
@@ -567,10 +545,7 @@ class IngestCore:
                 self._moments.setdefault(
                     self._label_names[ci], StreamingMoments()).merge(
                         nb, float(mean), m2, float(sa[ci] / nb),
-                        float(mx[ci]))
-
-        self._maybe_update_health(float(ts[-1]))
-        return IngestReport(d * m, 0, 0, 0, d, n_rej)
+                        float(mxl[ci]))
 
     # -- history tier ------------------------------------------------------
     # Boundaries are written before the slab's state is scattered: a

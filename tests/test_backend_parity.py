@@ -28,6 +28,7 @@ from _tol import assert_tier_close
 from repro.core import load as loads
 from repro.core.engine_backend import available_backends, get_backend
 from repro.core.engine_backend import numpy_backend as nb
+from repro.core.engine_backend.pytrees import SlabOperands
 from repro.core.ground_truth import TimelineBank
 from repro.core.stream import MonitorService
 
@@ -97,11 +98,8 @@ def _valid_ingest_slab(rng, k, u, *, single_sample=False):
 
 def _ingest_args(slab, trapezoid):
     t, v, seg, first, start_idx, end_idx, s = slab
-    return (t, v, seg, first, start_idx, end_idx,
-            s["prev_t"], s["prev_v"], s["has_prev"], s["run_t"],
-            s["n_changes"], s["gain"], s["offset"], s["tshift"],
-            s["win_a"], s["win_b"], s["max_hold"], s["env_lo"],
-            s["env_hi"], trapezoid)
+    return (t, v, seg, first, start_idx, end_idx, SlabOperands(**s),
+            trapezoid)
 
 
 def _assert_tuples_close(outn, outj, label, backend):
@@ -293,12 +291,13 @@ def test_property_stream_ingest_grid_parity(seed, d, m, trapezoid):
     win_a = rng.uniform(1.5, 3.0, d)
     win_b = np.where(rng.random(d) < 0.2, win_a,      # zero-length windows
                      win_a + rng.uniform(0.0, 2.0, d))
-    args = (ts, v, prev_t, rng.uniform(60.0, 250.0, d), has_prev,
-            np.where(has_prev, prev_t, ts[0]), rng.integers(0, 4, d),
-            rng.uniform(0.95, 1.05, d), rng.uniform(-3.0, 3.0, d),
-            rng.uniform(0.0, 0.05, d), win_a, win_b,
-            np.where(rng.random(d) < 0.5, np.inf, 0.05),
-            np.full(d, 0.0), np.full(d, 240.0), trapezoid)
+    args = (ts, v, SlabOperands(
+        prev_t, rng.uniform(60.0, 250.0, d), has_prev,
+        np.where(has_prev, prev_t, ts[0]), rng.integers(0, 4, d),
+        rng.uniform(0.95, 1.05, d), rng.uniform(-3.0, 3.0, d),
+        rng.uniform(0.0, 0.05, d), win_a, win_b,
+        np.where(rng.random(d) < 0.5, np.inf, 0.05),
+        np.full(d, 0.0), np.full(d, 240.0)), trapezoid)
     outn = nb.stream_ingest_grid(*args)
     for be in _accel_backends():
         outj = get_backend(be).stream_ingest_grid(*args)

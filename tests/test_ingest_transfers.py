@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core.engine_backend import get_backend, has_jax
+from repro.core.engine_backend.pytrees import SlabOperands
 
 pytestmark = pytest.mark.skipif(not has_jax(), reason="jax not installed")
 
@@ -25,12 +26,13 @@ def _grid_args(rng, d=37, m=7):
     v = np.round(rng.uniform(60.0, 250.0, (d, m)) / 25.0) * 25.0
     has_prev = rng.random(d) > 0.3
     prev_t = rng.uniform(1.0, 2.0, d)
-    return (ts, v, prev_t, rng.uniform(60.0, 250.0, d), has_prev,
-            np.where(has_prev, prev_t, ts[0]), rng.integers(0, 4, d),
-            rng.uniform(0.95, 1.05, d), rng.uniform(-3.0, 3.0, d),
-            np.full(d, 0.025), np.full(d, 2.2), np.full(d, 2.6),
-            np.where(rng.random(d) < 0.5, np.inf, 0.05), np.full(d, 0.0),
-            np.full(d, 240.0), True)
+    return (ts, v, SlabOperands(
+        prev_t, rng.uniform(60.0, 250.0, d), has_prev,
+        np.where(has_prev, prev_t, ts[0]), rng.integers(0, 4, d),
+        rng.uniform(0.95, 1.05, d), rng.uniform(-3.0, 3.0, d),
+        np.full(d, 0.025), np.full(d, 2.2), np.full(d, 2.6),
+        np.where(rng.random(d) < 0.5, np.inf, 0.05), np.full(d, 0.0),
+        np.full(d, 240.0)), True)
 
 
 def _flat_args(rng, k=300, u=11):
@@ -46,13 +48,13 @@ def _flat_args(rng, k=300, u=11):
     end_idx = np.r_[start_idx[1:] - 1, k - 1]
     has_prev = rng.random(u) > 0.3
     prev_t = rng.uniform(-1.0, 0.0, u)
-    return (t, v, seg, first, start_idx, end_idx, prev_t,
-            rng.uniform(60.0, 250.0, u), has_prev,
-            np.where(has_prev, prev_t, t[start_idx]), rng.integers(0, 4, u),
-            rng.uniform(0.95, 1.05, u), rng.uniform(-3.0, 3.0, u),
-            np.full(u, 0.025), np.full(u, 1.0), np.full(u, 4.0),
-            np.where(rng.random(u) < 0.5, np.inf, 0.5), np.full(u, 0.0),
-            np.full(u, 240.0), False)
+    return (t, v, seg, first, start_idx, end_idx, SlabOperands(
+        prev_t, rng.uniform(60.0, 250.0, u), has_prev,
+        np.where(has_prev, prev_t, t[start_idx]), rng.integers(0, 4, u),
+        rng.uniform(0.95, 1.05, u), rng.uniform(-3.0, 3.0, u),
+        np.full(u, 0.025), np.full(u, 1.0), np.full(u, 4.0),
+        np.where(rng.random(u) < 0.5, np.inf, 0.5), np.full(u, 0.0),
+        np.full(u, 240.0)), False)
 
 
 def _count_calls(monkeypatch, jax):

@@ -16,6 +16,7 @@ from _tol import assert_tier_close
 from repro.core import load as loads
 from repro.core.engine_backend import get_backend, has_jax
 from repro.core.engine_backend import numpy_backend as nb
+from repro.core.engine_backend.pytrees import SlabOperands, pad_operands
 from repro.core.stream import MonitorService, replay, stream_fleet
 from repro.core.fleet_engine import SensorBank
 from repro.core.meter import Workload
@@ -66,11 +67,8 @@ def test_stream_ingest_kernel_parity(accel_backend, trapezoid):
     rng = np.random.default_rng(42)
     for trial in range(3):
         t, v, seg, first, start_idx, end_idx, st = _random_slab(rng)
-        args = (t, v, seg, first, start_idx, end_idx,
-                st["prev_t"], st["prev_v"], st["has_prev"], st["run_t"],
-                st["n_changes"], st["gain"], st["offset"], st["tshift"],
-                st["win_a"], st["win_b"], st["max_hold"], st["env_lo"],
-                st["env_hi"], trapezoid)
+        args = (t, v, seg, first, start_idx, end_idx, SlabOperands(**st),
+                trapezoid)
         outn = nb.stream_ingest(*args)
         outj = jb.stream_ingest(*args)
         assert len(outn) == len(outj)
@@ -99,12 +97,12 @@ def _group_slab(rng, sizes, *, change=True, first_change=False,
         v = level[seg]
     prev_v = level + 25.0 if first_change else level
     prev_t = rng.uniform(-1.0, -0.1, u)
-    st = (prev_t, prev_v, np.ones(u, bool), prev_t - 0.3,
-          rng.integers(0, 3, u), rng.uniform(0.95, 1.05, u),
-          rng.uniform(-3.0, 3.0, u), np.full(u, 0.025),
-          np.full(u, window[0]), np.full(u, window[1]),
-          np.full(u, np.inf), np.zeros(u), np.full(u, 240.0))
-    return (t, v, seg, first, start_idx, end_idx) + st
+    ops = SlabOperands(prev_t, prev_v, np.ones(u, bool), prev_t - 0.3,
+                       rng.integers(0, 3, u), rng.uniform(0.95, 1.05, u),
+                       rng.uniform(-3.0, 3.0, u), np.full(u, 0.025),
+                       np.full(u, window[0]), np.full(u, window[1]),
+                       np.full(u, np.inf), np.zeros(u), np.full(u, 240.0))
+    return (t, v, seg, first, start_idx, end_idx, ops)
 
 
 _FOLD_CASES = {
@@ -132,14 +130,14 @@ def _reference_by_group(args):
     carried from one group into the next (the reference's whole-slab
     prefix difference for ``cum_e``/``cum_ec`` rounds at the slab's
     total: 1.1e-12 relative on the large case)."""
-    t, v, seg, first, start_idx, end_idx = args[:6]
+    t, v, seg, first, start_idx, end_idx, ops, trapezoid = args
     outs = []
     for g, (s, e) in enumerate(zip(start_idx, end_idx)):
         n = e - s + 1
         outs.append(nb.stream_ingest(
             t[s:e + 1], v[s:e + 1], np.zeros(n, int), first[s:e + 1],
             np.array([0]), np.array([n - 1]),
-            *(x[g:g + 1] for x in args[6:19]), args[19]))
+            SlabOperands(*(x[g:g + 1] for x in ops)), trapezoid))
     return [np.concatenate(o) for o in zip(*outs)]
 
 
@@ -161,7 +159,8 @@ def test_stream_ingest_fold_edge_groups(accel_backend, case):
     for i in (3, 4, 10, 15):    # new_n_changes, counts, n_out, run_rec
         np.testing.assert_array_equal(outj[i], outn[i],
                                       err_msg=f"output {i}")
-    t, start_idx, run_t, n_changes = args[0], args[4], args[9], args[10]
+    t, start_idx, ops = args[0], args[4], args[6]
+    run_t, n_changes = ops.run_t, ops.n_changes
     if case == "zero_window":
         assert np.all(outj[7] == 0.0) and np.all(outj[8] == 0.0)
     if case == "no_change_carries_run_t":
@@ -193,6 +192,55 @@ def test_ingest_fold_lowers_without_scatter():
             f(k), f(k), f(k), f(k), f(k), b, b).as_text()
     assert "stablehlo.gather" in text
     assert "scatter" not in text
+
+
+def _padded_call(kernel, rng, n_pad):
+    """One slab through the numpy reference kernel, as given and with
+    ``n_pad`` padding rows appended the way the padding tiers append
+    them: ``SLAB_PAD`` operands, zero readings, and on the flat path one
+    sample per padding group at the slab's last time."""
+    if kernel == "stream_ingest":
+        t, v, seg, first, start_idx, end_idx, st = _random_slab(rng)
+        ops, u, k = SlabOperands(**st), len(start_idx), len(t)
+        tail = np.arange(n_pad)
+        return ops, nb.stream_ingest(
+            t, v, seg, first, start_idx, end_idx, ops), nb.stream_ingest(
+            np.r_[t, np.full(n_pad, t[-1])], np.r_[v, np.zeros(n_pad)],
+            np.r_[seg, u + tail], np.r_[first, np.ones(n_pad, bool)],
+            np.r_[start_idx, k + tail], np.r_[end_idx, k + tail],
+            pad_operands(ops, u + n_pad))
+    d, m = 9, 12
+    ts = 2.0 + np.cumsum(rng.uniform(0.01, 0.1, m))
+    v = np.round(rng.uniform(60.0, 250.0, (d, m)) / 25.0) * 25.0
+    has_prev = rng.random(d) > 0.3
+    prev_t = rng.uniform(1.0, 2.0, d)
+    ops = SlabOperands(
+        prev_t, rng.uniform(60.0, 250.0, d), has_prev,
+        np.where(has_prev, prev_t, ts[0]), rng.integers(0, 4, d),
+        rng.uniform(0.95, 1.05, d), rng.uniform(-3.0, 3.0, d),
+        np.full(d, 0.025), np.full(d, 2.2), np.full(d, 2.6),
+        np.full(d, np.inf), np.full(d, 70.0), np.full(d, 240.0))
+    return ops, nb.stream_ingest_grid(ts, v, ops), nb.stream_ingest_grid(
+        ts, np.r_[v, np.zeros((n_pad, m))], pad_operands(ops, d + n_pad))
+
+
+@pytest.mark.parametrize("kernel", ["stream_ingest", "stream_ingest_grid"])
+def test_padding_rows_fold_to_zeros(kernel):
+    """Rows padded with ``SLAB_PAD`` fold to nothing in the reference:
+    no energy, window energy, change or out-of-envelope sample, and
+    finite values throughout; the real rows' results stay bitwise those
+    of the unpadded slab, and padding keeps each operand's dtype."""
+    n_pad = 3
+    ops, want, got = _padded_call(kernel, np.random.default_rng(9), n_pad)
+    assert [x.dtype for x in pad_operands(ops, 40)] == [x.dtype for x in ops]
+    # energies, window energies, change counts, n_out and run_rec
+    zero = ((3, 5, 6, 7, 8, 10, 15) if kernel == "stream_ingest"
+            else (2, 3, 4, 5, 6, 11, 15))
+    for i, (a, b) in enumerate(zip(want, got)):
+        assert np.all(np.isfinite(b)), i
+        np.testing.assert_array_equal(b[:len(a)], a, err_msg=f"output {i}")
+        if i in zero:
+            assert not np.any(b[len(a):]), i
 
 
 @pytest.mark.parametrize("trapezoid", [False, True])
@@ -269,24 +317,25 @@ def test_stream_ingest_grid_kernel_parity(accel_backend, trapezoid):
         v[rep] = np.round(v[rep] / 25.0) * 25.0
         has_prev = rng.random(d) > 0.3
         prev_t = rng.uniform(0.0, 2.0, d)
-        args = (ts, v, prev_t, rng.uniform(60.0, 250.0, d), has_prev,
-                np.where(has_prev, prev_t, ts[0]),
-                rng.integers(0, 4, d), rng.uniform(0.95, 1.05, d),
-                rng.uniform(-3.0, 3.0, d), np.full(d, 0.025),
-                np.full(d, 2.2), np.full(d, 3.4),
-                np.where(rng.random(d) < 0.5, np.inf, 0.05),
-                np.full(d, 0.0), np.full(d, 240.0), trapezoid)
+        args = (ts, v, SlabOperands(
+            prev_t, rng.uniform(60.0, 250.0, d), has_prev,
+            np.where(has_prev, prev_t, ts[0]),
+            rng.integers(0, 4, d), rng.uniform(0.95, 1.05, d),
+            rng.uniform(-3.0, 3.0, d), np.full(d, 0.025),
+            np.full(d, 2.2), np.full(d, 3.4),
+            np.where(rng.random(d) < 0.5, np.inf, 0.05),
+            np.full(d, 0.0), np.full(d, 240.0)), trapezoid)
         outn = nb.stream_ingest_grid(*args)
         outj = jb.stream_ingest_grid(*args)
         assert len(outn) == len(outj) == 16
         for i, (a, b) in enumerate(zip(outn, outj)):
             assert_tier_close(b, a, accel_backend, 1e-12, 1e-12,
                               err_msg=f"output {i} (trial {trial})")
-    empty = (np.zeros(0), np.zeros((3, 0)), np.zeros(3), np.ones(3),
-             np.ones(3, dtype=bool), np.zeros(3),
-             np.zeros(3, dtype=np.int64), np.ones(3), np.zeros(3),
-             np.zeros(3), np.zeros(3), np.ones(3), np.full(3, np.inf),
-             np.zeros(3), np.full(3, 240.0), trapezoid)
+    empty = (np.zeros(0), np.zeros((3, 0)), SlabOperands(
+        np.zeros(3), np.ones(3), np.ones(3, dtype=bool), np.zeros(3),
+        np.zeros(3, dtype=np.int64), np.ones(3), np.zeros(3), np.zeros(3),
+        np.zeros(3), np.ones(3), np.full(3, np.inf), np.zeros(3),
+        np.full(3, 240.0)), trapezoid)
     for a, b in zip(nb.stream_ingest_grid(*empty),
                     jb.stream_ingest_grid(*empty)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -26,7 +26,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
 from repro.core.engine_backend import jax_backend as jb  # noqa: E402
 from repro.core.engine_backend import pallas_backend as pb  # noqa: E402
 from repro.core.engine_backend import precision  # noqa: E402
-from repro.core.engine_backend.pytrees import TimelineArrays  # noqa: E402
+from repro.core.engine_backend.pytrees import (  # noqa: E402
+    SlabOperands, TimelineArrays)
 
 D, M = 102_400, 20          # fleet × poll ticks per slab (padded to 128s)
 K = 131_072                 # flat slab: samples (a power-of-two bucket)
@@ -128,10 +129,11 @@ def test_jax_stream_ingest_grid_compiles(one_chip):
     s = lambda shape, dt=jnp.float64: jax.ShapeDtypeStruct(
         shape, dt, sharding=one_chip)
     r = s((D,))
+    ops = SlabOperands(r, r, s((D,), jnp.bool_), r, s((D,), jnp.int64), r,
+                       r, r, r, r, r, r, r)
     with precision.x64():
-        _compile(jb._stream_ingest_grid_impl, s((M,)), s((D, M)), r, r,
-                 s((D,), jnp.bool_), r, s((D,), jnp.int64), r, r, r, r, r,
-                 r, r, r, False)
+        _compile(jb._stream_ingest_grid_impl, s((M,)), s((D, M)), ops,
+                 False)
 
 
 def test_jax_snapshot_energy_at_compiles(one_chip):
