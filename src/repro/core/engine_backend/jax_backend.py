@@ -33,8 +33,9 @@ from repro.common.trace import span
 from repro.core.engine_backend import numpy_backend as _nb
 from repro.core.engine_backend import precision as _p
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
-                                               SlabOperands, TimelineArrays,
-                                               pad_operands)
+                                               SlabOperands, TierOperands,
+                                               TimelineArrays, pad_operands,
+                                               unpack_tier_operands)
 
 name = "jax"
 
@@ -723,80 +724,191 @@ def history_write(e_raw, e_corr, rows: np.ndarray, cols: np.ndarray,
                   jnp.asarray(pad(v_corr, 0.0, np.float64), _p.FLOAT))
 
 
-def history_operands(*arrays) -> tuple:
-    """The per-device operands of the tier's kernels, placed on the
-    device once (a snapshot reuses them for every query)."""
+_history_unpack = jax.jit(unpack_tier_operands)
+
+
+def history_operands(f64: np.ndarray, i32: np.ndarray) -> tuple:
+    """The corrected and the raw :class:`~repro.core.engine_backend.\
+pytrees.TierOperands` of a snapshot's packed operand buffers, placed on
+    the device with one explicit put of the two buffers and unpacked
+    there (a snapshot reuses them for every query)."""
     with _p.x64():
-        return tuple(jnp.asarray(a) for a in arrays)
+        return _history_unpack(*jax.device_put((f64, i32)))
 
 
-def _history_rows(tier, rows, bq, tq, lo, hi, last_t, first_t, has,
-                  max_hold, dens, base):
+def _history_rows(tier, rows, tq, ops: TierOperands):
     tqc = tq[:, None]
-    dt = tqc - last_t[None, :]
-    hold = jnp.minimum(dt, max_hold[None, :])
-    live = has[None, :] & (dt >= 0.0)
-    e_live = jnp.where(live, base[None, :] + dens[None, :] * hold, 0.0)
-    covered = live | ~has[None, :] | (tqc <= first_t[None, :])
-    started = has[None, :] & (tqc > first_t[None, :])
+    dt = tqc - ops.last_t[None, :]
+    hold = jnp.minimum(dt, ops.max_hold[None, :])
+    live = ops.has[None, :] & (dt >= 0.0)
+    e_live = jnp.where(live, ops.base[None, :] + ops.dens[None, :] * hold,
+                       0.0)
+    covered = live | ~ops.has[None, :] | (tqc <= ops.first_t[None, :])
+    started = ops.has[None, :] & (tqc > ops.first_t[None, :])
     e = jnp.where(started, e_live, 0.0)
-    b = bq[:, None]
-    held = (started & (tqc < last_t[None, :]) & (b >= lo[None, :])
-            & (b <= hi[None, :]))
+    held = (started & (tqc < ops.last_t[None, :])
+            & (tqc >= ops.lo_t[None, :]) & (tqc <= ops.hi_t[None, :]))
     e = jnp.where(held, tier[rows], e)
     covered = covered | held
     return jnp.where(covered, e, jnp.nan), covered
 
 
-@jax.jit
-def _history_energy_at_impl(tier, rows, bq, tq, *ops):
+def _count(m):
+    return jnp.sum(m.astype(jnp.int32), axis=-1)
+
+
+def _energy_at(tier, rows, tq, ops: TierOperands):
     with jax.named_scope("history_energy_at"):
-        return _history_rows(tier, rows, bq, tq, *ops)
+        return _history_rows(tier, rows, tq, ops)
 
 
-def history_energy_at(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
-                      tq: np.ndarray):
-    """``(e, covered)`` [Q, N] at boundary instants (see the numpy
-    backend), as one jitted kernel; instants padded to a power of two."""
-    q = tq.shape[0]
-    qp = pad_bucket(q, 8)
-    pad = lambda x: np.concatenate([x, np.repeat(x[-1:], qp - q)])  # noqa
-    with _p.x64():
-        e, covered = _history_energy_at_impl(
-            tier, jnp.asarray(pad(rows).astype(np.int32)),
-            jnp.asarray(pad(bq).astype(np.int32)),
-            jnp.asarray(pad(np.asarray(tq, np.float64)), _p.FLOAT),
-            *ops[:8])
-        e, covered = jax.device_get((e, covered))
-    return e[:q], covered[:q]
-
-
-@jax.jit
-def _history_series_impl(tier, rows, bq, tq, step_s, lo, hi, last_t,
-                         first_t, has, max_hold, dens, base, active, tol):
+def _series(tier, rows, tq, step_s, ops: TierOperands):
     with jax.named_scope("history_series"):
-        e, cov = _history_rows(tier, rows, bq, tq, lo, hi, last_t, first_t,
-                               has, max_hold, dens, base)
-        inc = cov & active[None, :]
+        e, cov = _history_rows(tier, rows, tq, ops)
+        inc = cov & ops.active[None, :]
         e0 = jnp.where(inc, e, 0.0)
-        sig = tol[None, :] * jnp.abs(e0)
+        sig = ops.tol[None, :] * jnp.abs(e0)
         both = inc[1:] & inc[:-1]
         power = jnp.sum(jnp.where(both, e[1:] - e[:-1], 0.0),
                         axis=1) / step_s
-        count = lambda m: jnp.sum(m.astype(jnp.int32), axis=1)  # noqa
-        return (jnp.sum(e0, axis=1), count(cov), count(inc),
+        return (jnp.sum(e0, axis=1), _count(cov), _count(inc),
                 jnp.sum(sig * sig, axis=1), jnp.sum(sig, axis=1), power,
-                count(both))
+                _count(both))
 
 
-def history_series(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+def _by_label(tier, rows, tq, n_labels: int, ops: TierOperands):
+    # masked sums over a static [L, N]: no scatter, no segment sum
+    with jax.named_scope("history_by_label"):
+        e, cov = _history_rows(tier, rows, tq, ops)
+        covered = cov[0] & cov[1] & ops.has
+        de = jnp.where(covered, e[1] - e[0], 0.0)[None, :]
+        sel = (ops.codes[None, :] == jnp.arange(n_labels)[:, None]) \
+            & covered[None, :]
+        inc = sel & ops.active[None, :]
+        n = _count(inc)
+        total = jnp.sum(jnp.where(inc, de, 0.0), axis=1)
+        mean = jnp.where(n > 0, total / jnp.maximum(n, 1), jnp.nan)
+        m2 = jnp.sum(jnp.where(inc, (de - mean[:, None]) ** 2, 0.0),
+                     axis=1)
+        return (n, _count(sel & ~ops.active[None, :]), total, mean,
+                jnp.where(n > 0, m2, jnp.nan), _count(cov))
+
+
+def _flush_results(tiers, ops, qbuf, layout: tuple) -> list:
+    """Every tier call of a flush.  ``layout`` holds per call ``(kind,
+    tier index, Q, static)``: its instants ``tq`` [Q], slots [Q] and one
+    trailing number (a series' step) lie in turn in the float64 ``qbuf``;
+    ``static`` is a by-label's label count."""
+    out, at = [], 0
+    for kind, k, q, static in layout:
+        tq, rows = qbuf[at:at + q], qbuf[at + q:at + 2 * q]
+        rows, arg = rows.astype(jnp.int32), qbuf[at + 2 * q]
+        at += 2 * q + 1
+        if kind == "energy_at":
+            out.append(_energy_at(tiers[k], rows, tq, ops[k]))
+        elif kind == "series":
+            out.append(_series(tiers[k], rows, tq, arg, ops[k]))
+        else:
+            out.append(_by_label(tiers[k], rows, tq, static, ops[k]))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _history_flush(tiers, ops, qbuf, layout: tuple):
+    """:func:`_flush_results` as one program, its results in one float64
+    buffer (counts and flags are exact in it).  A tier that several
+    calls read is one operand, split once."""
+    out = _flush_results(tiers, ops, qbuf, layout)
+    with jax.named_scope("history_fetch"):
+        return jnp.concatenate([jnp.ravel(x).astype(_p.FLOAT)
+                                for x in jax.tree.leaves(out)])
+
+
+@functools.lru_cache(maxsize=256)
+def _result_shapes(layout: tuple, slots: int, n: int) -> list:
+    """Per call of ``layout``, each result's ``(shape, dtype)``."""
+    f64 = jax.ShapeDtypeStruct((n,), _p.FLOAT)
+    flag = jax.ShapeDtypeStruct((n,), jnp.bool_)
+    ops = TierOperands(lo_t=f64, hi_t=f64, last_t=f64, first_t=f64,
+                       has=flag, max_hold=f64, dens=f64, base=f64,
+                       active=flag, tol=f64,
+                       codes=jax.ShapeDtypeStruct((n,), jnp.int32))
+    k = 1 + max(c[1] for c in layout)
+    tiers = (jax.ShapeDtypeStruct((slots, n), _p.FLOAT),) * k
+    qbuf = jax.ShapeDtypeStruct((sum(2 * c[2] + 1 for c in layout),),
+                                _p.FLOAT)
+    with _p.x64():
+        out = jax.eval_shape(lambda t, o, b: _flush_results(t, o, b, layout),
+                             tiers, (ops,) * k, qbuf)
+    return [[(x.shape, np.dtype(x.dtype)) for x in c] for c in out]
+
+
+#: Host↔device buffers moved by each step of a query: placing a
+#: snapshot's operands, one batch of tier calls, one ring energy-at call.
+TRANSFERS = {"tier_operands": (2, 0), "tier_batch": (1, 1),
+             "ring": (10, 2)}
+
+
+def history_batch(calls: list) -> list:
+    """The results of tier kernel calls (see the numpy backend), as one
+    program with one upload and one fetch: every call's instants, slots
+    and trailing argument go up in one float64 buffer, and every result
+    comes back in one float64 buffer, cast back on the host by the
+    results' shapes and dtypes.  Energy-at instants are padded to a
+    power of two."""
+    tiers, ops, index = [], [], {}
+    layout, parts = [], []
+    for kind, tier, op, rows, tq, args in calls:
+        if id(tier) not in index:
+            index[id(tier)] = len(tiers)
+            tiers.append(tier)
+            ops.append(op)
+        q = len(tq)
+        qp = pad_bucket(q, 8) if kind == "energy_at" else q
+        pad = lambda x: np.concatenate(  # noqa: E731
+            [x, np.repeat(x[-1:], qp - q)])
+        parts += [pad(np.asarray(tq, np.float64)),
+                  pad(np.asarray(rows, np.float64)),
+                  [float(args[0]) if kind == "series" else 0.0]]
+        layout.append((kind, index[id(tier)], qp,
+                       int(args[0]) if kind == "by_label" else 0))
+    layout = tuple(layout)
+    with _p.x64():
+        flat = jax.device_get(_history_flush(
+            tuple(tiers), tuple(ops), jax.device_put(np.concatenate(parts)),
+            layout))
+    res, at = [], 0
+    shapes = _result_shapes(layout, *tiers[0].shape)
+    for (kind, _, _, _, tq, _), out in zip(calls, shapes):
+        host = []
+        for shape, dtype in out:
+            size = int(np.prod(shape))
+            host.append(flat[at:at + size].reshape(shape).astype(dtype))
+            at += size
+        if kind == "energy_at":
+            host = [h[:len(tq)] for h in host]
+        res.append(tuple(host))
+    return res
+
+
+def history_energy_at(tier, ops: TierOperands, rows: np.ndarray,
+                      tq: np.ndarray):
+    """``(e, covered)`` [Q, N] at boundary instants (see the numpy
+    backend), as one jitted kernel."""
+    return history_batch([("energy_at", tier, ops, rows, tq, ())])[0]
+
+
+def history_series(tier, ops: TierOperands, rows: np.ndarray,
                    tq: np.ndarray, step_s: float):
     """The fleet reductions over the tier's rows (see the numpy
     backend), done on the device: only [Q] numbers come back."""
-    with _p.x64():
-        out = _history_series_impl(
-            tier, jnp.asarray(rows.astype(np.int32)),
-            jnp.asarray(bq.astype(np.int32)),
-            jnp.asarray(np.asarray(tq, np.float64), _p.FLOAT),
-            jnp.asarray(float(step_s), _p.FLOAT), *ops)
-        return tuple(jax.device_get(out))
+    return history_batch([("series", tier, ops, rows, tq, (step_s,))])[0]
+
+
+def history_by_label(tier, ops: TierOperands, rows: np.ndarray,
+                     tq: np.ndarray, n_labels: int):
+    """The by-label reductions between two boundary instants (see the
+    numpy backend), done on the device over a static number of labels:
+    only [L] numbers come back."""
+    return history_batch([("by_label", tier, ops, rows, tq,
+                           (n_labels,))])[0]

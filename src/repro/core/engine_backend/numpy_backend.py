@@ -38,7 +38,9 @@ from typing import Tuple
 import numpy as np
 
 from repro.core.engine_backend.pytrees import (PollGrid, ReadingSchedule,
-                                               SlabOperands, TimelineArrays)
+                                               SlabOperands, TierOperands,
+                                               TimelineArrays,
+                                               unpack_tier_operands)
 
 name = "numpy"
 
@@ -646,49 +648,47 @@ def history_write(e_raw, e_corr, rows: np.ndarray, cols: np.ndarray,
     return e_raw, e_corr
 
 
-def history_operands(*arrays) -> tuple:
-    """The per-device operands of :func:`history_energy_at` and
-    :func:`history_series` as this backend reads them (host arrays)."""
-    return arrays
+def history_operands(f64: np.ndarray, i32: np.ndarray) -> tuple:
+    """The corrected and the raw :class:`~repro.core.engine_backend.\
+pytrees.TierOperands` of a snapshot's packed operand buffers
+    (``TIER_F64`` rows of float64, ``TIER_I32`` rows of int32), as this
+    backend reads them: host arrays."""
+    return unpack_tier_operands(f64, i32)
 
 
-def _history_rows(tier, rows, bq, tq, lo, hi, last_t, first_t, has,
-                  max_hold, dens, base):
+def _history_rows(tier, rows, tq, ops: TierOperands):
     """Energy since first sample at ``Q`` boundary instants for all
     ``N`` devices: :func:`snapshot_energy_at`'s rule with the tier in
     place of the ring.  ``tier`` [S, N]; ``rows`` [Q] the slot of each
-    instant, ``bq`` [Q] its boundary index; ``lo``/``hi`` [N] the
-    boundaries each device's slots hold (indices relative to one
-    reference boundary).  A device's instant between its first and
-    newest sample is answered from its slot where the slot holds that
-    boundary, and is not covered otherwise."""
+    instant, ``tq`` [Q] the instants (``b · step_s``); ``ops.lo_t``/
+    ``ops.hi_t`` [N] the times of the boundaries each device's slots
+    hold.  A device's instant between its first and newest sample is
+    answered from its slot where the slot holds that boundary, and is
+    not covered otherwise."""
     tq = tq[:, None]
-    dt = tq - last_t[None, :]
-    hold = np.minimum(dt, max_hold[None, :])
-    live = has[None, :] & (dt >= 0.0)
-    e_live = np.where(live, base[None, :] + dens[None, :] * hold, 0.0)
-    covered = live | ~has[None, :] | (tq <= first_t[None, :])
-    started = has[None, :] & (tq > first_t[None, :])
+    dt = tq - ops.last_t[None, :]
+    hold = np.minimum(dt, ops.max_hold[None, :])
+    live = ops.has[None, :] & (dt >= 0.0)
+    e_live = np.where(live, ops.base[None, :] + ops.dens[None, :] * hold,
+                      0.0)
+    covered = live | ~ops.has[None, :] | (tq <= ops.first_t[None, :])
+    started = ops.has[None, :] & (tq > ops.first_t[None, :])
     e = np.where(started, e_live, 0.0)
-    b = bq[:, None]
-    held = (started & (tq < last_t[None, :]) & (b >= lo[None, :])
-            & (b <= hi[None, :]))
+    held = (started & (tq < ops.last_t[None, :])
+            & (tq >= ops.lo_t[None, :]) & (tq <= ops.hi_t[None, :]))
     e = np.where(held, tier[rows], e)
     covered = covered | held
     return np.where(covered, e, np.nan), covered
 
 
-def history_energy_at(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+def history_energy_at(tier, ops: TierOperands, rows: np.ndarray,
                       tq: np.ndarray):
     """``(e, covered)`` [Q, N] at boundary instants ``tq`` (see
-    :func:`_history_rows`; ``ops`` from :func:`history_operands`:
-    ``lo, hi, last_t, first_t, has, max_hold, dens, base, active,
-    tol``)."""
-    return _history_rows(tier, rows, bq, np.asarray(tq, np.float64),
-                         *ops[:8])
+    :func:`_history_rows`)."""
+    return _history_rows(tier, rows, np.asarray(tq, np.float64), ops)
 
 
-def history_series(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
+def history_series(tier, ops: TierOperands, rows: np.ndarray,
                    tq: np.ndarray, step_s: float):
     """The fleet reductions of :func:`history_energy_at`'s rows, per
     instant [Q]: the energy of the included devices (covered and
@@ -696,13 +696,55 @@ def history_series(tier, ops: tuple, rows: np.ndarray, bq: np.ndarray,
     and of plain per-device sigmas (``tol · |e|``); and per step
     [Q - 1]: the energy change of devices included at both ends over
     ``step_s`` (their power), and how many they are."""
-    e, cov = history_energy_at(tier, ops, rows, bq, tq)
-    active, tol = ops[8], ops[9]
-    inc = cov & active[None, :]
+    e, cov = history_energy_at(tier, ops, rows, tq)
+    inc = cov & ops.active[None, :]
     e0 = np.where(inc, e, 0.0)
-    sig = tol[None, :] * np.abs(e0)
+    sig = ops.tol[None, :] * np.abs(e0)
     both = inc[1:] & inc[:-1]
     power = np.sum(np.where(both, e[1:] - e[:-1], 0.0), axis=1) / step_s
     return (np.sum(e0, axis=1), np.sum(cov, axis=1), np.sum(inc, axis=1),
             np.sum(sig * sig, axis=1), np.sum(sig, axis=1), power,
             np.sum(both, axis=1))
+
+
+def history_by_label(tier, ops: TierOperands, rows: np.ndarray,
+                     tq: np.ndarray, n_labels: int):
+    """The by-label reductions of the energy between two boundary
+    instants ``tq`` [2]: per label [L], over its devices covered at both
+    instants and reporting (``has``), the count of those ``active``
+    (``n_covered``) and of those quarantined (``n_quarantined``), and
+    over the active ones the total energy, its mean (nan where none) and
+    the two-pass sum of squared deviations from it (``M2``); and per
+    instant [2] how many devices are covered."""
+    e, cov = history_energy_at(tier, ops, rows, tq)
+    de = e[1] - e[0]
+    covered = cov[0] & cov[1] & ops.has
+    out = np.zeros((5, n_labels))
+    for c in range(n_labels):
+        sel = (ops.codes == c) & covered
+        inc = sel & ops.active
+        vals = de[inc]
+        n = vals.size
+        total = np.sum(vals)
+        mean = total / n if n else np.nan
+        out[:, c] = (n, np.sum(sel & ~ops.active), total, mean,
+                     np.sum((vals - mean) ** 2) if n else np.nan)
+    return (out[0].astype(np.int64), out[1].astype(np.int64), out[2],
+            out[3], out[4], np.sum(cov, axis=1))
+
+
+_TIER_KERNELS = {"energy_at": history_energy_at, "series": history_series,
+                 "by_label": history_by_label}
+
+#: Host↔device buffers each step of a query moves: none on this tier.
+TRANSFERS = {"tier_operands": (0, 0), "tier_batch": (0, 0),
+             "ring": (0, 0)}
+
+
+def history_batch(calls: list) -> list:
+    """The results of tier kernel calls, each ``(kind, tier, ops, rows,
+    tq, args)`` with ``kind`` one of ``energy_at``, ``series`` and
+    ``by_label`` and ``args`` the kernel's trailing arguments, in
+    order."""
+    return [_TIER_KERNELS[kind](tier, ops, rows, tq, *args)
+            for kind, tier, ops, rows, tq, args in calls]

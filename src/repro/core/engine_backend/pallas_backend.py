@@ -80,6 +80,9 @@ history_write = _jb.history_write
 history_operands = _jb.history_operands
 history_energy_at = _jb.history_energy_at
 history_series = _jb.history_series
+history_by_label = _jb.history_by_label
+history_batch = _jb.history_batch
+TRANSFERS = _jb.TRANSFERS
 
 
 def _interpret() -> bool:

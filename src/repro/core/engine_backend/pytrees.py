@@ -7,7 +7,9 @@ into ``jax.jit``-compiled kernels (leaves trace as ``jnp`` arrays) while
 staying a zero-cost tuple of ``np.ndarray`` views on the NumPy path.
 :class:`SlabOperands` is the streaming-ingest kernels' per-device operand
 record in the same form; :data:`SLAB_PAD` is the one table of the values
-its padding rows take.
+its padding rows take.  :class:`TierOperands` is the history tier's
+query kernels' per-device record, packed by dtype for one transfer
+(:data:`TIER_F64`, :data:`TIER_I32`, :func:`unpack_tier_operands`).
 
 The container carries no behaviour on purpose: backends implement the
 kernels as pure functions over these arrays, so the same signature works
@@ -131,3 +133,52 @@ def pad_operands(ops: SlabOperands, n: int) -> SlabOperands:
     return SlabOperands(*(
         np.concatenate([x, np.full(n - len(x), pad, np.asarray(x).dtype)])
         for x, pad in zip(ops, SLAB_PAD)))
+
+
+class TierOperands(NamedTuple):
+    """The per-device operands of the history tier's query kernels, each
+    ``[N]``, for one flavour (raw or corrected).
+
+    ``lo_t``/``hi_t`` are the times of the oldest and newest boundary the
+    device's slots hold (``+inf``/``-inf`` where it holds none);
+    ``last_t``/``first_t`` its newest and first sample time, ``has``
+    whether it has one, ``max_hold`` its hold cap; ``dens``/``base`` the
+    flavour's density and running energy at its newest sample; ``active``
+    False where health quarantined it, ``tol`` its sigma tolerance, and
+    ``codes`` its label's index.
+    """
+
+    lo_t: np.ndarray
+    hi_t: np.ndarray
+    last_t: np.ndarray
+    first_t: np.ndarray
+    has: np.ndarray
+    max_hold: np.ndarray
+    dens: np.ndarray
+    base: np.ndarray
+    active: np.ndarray
+    tol: np.ndarray
+    codes: np.ndarray
+
+
+#: The rows of a snapshot's packed float64 operand buffer [10, N]: both
+#: flavours' tails, so one buffer serves every query of the snapshot.
+TIER_F64 = ("lo_t", "hi_t", "last_t", "first_t", "max_hold", "tol",
+            "dens_corr", "base_corr", "dens_raw", "base_raw")
+#: The rows of its int32 buffer [3, N], booleans as 0 and 1.
+TIER_I32 = ("has", "active", "codes")
+
+
+def unpack_tier_operands(f64, i32) -> tuple:
+    """The corrected and the raw :class:`TierOperands` of one packed pair
+    of buffers (:data:`TIER_F64`, :data:`TIER_I32`); works on host and on
+    device arrays alike."""
+    f = dict(zip(TIER_F64, f64))
+    i = dict(zip(TIER_I32, i32))
+    common = dict(lo_t=f["lo_t"], hi_t=f["hi_t"], last_t=f["last_t"],
+                  first_t=f["first_t"], has=i["has"] != 0,
+                  max_hold=f["max_hold"], active=i["active"] != 0,
+                  tol=f["tol"], codes=i["codes"])
+    return tuple(TierOperands(dens=f["dens_" + flavour],
+                              base=f["base_" + flavour], **common)
+                 for flavour in ("corr", "raw"))

@@ -64,8 +64,9 @@ class OnlinePeriodEstimator:
         b = np.searchsorted(self.edges, durations, side="right")
         np.add.at(self.counts, (dev, b), 1)
         np.add.at(self.sums, (dev, b), durations)
-        if self._est is not None:
-            self._est[4][dev] = True
+        c = self._est
+        if c is not None and c[0] is self.counts:   # not since a grow
+            c[4][dev] = True
 
     @property
     def n_runs(self) -> np.ndarray:

@@ -42,16 +42,23 @@ every query answers an instant that is a tier boundary within the
 tier's horizon from the tier (:meth:`on_tier`), any other instant from
 the ring.
 :meth:`fleet_series` reduces the tier over devices on the backend and
-returns per-instant fleet numbers only.
+returns per-instant fleet numbers only; so does :meth:`by_label` between
+two tier boundaries, per label.  The tier's kernels read one operand
+record per flavour, packed and placed once per snapshot, and any number
+of their calls run with one upload and one fetch (:meth:`run_tier`,
+:meth:`prefetched`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from repro.common.trace import span
+from repro.core.engine_backend.pytrees import TIER_F64, TIER_I32
 from repro.core.fleet_engine import StreamingMoments
 from repro.core.stream.health import QUARANTINED, STALE
 from repro.core.stream.state import (DeviceState, HistoryView,
@@ -143,6 +150,13 @@ def series_multiple(step_s: float, history_step_s: float) -> int:
     return m
 
 
+def _call_key(call: tuple) -> tuple:
+    """What identifies a tier call's result on one snapshot: its kernel,
+    flavour, instants and trailing arguments."""
+    kind, corrected, tq, args = call
+    return kind, corrected, tq.tobytes(), args
+
+
 def _frozen(arr: np.ndarray) -> np.ndarray:
     out = arr.copy()
     out.setflags(write=False)
@@ -199,7 +213,7 @@ class MonitorSnapshot:
         self._health_code = health_code      # [N] i1 codes or None
         self._history = history
         self._flavor_cache: Dict[bool, tuple] = {}
-        self._history_ops_cache: Dict[Optional[bool], tuple] = {}
+        self._prefetch: Dict[tuple, tuple] = {}
 
     @classmethod
     def publish(cls, core) -> "MonitorSnapshot":
@@ -280,47 +294,85 @@ class MonitorSnapshot:
             return np.zeros(tq.shape, dtype=bool)
         return self._history.boundary_of(tq)[1]
 
-    def _history_ops(self, corrected: bool) -> tuple:
-        """The tier kernels' per-device operands, placed by the
-        backend once per snapshot: each device's covered boundaries
-        relative to the newest (int32), its tail state (the flavour's
-        density and running energy placed once per flavour), the health
-        mask and the sigma tolerance."""
-        if corrected not in self._history_ops_cache:
-            if None not in self._history_ops_cache:
-                self._history_ops_cache[None] = self._history_shared_ops()
-            shared = self._history_ops_cache[None]
-            tail = self._be.history_operands(*self._tail(corrected))
-            self._history_ops_cache[corrected] = (shared[:6] + tail
-                                                  + shared[6:])
-        return self._history_ops_cache[corrected]
-
-    def _history_shared_ops(self) -> tuple:
+    @functools.cached_property
+    def _tol(self) -> np.ndarray:
+        """[N] per-device sigma tolerance of an energy."""
         from repro.core.telemetry import (CALIBRATED_TOLERANCE,
                                           SHUNT_TOLERANCE)
-        h, st = self._history, self.state
-        ref, lim = h.b_newest, 2 ** 30
-        empty = h.b_hi < h.b_lo
-        lo = np.where(empty, 1, np.clip(h.b_lo - ref, -lim, lim))
-        hi = np.where(empty, 0, np.clip(h.b_hi - ref, -lim, lim))
-        active = self.active_mask
-        if active is None:
-            active = np.ones(self.n_devices, dtype=bool)
-        tol = np.where(self.corrections.calibrated,
-                       CALIBRATED_TOLERANCE, SHUNT_TOLERANCE)
-        return self._be.history_operands(
-            lo.astype(np.int32), hi.astype(np.int32), st.last_t,
-            st.first_t, st.has, self._max_hold, active, tol)
+        return np.where(self.corrections.calibrated, CALIBRATED_TOLERANCE,
+                        SHUNT_TOLERANCE)
 
-    def _history_args(self, tq: np.ndarray, corrected: bool) -> tuple:
-        """``(tier, ops, rows, b)`` for the tier kernels at boundary
-        instants ``tq``."""
+    @functools.cached_property
+    def _tier_ops(self) -> tuple:
+        """The corrected and the raw operands of the tier kernels: every
+        per-device operand packed into one float64 and one int32 host
+        buffer (``TIER_F64``, ``TIER_I32``), placed by the backend once
+        per snapshot."""
+        st, n = self.state, self.n_devices
+        f64 = np.empty((len(TIER_F64), n))
+        i32 = np.empty((len(TIER_I32), n), dtype=np.int32)
+        f = dict(zip(TIER_F64, f64))
+        f["lo_t"][:], f["hi_t"][:] = self._history.held_times()
+        f["last_t"][:], f["first_t"][:] = st.last_t, st.first_t
+        f["max_hold"][:], f["tol"][:] = self._max_hold, self._tol
+        f["dens_corr"][:], f["base_corr"][:] = self._tail(True)
+        f["dens_raw"][:], f["base_raw"][:] = self._tail(False)
+        active = self.active_mask
+        i32[0], i32[2] = st.has, self._label_codes
+        i32[1] = True if active is None else active
+        return self._be.history_operands(f64, i32)
+
+    def tier_call(self, kind: str, tq: np.ndarray, corrected: bool,
+                  args: tuple = ()) -> tuple:
+        """One call of the tier's ``kind`` kernel (``energy_at``,
+        ``series`` or ``by_label``) at boundary instants ``tq``, for
+        :meth:`run_tier`: ``(kind, corrected, tq, args)``, ``args`` the
+        kernel's trailing arguments."""
+        return kind, corrected, np.asarray(tq, dtype=np.float64), tuple(args)
+
+    def run_tier(self, calls: list) -> list:
+        """The host results of :meth:`tier_call` calls, in order: on the
+        accelerated tiers one upload of their instants and one fetch of
+        every result (the operands are placed on a snapshot's first
+        call).  Calls :meth:`prefetched` already ran are not run
+        again."""
+        keys = [_call_key(c) for c in calls]
         h = self._history
-        b = h.boundary_of(tq)[0]
-        lim = 2 ** 30 + 1           # past every clipped device bound
-        return ((h.e_corr if corrected else h.e_raw),
-                self._history_ops(corrected), b % h.slots,
-                np.clip(b - h.b_newest, -lim, lim))
+        todo = [(kind, h.e_corr if corrected else h.e_raw,
+                 self._tier_ops[0 if corrected else 1],
+                 h.boundary_of(tq)[0] % h.slots, tq, args)
+                for (kind, corrected, tq, args), k in zip(calls, keys)
+                if k not in self._prefetch]
+        fresh = iter(self._be.history_batch(todo) if todo else ())
+        return [self._prefetch[k] if k in self._prefetch else next(fresh)
+                for k in keys]
+
+    @contextlib.contextmanager
+    def prefetched(self, calls: list):
+        """Run ``calls`` now, as one :meth:`run_tier`; inside the block
+        the query methods that make any of them read its result instead
+        of running it again.  A flush of the executor plans its tier
+        calls, runs them here and answers every query through the
+        direct path's methods, so the flush makes one upload and one
+        fetch."""
+        self._prefetch = dict(zip(map(_call_key, calls),
+                                  self.run_tier(calls)))
+        try:
+            yield
+        finally:
+            self._prefetch = {}
+
+    def transfers(self, n_tier_calls: int, n_ring_calls: int) -> tuple:
+        """``(h2d, d2h)``: the host↔device buffers that ``n_tier_calls``
+        tier calls in one :meth:`run_tier` and ``n_ring_calls`` ring
+        energy-at calls move on this snapshot's backend."""
+        moves = self._be.TRANSFERS
+        steps = [moves["ring"]] * n_ring_calls
+        if n_tier_calls:
+            steps.append(moves["tier_batch"])
+            if "_tier_ops" not in self.__dict__:
+                steps.append(moves["tier_operands"])
+        return (sum(h for h, _ in steps), sum(d for _, d in steps))
 
     def energy_at_batch(self, tq: np.ndarray, corrected: bool = True
                         ) -> Tuple[np.ndarray, np.ndarray]:
@@ -332,12 +384,14 @@ class MonitorSnapshot:
         on = self.on_tier(tq)
         if not on.any():
             return self._ring_energy_at(tq, corrected)
+        (tier,) = self.run_tier([self.tier_call("energy_at", tq[on],
+                                                corrected)])
+        if on.all():
+            return tier
         e = np.empty((tq.size, self.n_devices))
         covered = np.empty((tq.size, self.n_devices), dtype=bool)
-        e[on], covered[on] = self._be.history_energy_at(
-            *self._history_args(tq[on], corrected), tq[on])
-        if not on.all():
-            e[~on], covered[~on] = self._ring_energy_at(tq[~on], corrected)
+        e[on], covered[on] = tier
+        e[~on], covered[~on] = self._ring_energy_at(tq[~on], corrected)
         return e, covered
 
     def _ring_energy_at(self, tq: np.ndarray, corrected: bool):
@@ -389,10 +443,7 @@ class MonitorSnapshot:
         reductions both the direct and the batched-executor paths use).
         See :class:`FleetEnergy` for the degraded-mode exclusion and
         sigma-widening semantics on health-tracked monitors."""
-        from repro.core.telemetry import (CALIBRATED_TOLERANCE,
-                                          SHUNT_TOLERANCE)
-        tol = np.where(self.corrections.calibrated,
-                       CALIBRATED_TOLERANCE, SHUNT_TOLERANCE)
+        tol = self._tol
         active = self.active_mask
         if active is None:
             include, n_q = covered, 0
@@ -497,9 +548,9 @@ class MonitorSnapshot:
         devices that had reported by then not covered."""
         tq = self.series_instants(t0, t1, step_s)
         with span("serve.series", instants=tq.size, devices=self.n_devices):
-            total, n_cov, n_inc, s2, s1, power, n_pow = \
-                self._be.history_series(*self._history_args(tq, corrected),
-                                        tq, step_s)
+            (raw,) = self.run_tier([self.series_call(tq, step_s,
+                                                     corrected)])
+        total, n_cov, n_inc, s2, s1, power, n_pow = raw
         n_cov, n_inc = n_cov.astype(np.int64), n_inc.astype(np.int64)
         n_q = n_cov - n_inc
         with np.errstate(divide="ignore", invalid="ignore"):
@@ -516,6 +567,12 @@ class MonitorSnapshot:
                            **{k: _frozen(np.asarray(v))
                               for k, v in out.items()})
 
+    def series_call(self, tq: np.ndarray, step_s: float,
+                    corrected: bool) -> tuple:
+        """The tier call of a series at instants ``tq``
+        (:meth:`series_instants`)."""
+        return self.tier_call("series", tq, corrected, (float(step_s),))
+
     def by_label(self, t0: Optional[float] = None,
                  t1: Optional[float] = None,
                  corrected: bool = True) -> Dict[str, Dict[str, float]]:
@@ -527,26 +584,66 @@ class MonitorSnapshot:
         report nan moments.  On health-tracked monitors quarantined
         devices are excluded from every aggregate (and reported per
         label as ``n_quarantined``, 0 otherwise) — the per-label
-        counterpart of :class:`FleetEnergy`'s degraded mode."""
+        counterpart of :class:`FleetEnergy`'s degraded mode.  Between
+        two tier boundaries the backend reduces the energy per label
+        (:meth:`label_call`); otherwise the host groups the rows."""
         if (t0 is None) != (t1 is None):
             raise ValueError("pass both t0 and t1, or neither")
         st = self.state
         if t0 is None:
             e = (st.energy_corr_j if corrected else st.energy_j)
-            covered = st.has.copy()
-        else:
-            e, covered = self.energy_between(t0, t1, corrected)
-            covered = covered & st.has
-        return self.by_label_rows(e, covered)
+            return self.by_label_rows(e, st.has.copy())
+        if not (t1 >= t0):
+            raise ValueError(f"bad window [{t0}, {t1}]")
+        call = self.label_call(t0, t1, corrected)
+        if call is not None:
+            return self.labels_from(self.run_tier([call])[0])
+        e, covered = self.energy_between(t0, t1, corrected)
+        return self.by_label_rows(e, covered & st.has)
+
+    def label_call(self, t0: float, t1: float,
+                   corrected: bool) -> Optional[tuple]:
+        """The tier call that reduces ``by_label(t0, t1)`` on the backend,
+        or None where an instant is not on the tier (:meth:`on_tier`):
+        the host path then groups the energy rows."""
+        tq = np.array([float(t0), float(t1)])
+        if not self.on_tier(tq).all():
+            return None
+        return self.tier_call("by_label", tq, corrected,
+                              (len(self._label_names),))
+
+    @functools.cached_property
+    def _label_counts(self) -> np.ndarray:
+        """[L] devices per label."""
+        return np.bincount(self._label_codes,
+                           minlength=len(self._label_names))
+
+    def labels_from(self, raw: tuple) -> Dict[str, Dict[str, float]]:
+        """The :meth:`by_label` answer from the by-label kernel's [L]
+        results (see the backends' ``history_by_label``)."""
+        n_cov, n_q, total, mean, m2 = raw[:5]
+        out: Dict[str, Dict[str, float]] = {}
+        for ci, label in enumerate(self._label_names):
+            n = int(n_cov[ci])
+            out[label] = {
+                "n_devices": int(self._label_counts[ci]),
+                "n_covered": n,
+                "n_quarantined": int(n_q[ci]),
+                "total_j": float(total[ci]) if n else 0.0,
+                "mean_j": float(mean[ci]) if n else float("nan"),
+                "std_j": (float(np.sqrt(max(m2[ci] / n, 0.0))) if n
+                          else float("nan")),
+            }
+        return out
 
     def by_label_rows(self, e: np.ndarray,
                       covered: np.ndarray) -> Dict[str, Dict[str, float]]:
-        """The by-label grouping of one [N] energy row (the reductions
-        :meth:`by_label` and the batched executor share), over the
-        integer label codes the ingest core keeps."""
+        """The by-label grouping of one [N] energy row on the host (the
+        reductions :meth:`by_label` and the batched executor share where
+        the tier does not answer), over the integer label codes the
+        ingest core keeps."""
         active = self.active_mask
         codes = self._label_codes
-        n_dev = np.bincount(codes, minlength=len(self._label_names))
         out: Dict[str, Dict[str, float]] = {}
         for ci, label in enumerate(self._label_names):
             sel = (codes == ci) & covered
@@ -559,7 +656,7 @@ class MonitorSnapshot:
             stats = sm.stats()
             n_cov = int(np.sum(sel))
             out[label] = {
-                "n_devices": int(n_dev[ci]),
+                "n_devices": int(self._label_counts[ci]),
                 "n_covered": n_cov,
                 "n_quarantined": n_q,
                 "total_j": float(np.sum(vals)) if vals.size else 0.0,

@@ -25,6 +25,7 @@ Three layers:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import weakref
 
 import numpy as np
@@ -239,11 +240,21 @@ class HistoryView:
     def slots(self) -> int:
         return self.steps + 1
 
-    @property
-    def b_newest(self) -> int:
-        """Newest boundary any device has written (``NO_BOUNDARY`` if
-        none)."""
-        return int(self.b_hi.max()) if self.b_hi.size else NO_BOUNDARY
+    @functools.cached_property
+    def _oldest(self):
+        """The oldest boundary any device holds, or None: a fleet-wide
+        min, taken once per view."""
+        held = self.b_hi >= self.b_lo
+        return int(self.b_lo[held].min()) if held.any() else None
+
+    def held_times(self) -> tuple:
+        """``(lo_t, hi_t)`` [N]: the times of the oldest and newest
+        boundary each device holds, ``(+inf, -inf)`` where it holds none.
+        An instant ``b · step_s`` lies between them exactly when ``b``
+        lies in ``[b_lo, b_hi]``."""
+        empty = self.b_hi < self.b_lo
+        return (np.where(empty, np.inf, self.b_lo * self.step_s),
+                np.where(empty, -np.inf, self.b_hi * self.step_s))
 
     def boundary_of(self, tq: np.ndarray) -> tuple:
         """``(b, on_tier)`` per instant: the boundary index nearest each
@@ -258,11 +269,9 @@ class HistoryView:
             b = np.rint(tq / self.step_s)
         ok = np.abs(b) < 2.0 ** 62          # nan and inf fail too
         b = np.where(ok, b, 0).astype(np.int64)
-        held = self.b_hi >= self.b_lo
-        if not held.any():
+        if self._oldest is None:
             return b, np.zeros(b.shape, dtype=bool)
-        oldest = int(self.b_lo[held].min())
-        return b, ok & (b * self.step_s == tq) & (b >= oldest)
+        return b, ok & (b * self.step_s == tq) & (b >= self._oldest)
 
 
 class HistoryTier:

@@ -12,7 +12,11 @@ whole batch against **one** immutable
   thousand requests are queued; the history tier's kernel for
   instants on its boundaries, ``snapshot_energy_at`` for the rest);
 * each ``fleet_series`` (a dashboard panel over the history tier) is
-  one series kernel call that reduces over devices on the backend;
+  one series kernel call that reduces over devices on the backend, and
+  each ``by_label`` over two tier boundaries one by-label kernel call;
+* every history-tier call of a flush runs with one upload of their
+  instants and one fetch of all their results (the snapshot's operands
+  go up once, on its first flush);
 * results are memoised in an LRU cache keyed ``(query, epoch)`` —
   an epoch tag in every key means a result can never be served against
   a different snapshot than the one that computed it;
@@ -138,6 +142,9 @@ class MonitorQueryService:
         self.n_instants_ring = 0
         self.n_instants_uncovered = 0
         self.n_series_calls = 0
+        # by-label answers reduced on the backend, and grouped on the host
+        self.n_labels_device = 0
+        self.n_labels_host = 0
 
     # -- request management ------------------------------------------------
     def submit(self, query: MonitorQuery) -> int:
@@ -218,16 +225,19 @@ class MonitorQueryService:
 
     def _execute(self, snap: MonitorSnapshot,
                  misses: List[MonitorQuery]) -> Dict[MonitorQuery, Any]:
-        """Run the deduplicated cache misses against one snapshot."""
-        with span("serve.execute"):
-            return self._execute_misses(snap, misses)
-
-    def _execute_misses(self, snap: MonitorSnapshot,
-                        misses: List[MonitorQuery]
-                        ) -> Dict[MonitorQuery, Any]:
-        out: Dict[MonitorQuery, Any] = {}
-        # collect every energy-at instant per corrected flavour:
-        # fleet_energy(t) needs one row, energy_between(t0, t1) two
+        """Run the deduplicated cache misses against one snapshot: plan
+        every history-tier call of the flush, run them all with one
+        upload and one fetch (:meth:`MonitorSnapshot.prefetched`), then
+        answer each query through the snapshot's own methods.  The span
+        ``serve.execute`` carries the host↔device buffers the flush
+        moves (``h2d``, ``d2h``)."""
+        calls: List[tuple] = []
+        # energy-at instants per corrected flavour: fleet_energy(t) needs
+        # one row, energy_between(t0, t1) two, and so does a by_label
+        # whose instants are not both on the tier; by_label over two tier
+        # instants is reduced on the backend instead
+        rows: Dict[bool, tuple] = {}
+        labels: Dict[MonitorQuery, tuple] = {}
         for corrected in (True, False):
             instants: List[float] = []
             seen: Dict[float, int] = {}
@@ -246,53 +256,82 @@ class MonitorQueryService:
                     plan.append((q, (row_of(q.t),)))
                 elif q.kind in ("energy_between", "by_label") \
                         and q.t0 is not None:
-                    plan.append((q, (row_of(q.t0), row_of(q.t1))))
+                    call = (snap.label_call(q.t0, q.t1, corrected)
+                            if q.kind == "by_label" else None)
+                    if call is None:
+                        plan.append((q, (row_of(q.t0), row_of(q.t1))))
+                    else:
+                        labels[q] = call
+                        calls.append(call)
             if plan:
                 tq = np.array(instants)
-                e, cov = snap.energy_at_batch(tq, corrected)
                 on = snap.on_tier(tq)
-                self.n_instants_tier += int(np.sum(on))
-                self.n_instants_ring += int(np.sum(~on))
-                self.n_instants_uncovered += int(np.sum(~cov.all(axis=1)))
-                for q, rows in plan:
-                    if q.kind == "fleet_energy":
-                        (r,) = rows
-                        out[q] = snap.fleet_from_rows(
-                            q.t, corrected, e[r].copy(), cov[r].copy())
-                    else:
-                        r0, r1 = rows
-                        de, dc = snap.between_from_rows(
-                            e[r0], cov[r0], e[r1], cov[r1])
-                        if q.kind == "energy_between":
-                            out[q] = (de, dc)
-                        else:
-                            out[q] = snap.by_label_rows(
-                                de, dc & snap.state.has)
+                if on.any():
+                    calls.append(snap.tier_call("energy_at", tq[on],
+                                                corrected))
+                rows[corrected] = (tq, on, plan)
+        for q in misses:
+            if q.kind == "fleet_series":
+                calls.append(snap.series_call(
+                    snap.series_instants(q.t0, q.t1, q.step), q.step,
+                    q.corrected))
 
-            # window_energy: all instants of a flavour in one broadcast
-            wq = [q for q in misses
-                  if q.kind == "window_energy" and q.corrected == corrected
-                  and q.t is not None]
-            if wq:
-                wt = []
-                wseen: Dict[float, int] = {}
-                for q in wq:
-                    if q.t not in wseen:
-                        wseen[q.t] = len(wt)
-                        wt.append(q.t)
-                we = snap.window_energy_batch(np.array(wt), corrected)
-                for q in wq:
-                    out[q] = we[wseen[q.t]].copy()
+        n_ring = sum(not on.all() for _, on, _ in rows.values())
+        h2d, d2h = snap.transfers(len(calls), n_ring)
+        with span("serve.execute", h2d=h2d, d2h=d2h), \
+                snap.prefetched(calls):
+            return self._answer(snap, misses, rows, labels)
 
+    def _answer(self, snap: MonitorSnapshot, misses: List[MonitorQuery],
+                rows: dict, labels: dict) -> Dict[MonitorQuery, Any]:
+        """Each miss's answer, planned by :meth:`_execute`, inside its
+        :meth:`MonitorSnapshot.prefetched` block."""
+        out: Dict[MonitorQuery, Any] = {}
+        for corrected, (tq, on, plan) in rows.items():
+            e, cov = snap.energy_at_batch(tq, corrected)
+            self.n_instants_tier += int(np.sum(on))
+            self.n_instants_ring += int(np.sum(~on))
+            self.n_instants_uncovered += int(np.sum(~cov.all(axis=1)))
+            for q, r in plan:
+                if q.kind == "fleet_energy":
+                    out[q] = snap.fleet_from_rows(
+                        q.t, corrected, e[r[0]].copy(), cov[r[0]].copy())
+                    continue
+                de, dc = snap.between_from_rows(e[r[0]], cov[r[0]],
+                                                e[r[1]], cov[r[1]])
+                if q.kind == "energy_between":
+                    out[q] = (de, dc)
+                else:
+                    out[q] = snap.by_label_rows(de, dc & snap.state.has)
+                    self.n_labels_host += 1
+        for q, call in labels.items():
+            (raw,) = snap.run_tier([call])
+            out[q] = snap.labels_from(raw)
+            self.n_labels_device += 1
+            self.n_instants_tier += raw[5].size
+            self.n_instants_uncovered += int(np.sum(raw[5] <
+                                                    snap.n_devices))
         # each series is one reduction kernel over the history tier
         for q in misses:
             if q.kind == "fleet_series":
-                res = snap.fleet_series(q.t0, q.t1, q.step, q.corrected)
-                out[q] = res
+                out[q] = res = snap.fleet_series(q.t0, q.t1, q.step,
+                                                 q.corrected)
                 self.n_series_calls += 1
                 self.n_instants_tier += int(res.t.size)
                 self.n_instants_uncovered += int(
                     np.sum(res.n_covered < snap.n_devices))
+
+        # window_energy(t): all instants of a flavour in one broadcast
+        for corrected in (True, False):
+            wq = [q for q in misses
+                  if q.kind == "window_energy" and q.corrected == corrected
+                  and q.t is not None]
+            if wq:
+                wt = {t: i for i, t in enumerate(dict.fromkeys(
+                    q.t for q in wq))}
+                we = snap.window_energy_batch(np.array(list(wt)), corrected)
+                for q in wq:
+                    out[q] = we[wt[q.t]].copy()
 
         # the t=None / since-start variants read snapshot arrays directly
         for q in misses:
@@ -304,6 +343,7 @@ class MonitorQueryService:
                 out[q] = snap.window_energy(None, q.corrected)
             elif q.kind == "by_label":
                 out[q] = snap.by_label(None, None, q.corrected)
+                self.n_labels_host += 1
             else:                                    # pragma: no cover
                 raise AssertionError(f"unplanned query {q}")
         return out
@@ -312,7 +352,9 @@ class MonitorQueryService:
     def stats(self) -> Dict[str, float]:
         """Executor counters: submissions, cache hit rate, flushes, the
         instants executed from the history tier, from the ring and with
-        some device not covered, and series kernel calls."""
+        some device not covered, series kernel calls, and the by-label
+        answers reduced on the backend (``labels_device``) and grouped
+        on the host (``labels_host``)."""
         answered = self.n_hits + self.n_misses
         return {
             "n_submitted": self.n_submitted,
@@ -327,4 +369,6 @@ class MonitorQueryService:
             "instants_ring": self.n_instants_ring,
             "instants_uncovered": self.n_instants_uncovered,
             "series_calls": self.n_series_calls,
+            "labels_device": self.n_labels_device,
+            "labels_host": self.n_labels_host,
         }

@@ -27,7 +27,7 @@ from repro.core.engine_backend import jax_backend as jb  # noqa: E402
 from repro.core.engine_backend import pallas_backend as pb  # noqa: E402
 from repro.core.engine_backend import precision  # noqa: E402
 from repro.core.engine_backend.pytrees import (  # noqa: E402
-    SlabOperands, TimelineArrays)
+    TIER_F64, TIER_I32, SlabOperands, TierOperands, TimelineArrays)
 
 D, M = 102_400, 20          # fleet × poll ticks per slab (padded to 128s)
 K = 131_072                 # flat slab: samples (a power-of-two bucket)
@@ -163,24 +163,35 @@ def test_sharded_audit_kernel_compiles_on_2x2_mesh(topo):
 
 
 def test_jax_history_kernels_compile(one_chip):
-    """The history tier's series, energy-at and write kernels at the
-    dashboard deployment's width: 301 boundaries × 10⁵ devices."""
+    """The history tier's write kernel, its operand unpack, and one
+    flush's program (two series, a by-label reduction and energy-at rows
+    over two tiers) at the dashboard deployment's width: 301 boundaries
+    × 10⁵ devices.  The program splits each float64 tier once, however
+    many of its calls read it."""
     slots, n, q, pairs = 301, 100_000, 301, 16_384
     s = lambda shape, dt=jnp.float64: jax.ShapeDtypeStruct(
         shape, dt, sharding=one_chip)
     v, b = s((n,)), s((n,), jnp.bool_)
-    ops = (s((n,), I32), s((n,), I32), v, v, b, v, v, v, b, v)
+    ops = TierOperands(lo_t=v, hi_t=v, last_t=v, first_t=v, has=b,
+                       max_hold=v, dens=v, base=v, active=b, tol=v,
+                       codes=s((n,), I32))
     tier = s((slots, n))
+    layout = (("series", 0, q, 0), ("series", 1, q, 0),
+              ("by_label", 0, 2, 3), ("energy_at", 1, 8, 0))
     with precision.x64():
-        texts = [
-            _compile(jb._history_series_impl, tier, s((q,), I32),
-                     s((q,), I32), s((q,)), s(()), *ops),
-            _compile(jb._history_energy_at_impl, tier, s((8,), I32),
-                     s((8,), I32), s((8,)), *ops[:8]),
-            _compile(jb._history_write_shared, tier, tier, s((pairs,), I32),
-                     s((pairs,), I32), s((pairs,)), s((pairs,)))]
+        flush = _compile(jb._history_flush, (tier, tier), (ops, ops),
+                         s((sum(2 * c[2] + 1 for c in layout),)), layout)
+        write = _compile(jb._history_write_shared, tier, tier,
+                         s((pairs,), I32), s((pairs,), I32), s((pairs,)),
+                         s((pairs,)))
+        _compile(jb._history_unpack, s((len(TIER_F64), n)),
+                 s((len(TIER_I32), n), I32))
     # each kernel's operations carry its scope in their op names, which a
     # device trace reports as their ``tf_op``
-    for text, scope in zip(texts, ("history_series", "history_energy_at",
-                                   "history_write")):
-        assert f"/{scope}/" in text, scope
+    for scope in ("history_series", "history_energy_at", "history_by_label",
+                  "history_fetch"):
+        assert f"/{scope}/" in flush, scope
+    assert "/history_write/" in write
+    splits = [line for line in flush.splitlines()
+              if "X64SplitHigh" in line and f"[{slots},{n}]" in line]
+    assert len(splits) == 2, splits
